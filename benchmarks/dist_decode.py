@@ -1,9 +1,10 @@
 """Distributed-decode benchmark: sharded vs local decode attention.
 
-Runs in a subprocess with --xla_force_host_platform_device_count=8 (the
-parent process has already locked jax to the visible device count), and
-merges its rows into ``BENCH_kernels.json`` next to the kernel
-micro-bench rows.
+Runs in a subprocess pinned to ``JAX_PLATFORMS=cpu`` with
+--xla_force_host_platform_device_count=8 (the parent process has already
+locked jax to the visible device count, and may hold a chip), and
+merges its rows — each stamped ``"platform": "cpu"`` — into
+``BENCH_kernels.json`` next to the kernel micro-bench rows.
 
 Per (B, T) cell:
   * local decode latency (``decode_attend_local`` on the full cache),
@@ -52,6 +53,7 @@ import jax.numpy as jnp
 
 from repro.dist.decode import sharded_flash_decode
 from repro.launch import hlo_analysis
+from repro.launch.mesh import make_mesh
 from repro.models.attention import decode_attend_local
 
 
@@ -67,7 +69,7 @@ def timed(fn, *args, reps=5):
 
 
 rows = []
-mesh = jax.make_mesh((1, 8), ("data", "model"))
+mesh = make_mesh((1, 8), ("data", "model"))
 key = jax.random.PRNGKey(0)
 H, KV, Dh = 8, 2, 64
 for B, T in ((4, 2048), (4, 8192)):
@@ -112,16 +114,17 @@ for B, T in ((4, 2048), (4, 8192)):
     n_pages = B * J
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (B, H, Dh))
-    kp = jax.random.normal(ks[1], (n_pages, PS_PAGE, KV, Dh))
-    vp = jax.random.normal(ks[2], (n_pages, PS_PAGE, KV, Dh))
+    kp = jax.random.normal(ks[1], (n_pages, KV, PS_PAGE, Dh))
+    vp = jax.random.normal(ks[2], (n_pages, KV, PS_PAGE, Dh))
     table = (jnp.arange(B, dtype=jnp.int32)[:, None] * J
              + jnp.arange(J, dtype=jnp.int32)[None, :])
     lens = jnp.full((B,), T_live, jnp.int32)
     # dense comparison cache: same live contents, (B, T) budget
-    ck = jnp.zeros((B, T, KV, Dh)).at[:, :T_live].set(
-        kp.reshape(B, T_live, KV, Dh))
-    cv = jnp.zeros((B, T, KV, Dh)).at[:, :T_live].set(
-        vp.reshape(B, T_live, KV, Dh))
+    def dense_of(pool):
+        return jnp.swapaxes(pool.reshape(B, J, KV, PS_PAGE, Dh), 2,
+                            3).reshape(B, T_live, KV, Dh)
+    ck = jnp.zeros((B, T, KV, Dh)).at[:, :T_live].set(dense_of(kp))
+    cv = jnp.zeros((B, T, KV, Dh)).at[:, :T_live].set(dense_of(vp))
 
     local = jax.jit(lambda q, k, v, c: decode_attend_local(
         q, k, v, jnp.arange(T), c))
@@ -222,7 +225,7 @@ for B, T in ((4, 2048),):
         # mla_concat_view materializes whole-POOL k_cat/v_cat copies —
         # exactly the cost the split row avoids
         q_cat, k_cat, v_cat, r = mla_concat_view(qa, qr, ck, kr,
-                                                 scale_mla)
+                                                 scale_mla, paged=True)
         return local_paged_decode_attend(q_cat, k_cat, v_cat, tb,
                                          ln)[..., :r]
 
@@ -273,8 +276,8 @@ for B, T in ((4, 2048),):
     n_pages = B * J_max
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (B, H, Dh))
-    kp = jax.random.normal(ks[1], (n_pages, PS_PAGE, KV, Dh))
-    vp = jax.random.normal(ks[2], (n_pages, PS_PAGE, KV, Dh))
+    kp = jax.random.normal(ks[1], (n_pages, KV, PS_PAGE, Dh))
+    vp = jax.random.normal(ks[2], (n_pages, KV, PS_PAGE, Dh))
     table = jnp.zeros((B, J_max), jnp.int32).at[:, :J_live].set(
         jnp.arange(B, dtype=jnp.int32)[:, None] * J_live
         + jnp.arange(J_live, dtype=jnp.int32)[None, :])
@@ -315,8 +318,8 @@ for B, T in ((4, 2048), (4, 8192)):
     n_pages = B * J
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (B, H, Dh))
-    kp = jax.random.normal(ks[1], (n_pages, PS_PAGE, KV, Dh))
-    vp = jax.random.normal(ks[2], (n_pages, PS_PAGE, KV, Dh))
+    kp = jax.random.normal(ks[1], (n_pages, KV, PS_PAGE, Dh))
+    vp = jax.random.normal(ks[2], (n_pages, KV, PS_PAGE, Dh))
     table = (jnp.arange(B, dtype=jnp.int32)[:, None] * J
              + jnp.arange(J, dtype=jnp.int32)[None, :])
     lens = jnp.full((B,), T_live, jnp.int32)
@@ -670,6 +673,10 @@ rows.append({
     "collective_bytes": None,
 })
 
+# every row names the platform it ran on: the host CPU's virtual
+# devices, never a chip
+for row in rows:
+    row["platform"] = jax.devices()[0].platform
 print("JSON:" + json.dumps(rows))
 """
 
@@ -679,7 +686,9 @@ def dist_decode_bench(json_path="BENCH_kernels.json"):
     src = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "src")
     pp = os.environ.get("PYTHONPATH")
-    env = {**os.environ,
+    # the child emulates an 8-device mesh on the host CPU: pinned to the
+    # CPU so it never contends with the parent for a chip
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
            "PYTHONPATH": src + (os.pathsep + pp if pp else "")}
     r = subprocess.run([sys.executable, "-c", _CHILD],
                        capture_output=True, text=True, timeout=900,

@@ -3,13 +3,13 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
 import dataclasses
-import jax
 
 from repro.common.config import ShapeSpec
 from repro.configs import ARCHS, reduced
 from repro.launch import dryrun
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 
 SHAPES = [
     ShapeSpec("train_4k", 64, 4, "train"),       # tiny stand-ins

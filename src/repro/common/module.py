@@ -13,6 +13,7 @@ training materializes arrays.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Callable, Dict, Optional
 
@@ -37,10 +38,22 @@ class ParamDef:
 
 # ---------------- initializers ----------------
 
-def fan_in_init(key, shape, dtype):
-    fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
+def fan_in_init(key, shape, dtype, n_in: int = 1, n_out: int = 1):
+    """Normal(0, 1/fan_in).  The fan-in is the product of the ``n_in``
+    input axes just before the ``n_out`` trailing output axes; leading
+    axes (a stacked layer axis) are batch.  A per-head projection
+    ``(d, H, Dh)`` takes ``n_out=2``, its output ``(H, Dh, d)``
+    ``n_in=2``."""
+    end = len(shape) - n_out
+    fan_in = (math.prod(shape[max(end - n_in, 0):end]) if len(shape) >= 2
+              else shape[-1])
     scale = 1.0 / math.sqrt(max(fan_in, 1))
     return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
+
+
+# per-head projections: (d, H, Dh) in, (H, Dh, d) out
+head_proj_init = functools.partial(fan_in_init, n_out=2)
+head_out_init = functools.partial(fan_in_init, n_in=2)
 
 
 def embed_init(key, shape, dtype):

@@ -37,7 +37,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as PS
 
 from repro.common.hints import resolve_mesh
@@ -130,7 +129,7 @@ def sharded_flash_decode(mesh, q, cache_k, cache_v, cur_len, *,
 
     scale_specs = (PS(dp, None), PS(dp, None)) if q8 else ()
     scale_args = (k_scale, v_scale) if q8 else ()
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(PS(dp, None, None),
                   PS(dp, model_axis, None, None),
@@ -138,9 +137,9 @@ def sharded_flash_decode(mesh, q, cache_k, cache_v, cur_len, *,
                  + scale_specs + (PS(),),
         out_specs=PS(dp, None, None),
         # the psum/pmax combine replicates the output over the model
-        # axis by construction, but check_rep has no rule for
-        # pallas_call — disable the static check rather than the path
-        check_rep=False)
+        # axis by construction, but the varying-axes check has no rule
+        # for pallas_call — disable the static check, not the path
+        check_vma=False)
     return fn(q, cache_k, cache_v, *scale_args,
               jnp.asarray(cur_len, jnp.int32).reshape(()))
 
@@ -197,7 +196,7 @@ def sharded_mla_flash_decode(mesh, q_abs, q_rope, cache_ckv,
 
     scale_specs = (PS(dp), PS(dp)) if q8 else ()
     scale_args = (ckv_scale, krope_scale) if q8 else ()
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(PS(dp, None, None),
                   PS(dp, None, None),
@@ -205,7 +204,7 @@ def sharded_mla_flash_decode(mesh, q_abs, q_rope, cache_ckv,
                   PS(dp, model_axis, None))
                  + scale_specs + (PS(),),
         out_specs=PS(dp, None, None),
-        check_rep=False)
+        check_vma=False)
     return fn(q_abs, q_rope, cache_ckv, cache_krope, *scale_args,
               jnp.asarray(cur_len, jnp.int32).reshape(()))
 
@@ -275,12 +274,12 @@ def local_paged_decode_attend(q, k_pool, v_pool, table, lens, *,
                               backend="xla") -> jax.Array:
     """Single-shard paged decode attention (normalized).
 
-    q: (B, H, Dh); k_pool/v_pool: (n_pages, page_size, KV, Dh);
-    table: (B, max_pages) int32; lens: (B,) int32 valid positions per
-    slot (0 = inactive slot -> zero output).  ``k_scale``/``v_scale``
-    ((n_pages, KV) fp32 per-page per-head sidecars) select the q8 op
-    over int8 pools."""
-    ps = k_pool.shape[1]
+    q: (B, H, Dh); k_pool/v_pool: head-major (n_pages, KV, page_size,
+    Dh); table: (B, max_pages) int32; lens: (B,) int32 valid positions
+    per slot (0 = inactive slot -> zero output).  ``k_scale``/
+    ``v_scale`` ((n_pages, KV) fp32 per-page per-head sidecars) select
+    the q8 op over int8 pools."""
+    ps = k_pool.shape[2]
     J = table.shape[1]
     counts = _page_counts(lens, J, ps)
     # page_size/max_pages ride as static kwargs so the page geometry
@@ -318,7 +317,7 @@ def sharded_paged_flash_decode(mesh, q, k_pool, v_pool, table, lens, *,
     int8 pools; the sidecars shard on their leading page dim exactly
     like the pools, so each shard dequantizes its own pages locally.
     """
-    n_pages, ps = k_pool.shape[0], k_pool.shape[1]
+    n_pages, ps = k_pool.shape[0], k_pool.shape[2]
     J = table.shape[1]
     q8 = k_scale is not None
     op = "decode_partial_paged_q8" if q8 else "decode_partial_paged"
@@ -357,14 +356,14 @@ def sharded_paged_flash_decode(mesh, q, k_pool, v_pool, table, lens, *,
     scale_specs = ((PS(model_axis, None), PS(model_axis, None))
                    if q8 else ())
     scale_args = (k_scale, v_scale) if q8 else ()
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(PS(dp, None, None),
                   PS(model_axis, None, None, None),
                   PS(model_axis, None, None, None))
                  + scale_specs + (PS(dp, None), PS(dp)),
         out_specs=PS(dp, None, None),
-        check_rep=False)
+        check_vma=False)
     return fn(q, k_pool, v_pool, *scale_args,
               table.astype(jnp.int32), jnp.asarray(lens, jnp.int32))
 
@@ -484,7 +483,7 @@ def sharded_mla_paged_flash_decode(mesh, q_abs, q_rope, ckv_pool,
 
     scale_specs = (PS(model_axis), PS(model_axis)) if q8 else ()
     scale_args = (ckv_scale, krope_scale) if q8 else ()
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(PS(dp, None, None),
                   PS(dp, None, None),
@@ -492,7 +491,7 @@ def sharded_mla_paged_flash_decode(mesh, q_abs, q_rope, ckv_pool,
                   PS(model_axis, None, None))
                  + scale_specs + (PS(dp, None), PS(dp)),
         out_specs=PS(dp, None, None),
-        check_rep=False)
+        check_vma=False)
     return fn(q_abs, q_rope, ckv_pool, krope_pool, *scale_args,
               table.astype(jnp.int32), jnp.asarray(lens, jnp.int32))
 
@@ -579,7 +578,7 @@ def local_chunk_prefix_attend(q, k_pool, v_pool, table, counts, *,
     exactly like the local ``chunk_prefill_attend``.
     ``k_scale``/``v_scale`` ((n_pages, KV) fp32) select the q8 op over
     int8 pools."""
-    ps, J = k_pool.shape[1], table.shape[0]
+    ps, J = k_pool.shape[2], table.shape[0]
     if k_scale is not None:
         return D.dispatch("chunk_prefix_paged_q8", backend, q, k_pool,
                           v_pool, k_scale, v_scale, table, counts,
@@ -608,7 +607,7 @@ def sharded_chunk_prefix_attend(mesh, q, k_pool, v_pool, table, counts,
     no prior pages (J = 0, or every count zeroed) combines to the
     fully-masked partial (o = 0, m = NEG_INF, l = 0), which the merge
     treats as exact identity."""
-    n_pages, ps = k_pool.shape[0], k_pool.shape[1]
+    n_pages, ps = k_pool.shape[0], k_pool.shape[2]
     J = table.shape[0]
     q8 = k_scale is not None
     op = "chunk_prefix_paged_q8" if q8 else "chunk_prefix_paged"
@@ -645,7 +644,7 @@ def sharded_chunk_prefix_attend(mesh, q, k_pool, v_pool, table, counts,
     scale_specs = ((PS(model_axis, None), PS(model_axis, None))
                    if q8 else ())
     scale_args = (k_scale, v_scale) if q8 else ()
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(PS(None, None, None),
                   PS(model_axis, None, None, None),
@@ -653,7 +652,7 @@ def sharded_chunk_prefix_attend(mesh, q, k_pool, v_pool, table, counts,
                  + scale_specs + (PS(None), PS(None)),
         out_specs=(PS(None, None, None), PS(None, None),
                    PS(None, None)),
-        check_rep=False)
+        check_vma=False)
     return fn(q, k_pool, v_pool, *scale_args,
               table.astype(jnp.int32), counts.astype(jnp.int32))
 

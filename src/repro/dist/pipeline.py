@@ -14,7 +14,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as PS
 
 
@@ -55,6 +54,6 @@ def pipeline_apply(mesh, stage_fn, stage_params, x, *, n_micro: int,
         out = jax.lax.psum(jnp.stack(outs), axis_name)
         return out.reshape(n_tokens, *xg.shape[1:])
 
-    fn = shard_map(run, mesh=mesh,
-                   in_specs=(PS(axis_name), PS()), out_specs=PS())
+    fn = jax.shard_map(run, mesh=mesh,
+                       in_specs=(PS(axis_name), PS()), out_specs=PS())
     return fn(stage_params, x)

@@ -239,7 +239,7 @@ def paged_cache_pspecs(cfg, mesh, batch_slots: int, *,
                        quantized: bool = False):
     """PartitionSpec tree matching ``engine.paged_cache.paged_cache_spec``.
 
-    Pool leaves are ``(L, n_pages, page_size, ...)``: with
+    Pool leaves are ``(L, n_pages, ...)``: with
     ``seq_shard=True`` the *page* dim takes 'model' (each shard owns a
     contiguous slab of the pool — ``dist.decode.
     sharded_paged_flash_decode`` masks foreign pages by count and
@@ -265,7 +265,7 @@ def paged_cache_pspecs(cfg, mesh, batch_slots: int, *,
                    and cfg.n_kv_heads % sizes[mp] == 0) else None)
 
     def gqa_pool():
-        sh = PS(None, pageax, None, kvax, None)
+        sh = PS(None, pageax, kvax, None, None)   # (L, n_pages, KV, ps, Dh)
         pool = {"k": sh, "v": sh}
         if quantized:
             ssh = PS(None, pageax, kvax)       # (L, n_pages, KV)
@@ -328,6 +328,36 @@ def decode_batch_pspecs(cfg, mesh, global_batch: int, *,
         "cache": cache_pspecs(cfg, mesh, global_batch,
                               seq_shard=seq_shard),
     }
+
+
+# ----------------------------------------------------------------------
+# Pallas kernels in multi-device programs
+# ----------------------------------------------------------------------
+
+def multi_device(mesh) -> bool:
+    return mesh is not None and mesh.size > 1
+
+
+def tp_entry(mesh, n: int) -> Optional[str]:
+    """'model' when the mesh has a model axis that divides ``n``."""
+    mp = model_axis(mesh)
+    return mp if mp is not None and n % mesh.shape[mp] == 0 else None
+
+
+def batch_entry(mesh, batch: int):
+    """The data axes for a leading batch dim of size ``batch``, when
+    they divide it."""
+    dp = _dp_entry(mesh)
+    return dp if dp is not None and batch % data_size(mesh) == 0 else None
+
+
+def per_device(fn, mesh, in_specs, out_specs):
+    """``fn`` — a body that calls Pallas kernels — run on each device's
+    shard under ``shard_map``: the compiler cannot partition a Mosaic
+    kernel, so a multi-device program hands every device its block
+    explicitly (tensor-parallel bodies psum over 'model' themselves)."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def to_shardings(mesh, tree):

@@ -6,8 +6,11 @@ The dense decode cache pads every request to the engine's full
 per-slot budget with a shared pool of fixed-width pages:
 
   * each family cache leaf becomes a **page pool** with the (B, T)
-    dims replaced by ``(n_pages, page_size)`` — e.g. the GQA leaf
-    ``(L, B, T, KV, Dh)`` becomes ``(L, n_pages, page_size, KV, Dh)``;
+    dims replaced by ``(n_pages, page_size)`` — the GQA leaf
+    ``(L, B, T, KV, Dh)`` becomes the head-major ``(L, n_pages, KV,
+    page_size, Dh)`` (one head's page is a contiguous ``(page_size,
+    Dh)`` tile, the block the TPU kernels stage), the MLA latent leaf
+    ``(L, B, T, r)`` becomes ``(L, n_pages, page_size, r)``;
   * a ``(B_slots, max_pages)`` int32 **block table** maps each slot's
     logical page j to a physical page id (the allocator hands pages
     out on demand, so a slot only ever owns ``ceil(len/page_size)``
@@ -88,7 +91,8 @@ def paged_cache_spec(cfg, n_pages: int, page_size: int,
                      kv_dtype: str = None):
     """ShapeDtypeStruct tree for the paged decode cache.
 
-    KV leaves become ``(L, n_pages, page_size, ...)`` pools.  The audio
+    KV leaves become ``(L, n_pages, KV, page_size, Dh)`` (GQA) or
+    ``(L, n_pages, page_size, r)`` (MLA latents) pools.  The audio
     cross-attention cache stays slot-dense ``(L, B_slots, enc_len_p,
     KV, Dh)`` — it is written once at admission and sized exactly by
     the encoder length (no dead bytes to reclaim); ``lm`` *views* it as
@@ -119,7 +123,7 @@ def paged_cache_spec(cfg, n_pages: int, page_size: int,
         return jax.ShapeDtypeStruct(shape, dtype)
 
     def gqa_pool(L):
-        sh = (L, n_pages, page_size, cfg.n_kv_heads, cfg.d_head)
+        sh = (L, n_pages, cfg.n_kv_heads, page_size, cfg.d_head)
         pool = {"k": sds(sh, pool_dt), "v": sds(sh, pool_dt)}
         if q8:
             ssh = (L, n_pages, cfg.n_kv_heads)
@@ -172,19 +176,27 @@ def init_paged_cache(cfg, n_pages: int, page_size: int,
 # prefill -> pages
 # ----------------------------------------------------------------------
 
-def _scatter_pages(pool, kv, table):
-    """pool (L, n_pages, ps, ...) <- kv (L, B', S, ...) at the pages of
-    ``table`` (B', max_pages); S is padded up to a page multiple (the
-    zero pad also scrubs stale bytes from reused pages)."""
+def _to_pages(kv, ps):
+    """Prefill material (L, B', S, ...) -> (L, B', J, *page) in the
+    pool's page layout: S padded up to a page multiple (the zero pad
+    also scrubs stale bytes from reused pages) and a GQA page turned
+    head-major, (ps, KV, Dh) -> (KV, ps, Dh)."""
     L, Bp, S = kv.shape[:3]
-    ps = pool.shape[2]
     pad = (-S) % ps
     if pad:
         kv = jnp.pad(kv, ((0, 0), (0, 0), (0, pad))
                      + ((0, 0),) * (kv.ndim - 3))
     J = kv.shape[2] // ps
-    kv = kv.reshape(L, Bp, J, ps, *kv.shape[3:]).astype(pool.dtype)
-    return pool.at[:, table[:, :J]].set(kv)
+    kv = kv.reshape(L, Bp, J, ps, *kv.shape[3:])
+    return jnp.swapaxes(kv, 3, 4) if kv.ndim == 6 else kv
+
+
+def _scatter_pages(pool, kv, table):
+    """pool (L, n_pages, *page) <- kv (L, B', S, ...) at the pages of
+    ``table`` (B', max_pages)."""
+    pages = _to_pages(kv, pool.shape[-2])
+    J = pages.shape[2]
+    return pool.at[:, table[:, :J]].set(pages.astype(pool.dtype))
 
 
 def _scatter_pages_q8(pool, scales, kv, table):
@@ -192,25 +204,17 @@ def _scatter_pages_q8(pool, scales, kv, table):
 
     Same layout contract as ``_scatter_pages`` but the page material is
     symmetric-int8 quantized per page — per KV head when the pool
-    carries a head axis (GQA (L, n_pages, ps, KV, Dh), scale group =
+    carries a head axis (GQA (L, n_pages, KV, ps, Dh), scale group =
     (ps, Dh) per head), per whole page for the flat MLA latents
     (L, n_pages, ps, r).  The zero pad of a partial last page rides
     inside the scale group, so it both scrubs stale bytes and leaves
     the amax untouched.  Returns (pool, scales)."""
-    L, Bp, S = kv.shape[:3]
-    ps = pool.shape[2]
-    pad = (-S) % ps
-    if pad:
-        kv = jnp.pad(kv, ((0, 0), (0, 0), (0, pad))
-                     + ((0, 0),) * (kv.ndim - 3))
-    J = kv.shape[2] // ps
-    kvr = kv.reshape(L, Bp, J, ps, *kv.shape[3:])
-    if pool.ndim == 5:                      # GQA: per-page per-head
-        q, s = quantize_int8(kvr, axis=(3, 5))
-        s = s.reshape(L, Bp, J, kvr.shape[4])
-    else:                                   # MLA latent: per-page
-        q, s = quantize_int8(kvr, axis=(3, 4))
-        s = s.reshape(L, Bp, J)
+    pages = _to_pages(kv, pool.shape[-2])
+    L, Bp, J = pages.shape[:3]
+    # GQA (L, B', J, KV, ps, Dh): per-page per-head; MLA (L, B', J, ps,
+    # r): per-page
+    q, s = quantize_int8(pages, axis=(pages.ndim - 2, pages.ndim - 1))
+    s = s.reshape(pages.shape[:pages.ndim - 2])
     return (pool.at[:, table[:, :J]].set(q),
             scales.at[:, table[:, :J]].set(s))
 
@@ -233,7 +237,7 @@ def _scatter_family(sub, kvs, keys, table):
 def quantized_page_write(pool, scales, pages, offs, x):
     """One decode token per slot into an int8 pool + scale sidecar.
 
-    pool: (n_pages, ps, KV, Dh) or (n_pages, ps, r) int8 (one layer's
+    pool: (n_pages, KV, ps, Dh) or (n_pages, ps, r) int8 (one layer's
     slice); scales: (n_pages, KV) or (n_pages,) fp32; pages/offs: (B,)
     from ``models.lm._page_write_ids`` (page id ``n_pages`` = inactive
     slot, dropped); x: (B, KV, Dh) or (B, r) new-token material.
@@ -248,7 +252,7 @@ def quantized_page_write(pool, scales, pages, offs, x):
     ``at[pages, offs].set``."""
     n_pages = pool.shape[0]
     B = x.shape[0]
-    per_head = pool.ndim == 4               # (n_pages, ps, KV, Dh)
+    per_head = pool.ndim == 4               # (n_pages, KV, ps, Dh)
     xf = x.astype(jnp.float32)
     s_tok = int8_scale(jnp.max(jnp.abs(xf), axis=-1))  # (B, KV) | (B,)
     pidx = jnp.clip(pages, 0, n_pages - 1)
@@ -258,7 +262,7 @@ def quantized_page_write(pool, scales, pages, offs, x):
                       s_tok, jnp.maximum(s_old, s_tok))
 
     def ex(s):                              # scale -> page broadcast
-        return s[:, None, :, None] if per_head else s[:, None, None]
+        return s[:, :, None, None] if per_head else s[:, None, None]
 
     page_f = pool[pidx].astype(jnp.float32) * ex(s_old)
     keep = ~fresh.reshape((B,) + (1,) * (page_f.ndim - 1))
@@ -267,7 +271,8 @@ def quantized_page_write(pool, scales, pages, offs, x):
                      -127, 127).astype(jnp.int8)
     qtok = jnp.clip(jnp.round(xf / s_new[..., None]),
                     -127, 127).astype(jnp.int8)
-    qpage = qpage.at[jnp.arange(B), offs].set(qtok)
+    qpage = (qpage.at[jnp.arange(B), :, offs] if per_head
+             else qpage.at[jnp.arange(B), offs]).set(qtok)
     return (pool.at[pages].set(qpage, mode="drop"),
             scales.at[pages].set(s_new, mode="drop"))
 
@@ -505,8 +510,8 @@ def fork_page(cfg, cache, src, dst):
 
     ``src``/``dst`` may be traced int32 scalars, so one jitted copy
     serves every (src, dst) pair.  Every leaf of the dense/moe paged
-    cache carries the page dim at axis 1 (pools ``(L, n_pages, ps,
-    ...)``, sidecars ``(L, n_pages[, KV])``); the audio family's
+    cache carries the page dim at axis 1 (pools ``(L, n_pages, ...)``,
+    sidecars ``(L, n_pages[, KV])``); the audio family's
     slot-dense cross cache breaks that contract and is rejected.
     """
     check_family(cfg)

@@ -62,7 +62,9 @@ import numpy as np
 
 from repro.checkpoint.store import CheckpointStore
 
-SNAPSHOT_VERSION = 1
+# 2: GQA page pools are head-major (L, n_pages, KV, page_size, Dh); a
+# version-1 pool (L, n_pages, page_size, KV, Dh) would be misread
+SNAPSHOT_VERSION = 2
 
 
 # ----------------------------------------------------------------------

@@ -387,7 +387,7 @@ def vwr_flash_decode(q, k, v, cur_len, pos0=0, *, bkv=None,
 def _vwr_paged_flash_decode_jit(q, k_pool, v_pool, table, counts, *,
                                 interpret):
     B, H, D = q.shape
-    n_pages, ps, KV, _ = k_pool.shape
+    n_pages, KV, ps, _ = k_pool.shape
     G = H // KV
     qf = q.reshape(B * KV, G, D)
     # unallocated / foreign table entries carry count 0, so any legal
@@ -403,9 +403,10 @@ def vwr_paged_flash_decode(q, k_pool, v_pool, table, counts, *,
                            interpret=None):
     """Unnormalized flash-decode partials against a paged KV pool.
 
-    q: (B, H, Dh); k_pool, v_pool: (n_pages, page_size, KV, Dh) — the
-    shared page pool (possibly one shard's slab of it); table: (B,
-    max_pages) int32 physical page per (slot, logical page); counts:
+    q: (B, H, Dh); k_pool, v_pool: head-major (n_pages, KV, page_size,
+    Dh) — the shared page pool (possibly one shard's slab of it);
+    table: (B, max_pages) int32 physical page per (slot, logical page);
+    counts:
     (B, max_pages) int32 valid tokens per (slot, logical page) — 0
     masks a page completely.  Page size is the transaction width here
     (the engine owns it), so there is no block autotuning; the 'auto'
@@ -611,7 +612,7 @@ def _decode_blocks_q8(B, T, H, KV, D, interpret):
 def _vwr_paged_flash_decode_q8_jit(q, k_pool, v_pool, k_scale, v_scale,
                                    table, counts, *, interpret):
     B, H, D = q.shape
-    n_pages, ps, KV, _ = k_pool.shape
+    n_pages, KV, ps, _ = k_pool.shape
     G = H // KV
     qf = q.reshape(B * KV, G, D)
     tbl = jnp.clip(table, 0, n_pages - 1).astype(jnp.int32)
@@ -626,7 +627,7 @@ def vwr_paged_flash_decode_q8(q, k_pool, v_pool, k_scale, v_scale,
                               table, counts, *, interpret=None):
     """``vwr_paged_flash_decode`` over int8 page pools.
 
-    k_pool, v_pool: int8 (n_pages, page_size, KV, Dh); k_scale,
+    k_pool, v_pool: int8 (n_pages, KV, page_size, Dh); k_scale,
     v_scale: fp32 (n_pages, KV) sidecars riding the same block-table
     indirection as the pages (scalar-prefetch, resolved per grid
     step).  Staged cache traffic per token is halved vs bf16 pools;
@@ -723,7 +724,7 @@ def vwr_mla_paged_flash_decode_q8(q_abs, q_rope, ckv_pool, krope_pool,
 def _vwr_chunk_prefix_attend_jit(q, k_pool, v_pool, table, counts, *,
                                  interpret):
     C, H, D = q.shape
-    n_pages, ps, KV, _ = k_pool.shape
+    n_pages, KV, ps, _ = k_pool.shape
     G = H // KV
     qf = jnp.transpose(q.reshape(C, KV, G, D),
                        (1, 0, 2, 3)).reshape(KV, C * G, D)
@@ -758,7 +759,7 @@ def vwr_chunk_prefix_attend(q, k_pool, v_pool, table, counts, *,
 def _vwr_chunk_prefix_attend_q8_jit(q, k_pool, v_pool, k_scale, v_scale,
                                     table, counts, *, interpret):
     C, H, D = q.shape
-    n_pages, ps, KV, _ = k_pool.shape
+    n_pages, KV, ps, _ = k_pool.shape
     G = H // KV
     qf = jnp.transpose(q.reshape(C, KV, G, D),
                        (1, 0, 2, 3)).reshape(KV, C * G, D)

@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import tpu_compiler_params
-
 NEG_INF = -1e30
 
 
@@ -110,7 +108,7 @@ def vwr_attention_p(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
-            "parallel", "parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

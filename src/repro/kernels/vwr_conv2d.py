@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import halo_block_spec, tpu_compiler_params
 from repro.kernels.vwr_matmul import ACTIVATIONS
 
 
@@ -71,9 +70,10 @@ def vwr_conv2d_p(x: jax.Array, w: jax.Array, bias=None, *, bh: int = 8,
                                W_out=W_out, has_bias=bias is not None,
                                activation=activation)
     in_specs = [
-        halo_block_spec((1, bh + KH - 1, W, C),
-                        lambda n, r, f: (n, r * bh, 0, 0),
-                        halo_dim=1),
+        # overlapping (halo'd) row windows: the row index map returns
+        # ELEMENT offsets
+        pl.BlockSpec((1, pl.Element(bh + KH - 1), W, C),
+                     lambda n, r, f: (n, r * bh, 0, 0)),
         pl.BlockSpec((KH, KW, C, bf), lambda n, r, f: (0, 0, 0, f)),
     ]
     operands = [x, w]
@@ -81,7 +81,8 @@ def vwr_conv2d_p(x: jax.Array, w: jax.Array, bias=None, *, bh: int = 8,
         assert bias.shape == (1, F), bias.shape
         in_specs.append(pl.BlockSpec((1, bf), lambda n, r, f: (0, f)))
         operands.append(bias)
-    params = tpu_compiler_params("parallel", "parallel", "parallel")
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel"))
     return pl.pallas_call(
         kernel,
         grid=(N, H_out // bh, F // bf),

@@ -1,15 +1,16 @@
-"""Flash-decode kernel: one query token vs a (possibly sharded) cache.
+"""Flash-decode kernels: one query token (or one prompt chunk) vs a
+dense, paged or int8 KV cache.
 
 Decode is the zero-reuse end of the paper's streaming spectrum — every
 cached (T, Dh) K/V element is read exactly once per generated token, so
 the only lever is transaction width: each grid step stages one wide
-(bkv x Dh) cache block in VMEM (the ultra-wide transaction) and the
+cache block (a ``bkv x Dh`` slab, or one whole page) in VMEM and the
 whole head group consumes it before the next fetch.  GQA is zero-copy
 as in ``vwr_attention``: the q block is the *group* (G query heads that
 share one KV head), so the staged cache bytes per group are 1/G of the
 head-expanded layout.
 
-Unlike the prefill kernel this one returns the **unnormalized** online-
+Unlike the prefill kernel these return the **unnormalized** online-
 softmax partials (o_tilde, m, l) rather than the normalized context:
 that is the combine contract of distributed FlashDecoding
 (``dist.decode``), where each model shard holds a slab of the cache
@@ -17,10 +18,22 @@ starting at global position ``pos0`` and only the (B, H) statistics
 cross the interconnect.  Single-device callers normalize with
 ``o_tilde / max(l, eps)``.
 
-q: (B*KV, G, Dh); k, v: (B*KV, Tp, Dh) flattened kv heads, Tp padded
-to a bkv multiple; lens: (1, 2) int32 [cur_len, pos0] (dynamic —
-decode runs inside a jitted generation loop).  Grid: (B*KV, kv-blocks),
-kv innermost (sequential).
+Mosaic tiling contract (what the TPU compiler accepts): the last two
+dims of every block equal the array's own, or divide by (8, 128).  So
+
+  * paged GQA pools are **head-major**, ``(n_pages, KV, page_size,
+    Dh)``: one (slot, head, page) grid step stages the contiguous
+    ``(page_size, Dh)`` tile of one head, a block ``(1, 1, ps, Dh)``;
+  * the (m, l) statistics leave the kernel as ``(rows, G, 1)`` columns
+    (block ``(1, G, 1)``) — exactly the ``(G, 1)`` VMEM scratch they
+    accumulate in — and the wrappers drop the trailing 1;
+  * dense-cache lengths ``[cur_len, pos0]`` ride as a scalar-prefetch
+    operand (SMEM), never as a vector block.
+
+Every kernel shares one online-softmax step (``_flash_update``); the
+int8 variants stage int8 blocks and fold their per-block scales into
+the scores and values (``q.(k*s) == (q.k)*s``), so dequantization costs
+one multiply per staged block, not one per element.
 """
 from __future__ import annotations
 
@@ -31,421 +44,157 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import tpu_compiler_params
-
 NEG_INF = -1e30
+F32 = jnp.float32
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, lens_ref, ot_ref, m_ref, l_ref,
-                   acc_ref, ms_ref, ls_ref, *, scale, bkv, t_valid,
-                   n_kv):
-    j = pl.program_id(1)
+def _params(*dimension_semantics):
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
 
+
+# ----------------------------------------------------------------------
+# the shared online-softmax step
+# ----------------------------------------------------------------------
+
+def _flash_init(j, acc_ref, ms_ref, ls_ref):
     @pl.when(j == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         ms_ref[...] = jnp.full_like(ms_ref, NEG_INF)
         ls_ref[...] = jnp.zeros_like(ls_ref)
 
-    cur = lens_ref[0, 0]
-    pos0 = lens_ref[0, 1]
-    q = q_ref[0].astype(jnp.float32) * scale            # (G, Dh)
-    k = k_ref[0].astype(jnp.float32)                    # (bkv, Dh)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    idx = j * bkv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    # idx < t_valid masks the block-multiple padding; pos0 + idx < cur
-    # masks positions not yet written (and, sharded, positions owned by
-    # other shards' slabs never appear here at all)
-    valid = (idx < t_valid) & (pos0 + idx < cur)
+
+def _scores(q, k):
+    """(R, D) x (T, D) -> (R, T) fp32."""
+    return jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                               preferred_element_type=F32)
+
+
+def _flash_update(s, valid, v, acc_ref, ms_ref, ls_ref, v_scale=None):
+    """Fold one staged block into the running (acc, m, l): ``s`` (R, T)
+    scores, ``valid`` (R, T) mask, ``v`` (T, Dv) values; the scratch
+    refs are (R, Dv), (R, 1), (R, 1)."""
     s = jnp.where(valid, s, NEG_INF)
-    m_prev = ms_ref[:, 0]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))         # (G,)
-    p = jnp.exp(s - m_new[:, None])
-    p = jnp.where((m_new > NEG_INF / 2)[:, None], p, 0.0)
+    m_prev = ms_ref[...]
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    p = jnp.where(m_new > NEG_INF / 2, p, 0.0)
     corr = jnp.exp(m_prev - m_new)
-    ls_ref[:, 0] = ls_ref[:, 0] * corr + p.sum(axis=-1)
-    pv = jnp.dot(p, v_ref[0].astype(jnp.float32),
-                 preferred_element_type=jnp.float32)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + pv
-    ms_ref[:, 0] = m_new
+    ls_ref[...] = ls_ref[...] * corr + p.sum(axis=-1, keepdims=True)
+    pv = jnp.dot(p, v, preferred_element_type=F32)
+    if v_scale is not None:
+        pv = pv * v_scale
+    acc_ref[...] = acc_ref[...] * corr + pv
+    ms_ref[...] = m_new
 
-    @pl.when(j == n_kv - 1)
+
+def _flash_store(j, last, ot_ref, m_ref, l_ref, acc_ref, ms_ref, ls_ref):
+    @pl.when(j == last)
     def _store():
         ot_ref[0] = acc_ref[...]
-        m_ref[0] = ms_ref[:, 0]
-        l_ref[0] = ls_ref[:, 0]
+        m_ref[0] = ms_ref[...]
+        l_ref[0] = ls_ref[...]
 
 
-def _paged_decode_kernel(tbl_ref, cnt_ref, q_ref, k_ref, v_ref,
-                         ot_ref, m_ref, l_ref, acc_ref, ms_ref, ls_ref,
-                         *, scale, page_size, n_logical, kv_heads):
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        ms_ref[...] = jnp.full_like(ms_ref, NEG_INF)
-        ls_ref[...] = jnp.zeros_like(ls_ref)
-
-    count = cnt_ref[b // kv_heads, j]                   # tokens valid here
-    q = q_ref[0].astype(jnp.float32) * scale            # (G, Dh)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)           # (page_size, Dh)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    idx = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    # count == 0 masks the whole page: a logical page past the slot's
-    # length, an unallocated table entry, or (sharded) a page owned by
-    # another shard's slab — the caller folds all three into counts
-    s = jnp.where(idx < count, s, NEG_INF)
-    m_prev = ms_ref[:, 0]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))         # (G,)
-    p = jnp.exp(s - m_new[:, None])
-    p = jnp.where((m_new > NEG_INF / 2)[:, None], p, 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    ls_ref[:, 0] = ls_ref[:, 0] * corr + p.sum(axis=-1)
-    pv = jnp.dot(p, v_ref[0, :, 0, :].astype(jnp.float32),
-                 preferred_element_type=jnp.float32)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + pv
-    ms_ref[:, 0] = m_new
-
-    @pl.when(j == n_logical - 1)
-    def _store():
-        ot_ref[0] = acc_ref[...]
-        m_ref[0] = ms_ref[:, 0]
-        l_ref[0] = ls_ref[:, 0]
-
-
-def _mla_decode_kernel(qa_ref, qr_ref, ckv_ref, kr_ref, lens_ref,
-                       ot_ref, m_ref, l_ref, acc_ref, ms_ref, ls_ref,
-                       *, scale, bkv, t_valid, n_kv):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        ms_ref[...] = jnp.full_like(ms_ref, NEG_INF)
-        ls_ref[...] = jnp.zeros_like(ls_ref)
-
-    cur = lens_ref[0, 0]
-    pos0 = lens_ref[0, 1]
-    qa = qa_ref[0].astype(jnp.float32) * scale          # (H, r)
-    qr = qr_ref[0].astype(jnp.float32) * scale          # (H, rope)
-    ckv = ckv_ref[0].astype(jnp.float32)                # (bkv, r)
-    kr = kr_ref[0].astype(jnp.float32)                  # (bkv, rope)
-    # split-operand score: the latent block carries BOTH the key's nope
-    # part (absorbed) and the values, the rope block only its 64-ish
-    # rope features — no k_cat/v_cat concat copies, no value zero-pad
-    s = jax.lax.dot_general(qa, ckv, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    s = s + jax.lax.dot_general(qr, kr, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+def _dense_valid(s, j, bkv, t_valid, lens_ref):
+    """Mask of a dense-cache block: ``idx < t_valid`` drops the
+    block-multiple padding, ``pos0 + idx < cur`` positions not yet
+    written (sharded, other shards' positions never appear here)."""
     idx = j * bkv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    valid = (idx < t_valid) & (pos0 + idx < cur)
-    s = jnp.where(valid, s, NEG_INF)
-    m_prev = ms_ref[:, 0]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))         # (H,)
-    p = jnp.exp(s - m_new[:, None])
-    p = jnp.where((m_new > NEG_INF / 2)[:, None], p, 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    ls_ref[:, 0] = ls_ref[:, 0] * corr + p.sum(axis=-1)
-    pv = jnp.dot(p, ckv, preferred_element_type=jnp.float32)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + pv
-    ms_ref[:, 0] = m_new
-
-    @pl.when(j == n_kv - 1)
-    def _store():
-        ot_ref[0] = acc_ref[...]
-        m_ref[0] = ms_ref[:, 0]
-        l_ref[0] = ls_ref[:, 0]
+    return (idx < t_valid) & (lens_ref[0, 1] + idx < lens_ref[0, 0])
 
 
-def vwr_mla_flash_decode_p(q_abs: jax.Array, q_rope: jax.Array,
-                           c_kv: jax.Array, k_rope: jax.Array,
-                           lens: jax.Array, *, scale: float, bkv: int,
-                           t_valid: int, interpret: bool = False):
-    """Split-operand absorbed-MLA flash decode (the MQA KV=1 problem).
+def _count_valid(s, count):
+    """Mask of a page: its first ``count`` positions are valid (0 masks
+    a page past the slot's length, an unallocated table entry, or —
+    sharded — a page another shard owns)."""
+    return jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) < count
 
-    The latent and rope-key caches ride in as SEPARATE BlockSpec
-    operands: each grid step stages one (bkv x r) latent block and one
-    (bkv x rope) rope block, computes ``s = q_abs.c_kv + q_rope.k_rope``
-    and takes values directly from the latent block — so the staged
-    cache bytes per token are exactly ``r + rope`` features/position,
-    vs the concatenated-MQA view's ``2*(r + rope)`` (one k_cat copy +
-    one zero-padded v_cat copy of the cache, rebuilt every step).
 
-    q_abs: (B, H, r) nope queries folded through wk_b; q_rope: (B, H,
-    rope); c_kv: (B, Tp, r); k_rope: (B, Tp, rope), Tp padded to a bkv
-    multiple; lens: (1, 2) int32 [cur_len, pos0]; ``scale`` the
-    absorbed-MLA 1/sqrt(nope+rope).  Returns fp32 (o_tilde (B, H, r),
-    m (B, H), l (B, H)) — the same unnormalized combine contract as
-    ``vwr_flash_decode_p``.
-    """
-    B, H, r = q_abs.shape
-    rope = q_rope.shape[2]
-    Tp = c_kv.shape[1]
-    assert q_rope.shape == (B, H, rope)
-    assert c_kv.shape == (B, Tp, r) and k_rope.shape == (B, Tp, rope)
+def _scratch(rows, width):
+    return [pltpu.VMEM((rows, width), F32), pltpu.VMEM((rows, 1), F32),
+            pltpu.VMEM((rows, 1), F32)]
+
+
+def _out_shapes(n, rows, width):
+    return [jax.ShapeDtypeStruct((n, rows, width), F32),
+            jax.ShapeDtypeStruct((n, rows, 1), F32),
+            jax.ShapeDtypeStruct((n, rows, 1), F32)]
+
+
+def _out_specs(rows, width, index_map):
+    """o_tilde (1, rows, width) + m, l (1, rows, 1) blocks, all at
+    ``index_map``'s leading index."""
+    return [pl.BlockSpec((1, rows, width), index_map),
+            pl.BlockSpec((1, rows, 1), index_map),
+            pl.BlockSpec((1, rows, 1), index_map)]
+
+
+def _squeeze_stats(o_t, m, l):
+    return o_t, m[..., 0], l[..., 0]
+
+
+# ----------------------------------------------------------------------
+# GQA: dense cache, paged pool, chunk prefix — bf16 and int8
+# ----------------------------------------------------------------------
+
+def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, ot_ref, m_ref, l_ref,
+                   acc_ref, ms_ref, ls_ref, *, scale, bkv, t_valid, n_kv):
+    j = pl.program_id(1)
+    _flash_init(j, acc_ref, ms_ref, ls_ref)
+    s = _scores(q_ref[0].astype(F32) * scale, k_ref[0].astype(F32))
+    _flash_update(s, _dense_valid(s, j, bkv, t_valid, lens_ref),
+                  v_ref[0].astype(F32), acc_ref, ms_ref, ls_ref)
+    _flash_store(j, n_kv - 1, ot_ref, m_ref, l_ref, acc_ref, ms_ref,
+                 ls_ref)
+
+
+def vwr_flash_decode_p(q: jax.Array, k: jax.Array, v: jax.Array,
+                       lens: jax.Array, *, bkv: int, t_valid: int,
+                       interpret: bool = False):
+    """q: (B*KV, G, Dh); k, v: (B*KV, Tp, Dh) flattened kv heads, Tp
+    padded to a bkv multiple; lens: (1, 2) int32 [cur_len, pos0]
+    (dynamic — decode runs inside a jitted generation loop).  Grid:
+    (B*KV, kv-blocks), kv innermost (sequential).  Returns (o_tilde
+    (BKV, G, Dh) f32, m (BKV, G) f32, l (BKV, G) f32)."""
+    BKV, G, D = q.shape
+    Tp = k.shape[1]
+    assert k.shape == (BKV, Tp, D) and v.shape == k.shape
     assert Tp % bkv == 0, (Tp, bkv)
     n_kv = Tp // bkv
-    kernel = functools.partial(_mla_decode_kernel, scale=scale, bkv=bkv,
-                               t_valid=t_valid, n_kv=n_kv)
-    f32 = jnp.float32
-    return pl.pallas_call(
-        kernel,
-        grid=(B, n_kv),
-        in_specs=[
-            pl.BlockSpec((1, H, r), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, H, rope), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, bkv, r), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, bkv, rope), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, 2), lambda b, j: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, H, r), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, H), lambda b, j: (b, 0)),
-            pl.BlockSpec((1, H), lambda b, j: (b, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, r), f32),
-            jax.ShapeDtypeStruct((B, H), f32),
-            jax.ShapeDtypeStruct((B, H), f32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((H, r), f32),
-            pltpu.VMEM((H, 1), f32),
-            pltpu.VMEM((H, 1), f32),
-        ],
-        compiler_params=tpu_compiler_params("parallel", "arbitrary"),
-        interpret=interpret,
-    )(q_abs, q_rope, c_kv, k_rope, lens)
-
-
-def _mla_paged_decode_kernel(tbl_ref, cnt_ref, qa_ref, qr_ref, ckv_ref,
-                             kr_ref, ot_ref, m_ref, l_ref, acc_ref,
-                             ms_ref, ls_ref, *, scale, n_logical):
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        ms_ref[...] = jnp.full_like(ms_ref, NEG_INF)
-        ls_ref[...] = jnp.zeros_like(ls_ref)
-
-    count = cnt_ref[b, j]                               # tokens valid here
-    qa = qa_ref[0].astype(jnp.float32) * scale          # (H, r)
-    qr = qr_ref[0].astype(jnp.float32) * scale          # (H, rope)
-    ckv = ckv_ref[0].astype(jnp.float32)                # (ps, r)
-    kr = kr_ref[0].astype(jnp.float32)                  # (ps, rope)
-    s = jax.lax.dot_general(qa, ckv, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    s = s + jax.lax.dot_general(qr, kr, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-    idx = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(idx < count, s, NEG_INF)
-    m_prev = ms_ref[:, 0]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))         # (H,)
-    p = jnp.exp(s - m_new[:, None])
-    p = jnp.where((m_new > NEG_INF / 2)[:, None], p, 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    ls_ref[:, 0] = ls_ref[:, 0] * corr + p.sum(axis=-1)
-    pv = jnp.dot(p, ckv, preferred_element_type=jnp.float32)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + pv
-    ms_ref[:, 0] = m_new
-
-    @pl.when(j == n_logical - 1)
-    def _store():
-        ot_ref[0] = acc_ref[...]
-        m_ref[0] = ms_ref[:, 0]
-        l_ref[0] = ls_ref[:, 0]
-
-
-def vwr_mla_paged_flash_decode_p(q_abs: jax.Array, q_rope: jax.Array,
-                                 ckv_pool: jax.Array,
-                                 krope_pool: jax.Array,
-                                 table: jax.Array, counts: jax.Array, *,
-                                 scale: float, interpret: bool = False):
-    """Split-operand absorbed-MLA flash decode over paged latent pools.
-
-    The paged sibling of ``vwr_mla_flash_decode_p``: the block table
-    rides in as a scalar-prefetch operand and each (slot, logical-page)
-    grid step stages ONE physical latent page (page_size x r) plus its
-    rope page (page_size x rope) — the concat-MQA view instead rebuilt
-    k_cat/v_cat copies of the whole POOL every decode step.
-
-    q_abs: (B, H, r); q_rope: (B, H, rope); ckv_pool: (n_pages,
-    page_size, r); krope_pool: (n_pages, page_size, rope); table,
-    counts: (B, max_pages) int32, table pre-clamped to [0, n_pages).
-    Returns fp32 (o_tilde (B, H, r), m (B, H), l (B, H)).
-    """
-    B, H, r = q_abs.shape
-    rope = q_rope.shape[2]
-    n_pages, ps, _ = ckv_pool.shape
-    assert krope_pool.shape == (n_pages, ps, rope)
-    Bt, J = table.shape
-    assert Bt == B and counts.shape == (B, J), (table.shape, B)
-    kernel = functools.partial(_mla_paged_decode_kernel, scale=scale,
-                               n_logical=J)
-    f32 = jnp.float32
+    kernel = functools.partial(_decode_kernel, scale=1.0 / (D ** 0.5),
+                               bkv=bkv, t_valid=t_valid, n_kv=n_kv)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,          # table, counts
-        grid=(B, J),
+        num_scalar_prefetch=1,              # lens
+        grid=(BKV, n_kv),
         in_specs=[
-            pl.BlockSpec((1, H, r), lambda b, j, tbl, cnt: (b, 0, 0)),
-            pl.BlockSpec((1, H, rope), lambda b, j, tbl, cnt: (b, 0, 0)),
-            pl.BlockSpec((1, ps, r),
-                         lambda b, j, tbl, cnt: (tbl[b, j], 0, 0)),
-            pl.BlockSpec((1, ps, rope),
-                         lambda b, j, tbl, cnt: (tbl[b, j], 0, 0)),
+            pl.BlockSpec((1, G, D), lambda b, j, ln: (b, 0, 0)),
+            pl.BlockSpec((1, bkv, D), lambda b, j, ln: (b, j, 0)),
+            pl.BlockSpec((1, bkv, D), lambda b, j, ln: (b, j, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, H, r), lambda b, j, tbl, cnt: (b, 0, 0)),
-            pl.BlockSpec((1, H), lambda b, j, tbl, cnt: (b, 0)),
-            pl.BlockSpec((1, H), lambda b, j, tbl, cnt: (b, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((H, r), f32),
-            pltpu.VMEM((H, 1), f32),
-            pltpu.VMEM((H, 1), f32),
-        ],
+        out_specs=_out_specs(G, D, lambda b, j, ln: (b, 0, 0)),
+        scratch_shapes=_scratch(G, D),
     )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, r), f32),
-            jax.ShapeDtypeStruct((B, H), f32),
-            jax.ShapeDtypeStruct((B, H), f32),
-        ],
-        compiler_params=tpu_compiler_params("parallel", "arbitrary"),
+    return _squeeze_stats(*pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=_out_shapes(BKV, G, D),
+        compiler_params=_params("parallel", "arbitrary"),
         interpret=interpret,
-    )(table, counts, q_abs, q_rope, ckv_pool, krope_pool)
+    )(lens, q, k, v))
 
 
-def vwr_paged_flash_decode_p(q: jax.Array, k_pool: jax.Array,
-                             v_pool: jax.Array, table: jax.Array,
-                             counts: jax.Array, *,
-                             interpret: bool = False):
-    """Flash-decode over a paged KV pool, one staged page per grid step.
-
-    The block table IS the transaction schedule: it rides in as a
-    scalar-prefetch operand, so each (slot, logical-page) grid step's
-    BlockSpec index map resolves ``table[slot, j]`` *before* the DMA
-    fires and stages exactly that physical (page_size x Dh) page in
-    VMEM — the gather never materializes in HBM.  ``counts[slot, j]``
-    is the number of valid tokens in that page (0 masks the page
-    entirely — length overrun, unallocated entry, or a page owned by
-    another shard's slab).
-
-    q: (B*KV, G, Dh); k_pool, v_pool: (n_pages, page_size, KV, Dh);
-    table, counts: (B, max_pages) int32, table pre-clamped to
-    [0, n_pages).  Returns (o_tilde (BKV, G, Dh) f32, m (BKV, G) f32,
-    l (BKV, G) f32) — the same unnormalized combine contract as
-    ``vwr_flash_decode_p``.
-    """
-    BKV, G, D = q.shape
-    n_pages, ps, KV, Dp = k_pool.shape
-    assert v_pool.shape == k_pool.shape and Dp == D
-    assert BKV % KV == 0, (BKV, KV)
-    B, J = table.shape
-    assert counts.shape == (B, J) and B * KV == BKV, (table.shape, BKV)
-    scale = 1.0 / (D ** 0.5)
-    kernel = functools.partial(_paged_decode_kernel, scale=scale,
-                               page_size=ps, n_logical=J, kv_heads=KV)
-    f32 = jnp.float32
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,          # table, counts
-        grid=(BKV, J),
-        in_specs=[
-            pl.BlockSpec((1, G, D), lambda b, j, tbl, cnt: (b, 0, 0)),
-            pl.BlockSpec((1, ps, 1, D),
-                         lambda b, j, tbl, cnt:
-                         (tbl[b // KV, j], 0, b % KV, 0)),
-            pl.BlockSpec((1, ps, 1, D),
-                         lambda b, j, tbl, cnt:
-                         (tbl[b // KV, j], 0, b % KV, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, G, D), lambda b, j, tbl, cnt: (b, 0, 0)),
-            pl.BlockSpec((1, G), lambda b, j, tbl, cnt: (b, 0)),
-            pl.BlockSpec((1, G), lambda b, j, tbl, cnt: (b, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((G, D), f32),
-            pltpu.VMEM((G, 1), f32),
-            pltpu.VMEM((G, 1), f32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((BKV, G, D), f32),
-            jax.ShapeDtypeStruct((BKV, G), f32),
-            jax.ShapeDtypeStruct((BKV, G), f32),
-        ],
-        compiler_params=tpu_compiler_params("parallel", "arbitrary"),
-        interpret=interpret,
-    )(table, counts, q, k_pool, v_pool)
-
-
-# ======================================================================
-# q8 variants: int8 caches/pools with fp32 scale sidecars
-# ======================================================================
-#
-# The staged cache block stays int8 all the way into VMEM — HBM traffic
-# per token is 1 byte/feature instead of 2 (bf16) — and dequantization
-# happens INSIDE the kernel on the staged block.  Because every scale
-# is constant over the staged block (per sequence for dense, per
-# physical page for paged), the dequant multiplies hoist through the
-# dots exactly: ``q.(k*s) == (q.k)*s`` and ``p@(v*s) == (p@v)*s``, so
-# the int8 path adds one scalar multiply per staged block, not one per
-# staged element.  Scales ride as scalar-prefetch operands next to the
-# block table, resolved by the same index arithmetic as the page DMA.
-# Softmax/accumulate math is fp32 throughout, as in the bf16 kernels.
-
-
-def _decode_kernel_q8(ks_ref, vs_ref, q_ref, k_ref, v_ref, lens_ref,
+def _decode_kernel_q8(lens_ref, ks_ref, vs_ref, q_ref, k_ref, v_ref,
                       ot_ref, m_ref, l_ref, acc_ref, ms_ref, ls_ref, *,
                       scale, bkv, t_valid, n_kv):
     b = pl.program_id(0)
     j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        ms_ref[...] = jnp.full_like(ms_ref, NEG_INF)
-        ls_ref[...] = jnp.zeros_like(ls_ref)
-
-    cur = lens_ref[0, 0]
-    pos0 = lens_ref[0, 1]
-    ks = ks_ref[b]                                      # per-row scales
-    vs = vs_ref[b]
-    q = q_ref[0].astype(jnp.float32) * scale            # (G, Dh)
-    k = k_ref[0].astype(jnp.float32)                    # (bkv, Dh) int8
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * ks
-    idx = j * bkv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    valid = (idx < t_valid) & (pos0 + idx < cur)
-    s = jnp.where(valid, s, NEG_INF)
-    m_prev = ms_ref[:, 0]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))         # (G,)
-    p = jnp.exp(s - m_new[:, None])
-    p = jnp.where((m_new > NEG_INF / 2)[:, None], p, 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    ls_ref[:, 0] = ls_ref[:, 0] * corr + p.sum(axis=-1)
-    pv = jnp.dot(p, v_ref[0].astype(jnp.float32),
-                 preferred_element_type=jnp.float32) * vs
-    acc_ref[...] = acc_ref[...] * corr[:, None] + pv
-    ms_ref[:, 0] = m_new
-
-    @pl.when(j == n_kv - 1)
-    def _store():
-        ot_ref[0] = acc_ref[...]
-        m_ref[0] = ms_ref[:, 0]
-        l_ref[0] = ls_ref[:, 0]
+    _flash_init(j, acc_ref, ms_ref, ls_ref)
+    s = _scores(q_ref[0].astype(F32) * scale,
+                k_ref[0].astype(F32)) * ks_ref[b]
+    _flash_update(s, _dense_valid(s, j, bkv, t_valid, lens_ref),
+                  v_ref[0].astype(F32), acc_ref, ms_ref, ls_ref,
+                  v_scale=vs_ref[b])
+    _flash_store(j, n_kv - 1, ot_ref, m_ref, l_ref, acc_ref, ms_ref,
+                 ls_ref)
 
 
 def vwr_flash_decode_q8_p(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -461,82 +210,109 @@ def vwr_flash_decode_q8_p(q: jax.Array, k: jax.Array, v: jax.Array,
     assert k_scale.shape == (BKV,) and v_scale.shape == (BKV,)
     assert Tp % bkv == 0, (Tp, bkv)
     n_kv = Tp // bkv
-    scale = 1.0 / (D ** 0.5)
-    kernel = functools.partial(_decode_kernel_q8, scale=scale, bkv=bkv,
-                               t_valid=t_valid, n_kv=n_kv)
-    f32 = jnp.float32
+    kernel = functools.partial(_decode_kernel_q8, scale=1.0 / (D ** 0.5),
+                               bkv=bkv, t_valid=t_valid, n_kv=n_kv)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,          # k_scale, v_scale
+        num_scalar_prefetch=3,              # lens, k_scale, v_scale
         grid=(BKV, n_kv),
         in_specs=[
-            pl.BlockSpec((1, G, D), lambda b, j, ks, vs: (b, 0, 0)),
-            pl.BlockSpec((1, bkv, D), lambda b, j, ks, vs: (b, j, 0)),
-            pl.BlockSpec((1, bkv, D), lambda b, j, ks, vs: (b, j, 0)),
-            pl.BlockSpec((1, 2), lambda b, j, ks, vs: (0, 0)),
+            pl.BlockSpec((1, G, D), lambda b, j, *_: (b, 0, 0)),
+            pl.BlockSpec((1, bkv, D), lambda b, j, *_: (b, j, 0)),
+            pl.BlockSpec((1, bkv, D), lambda b, j, *_: (b, j, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, G, D), lambda b, j, ks, vs: (b, 0, 0)),
-            pl.BlockSpec((1, G), lambda b, j, ks, vs: (b, 0)),
-            pl.BlockSpec((1, G), lambda b, j, ks, vs: (b, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((G, D), f32),
-            pltpu.VMEM((G, 1), f32),
-            pltpu.VMEM((G, 1), f32),
-        ],
+        out_specs=_out_specs(G, D, lambda b, j, *_: (b, 0, 0)),
+        scratch_shapes=_scratch(G, D),
     )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((BKV, G, D), f32),
-            jax.ShapeDtypeStruct((BKV, G), f32),
-            jax.ShapeDtypeStruct((BKV, G), f32),
-        ],
-        compiler_params=tpu_compiler_params("parallel", "arbitrary"),
+    return _squeeze_stats(*pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=_out_shapes(BKV, G, D),
+        compiler_params=_params("parallel", "arbitrary"),
         interpret=interpret,
-    )(k_scale, v_scale, q, k, v, lens)
+    )(lens, k_scale, v_scale, q, k, v))
+
+
+def _paged_decode_kernel(tbl_ref, cnt_ref, q_ref, k_ref, v_ref,
+                         ot_ref, m_ref, l_ref, acc_ref, ms_ref, ls_ref,
+                         *, scale, n_logical, kv_heads):
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    _flash_init(j, acc_ref, ms_ref, ls_ref)
+    s = _scores(q_ref[0].astype(F32) * scale, k_ref[0, 0].astype(F32))
+    _flash_update(s, _count_valid(s, cnt_ref[b // kv_heads, j]),
+                  v_ref[0, 0].astype(F32), acc_ref, ms_ref, ls_ref)
+    _flash_store(j, n_logical - 1, ot_ref, m_ref, l_ref, acc_ref, ms_ref,
+                 ls_ref)
+
+
+def _page_spec(ps, D, KV, page_of):
+    """One head's (ps, Dh) tile of the physical page ``page_of(b, j,
+    tbl)`` of a head-major (n_pages, KV, ps, Dh) pool, for grid row
+    b = slot * KV + head."""
+    return pl.BlockSpec(
+        (1, 1, ps, D),
+        lambda b, j, tbl, *_: (page_of(b, j, tbl), b % KV, 0, 0))
+
+
+def vwr_paged_flash_decode_p(q: jax.Array, k_pool: jax.Array,
+                             v_pool: jax.Array, table: jax.Array,
+                             counts: jax.Array, *,
+                             interpret: bool = False):
+    """Flash-decode over a paged KV pool, one staged page per grid step.
+
+    The block table IS the transaction schedule: it rides in as a
+    scalar-prefetch operand, so each (slot*head, logical-page) grid
+    step's BlockSpec index map resolves ``table[slot, j]`` *before* the
+    DMA fires and stages exactly that physical page's (page_size x Dh)
+    tile for the head — the gather never materializes in HBM.
+    ``counts[slot, j]`` is the number of valid tokens in that page.
+
+    q: (B*KV, G, Dh); k_pool, v_pool: head-major (n_pages, KV,
+    page_size, Dh); table, counts: (B, max_pages) int32, table
+    pre-clamped to [0, n_pages).  Returns (o_tilde (BKV, G, Dh),
+    m (BKV, G), l (BKV, G)) fp32 — the ``vwr_flash_decode_p`` contract.
+    """
+    BKV, G, D = q.shape
+    n_pages, KV, ps, Dp = k_pool.shape
+    assert v_pool.shape == k_pool.shape and Dp == D
+    B, J = table.shape
+    assert counts.shape == (B, J) and B * KV == BKV, (table.shape, BKV)
+    kernel = functools.partial(_paged_decode_kernel,
+                               scale=1.0 / (D ** 0.5), n_logical=J,
+                               kv_heads=KV)
+    page_of = lambda b, j, tbl: tbl[b // KV, j]             # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,              # table, counts
+        grid=(BKV, J),
+        in_specs=[
+            pl.BlockSpec((1, G, D), lambda b, j, *_: (b, 0, 0)),
+            _page_spec(ps, D, KV, page_of),
+            _page_spec(ps, D, KV, page_of),
+        ],
+        out_specs=_out_specs(G, D, lambda b, j, *_: (b, 0, 0)),
+        scratch_shapes=_scratch(G, D),
+    )
+    return _squeeze_stats(*pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=_out_shapes(BKV, G, D),
+        compiler_params=_params("parallel", "arbitrary"),
+        interpret=interpret,
+    )(table, counts, q, k_pool, v_pool))
 
 
 def _paged_decode_kernel_q8(tbl_ref, cnt_ref, ks_ref, vs_ref, q_ref,
                             k_ref, v_ref, ot_ref, m_ref, l_ref, acc_ref,
-                            ms_ref, ls_ref, *, scale, page_size,
-                            n_logical, kv_heads):
+                            ms_ref, ls_ref, *, scale, n_logical,
+                            kv_heads):
     b = pl.program_id(0)
     j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        ms_ref[...] = jnp.full_like(ms_ref, NEG_INF)
-        ls_ref[...] = jnp.zeros_like(ls_ref)
-
-    count = cnt_ref[b // kv_heads, j]                   # tokens valid here
+    _flash_init(j, acc_ref, ms_ref, ls_ref)
     page = tbl_ref[b // kv_heads, j]
-    ks = ks_ref[page, b % kv_heads]                     # per-page per-head
-    vs = vs_ref[page, b % kv_heads]
-    q = q_ref[0].astype(jnp.float32) * scale            # (G, Dh)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)           # (ps, Dh) int8
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * ks
-    idx = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(idx < count, s, NEG_INF)
-    m_prev = ms_ref[:, 0]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))         # (G,)
-    p = jnp.exp(s - m_new[:, None])
-    p = jnp.where((m_new > NEG_INF / 2)[:, None], p, 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    ls_ref[:, 0] = ls_ref[:, 0] * corr + p.sum(axis=-1)
-    pv = jnp.dot(p, v_ref[0, :, 0, :].astype(jnp.float32),
-                 preferred_element_type=jnp.float32) * vs
-    acc_ref[...] = acc_ref[...] * corr[:, None] + pv
-    ms_ref[:, 0] = m_new
-
-    @pl.when(j == n_logical - 1)
-    def _store():
-        ot_ref[0] = acc_ref[...]
-        m_ref[0] = ms_ref[:, 0]
-        l_ref[0] = ls_ref[:, 0]
+    head = b % kv_heads
+    s = _scores(q_ref[0].astype(F32) * scale,
+                k_ref[0, 0].astype(F32)) * ks_ref[page, head]
+    _flash_update(s, _count_valid(s, cnt_ref[b // kv_heads, j]),
+                  v_ref[0, 0].astype(F32), acc_ref, ms_ref, ls_ref,
+                  v_scale=vs_ref[page, head])
+    _flash_store(j, n_logical - 1, ot_ref, m_ref, l_ref, acc_ref, ms_ref,
+                 ls_ref)
 
 
 def vwr_paged_flash_decode_q8_p(q: jax.Array, k_pool: jax.Array,
@@ -546,104 +322,251 @@ def vwr_paged_flash_decode_q8_p(q: jax.Array, k_pool: jax.Array,
                                 interpret: bool = False):
     """Flash decode over int8 page pools with per-page per-head scales.
 
-    k_pool, v_pool: int8 (n_pages, page_size, KV, Dh); k_scale,
-    v_scale: fp32 (n_pages, KV) sidecars resolved through the SAME
-    ``table[slot, j]`` scalar-prefetch indirection as the page DMA.
-    Everything else matches ``vwr_paged_flash_decode_p``.
+    k_pool, v_pool: int8 head-major (n_pages, KV, page_size, Dh);
+    k_scale, v_scale: fp32 (n_pages, KV) sidecars resolved through the
+    SAME ``table[slot, j]`` scalar-prefetch indirection as the page
+    DMA.  Everything else matches ``vwr_paged_flash_decode_p``.
     """
     BKV, G, D = q.shape
-    n_pages, ps, KV, Dp = k_pool.shape
+    n_pages, KV, ps, Dp = k_pool.shape
     assert v_pool.shape == k_pool.shape and Dp == D
     assert k_scale.shape == (n_pages, KV), (k_scale.shape, k_pool.shape)
     assert v_scale.shape == (n_pages, KV)
-    assert BKV % KV == 0, (BKV, KV)
     B, J = table.shape
     assert counts.shape == (B, J) and B * KV == BKV, (table.shape, BKV)
-    scale = 1.0 / (D ** 0.5)
-    kernel = functools.partial(_paged_decode_kernel_q8, scale=scale,
-                               page_size=ps, n_logical=J, kv_heads=KV)
-    f32 = jnp.float32
+    kernel = functools.partial(_paged_decode_kernel_q8,
+                               scale=1.0 / (D ** 0.5), n_logical=J,
+                               kv_heads=KV)
+    page_of = lambda b, j, tbl: tbl[b // KV, j]             # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,          # table, counts, k_scale, v_scale
         grid=(BKV, J),
         in_specs=[
-            pl.BlockSpec((1, G, D),
-                         lambda b, j, tbl, cnt, ks, vs: (b, 0, 0)),
-            pl.BlockSpec((1, ps, 1, D),
-                         lambda b, j, tbl, cnt, ks, vs:
-                         (tbl[b // KV, j], 0, b % KV, 0)),
-            pl.BlockSpec((1, ps, 1, D),
-                         lambda b, j, tbl, cnt, ks, vs:
-                         (tbl[b // KV, j], 0, b % KV, 0)),
+            pl.BlockSpec((1, G, D), lambda b, j, *_: (b, 0, 0)),
+            _page_spec(ps, D, KV, page_of),
+            _page_spec(ps, D, KV, page_of),
         ],
-        out_specs=[
-            pl.BlockSpec((1, G, D),
-                         lambda b, j, tbl, cnt, ks, vs: (b, 0, 0)),
-            pl.BlockSpec((1, G), lambda b, j, tbl, cnt, ks, vs: (b, 0)),
-            pl.BlockSpec((1, G), lambda b, j, tbl, cnt, ks, vs: (b, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((G, D), f32),
-            pltpu.VMEM((G, 1), f32),
-            pltpu.VMEM((G, 1), f32),
-        ],
+        out_specs=_out_specs(G, D, lambda b, j, *_: (b, 0, 0)),
+        scratch_shapes=_scratch(G, D),
     )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((BKV, G, D), f32),
-            jax.ShapeDtypeStruct((BKV, G), f32),
-            jax.ShapeDtypeStruct((BKV, G), f32),
-        ],
-        compiler_params=tpu_compiler_params("parallel", "arbitrary"),
+    return _squeeze_stats(*pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=_out_shapes(BKV, G, D),
+        compiler_params=_params("parallel", "arbitrary"),
         interpret=interpret,
-    )(table, counts, k_scale, v_scale, q, k_pool, v_pool)
+    )(table, counts, k_scale, v_scale, q, k_pool, v_pool))
 
 
-def _mla_decode_kernel_q8(cs_ref, rs_ref, qa_ref, qr_ref, ckv_ref,
-                          kr_ref, lens_ref, ot_ref, m_ref, l_ref,
+# chunked prefill: a (C, d) query chunk against the paged pool.
+#
+# Chunked prefill attends C chunk queries (one in-flight prompt's next
+# slice) against the PRIOR pages of that prompt — earlier chunks and
+# prefix-cache hits already resident in the pool via the block table.
+# The payoff vs replaying the decode kernel C times: each prior page is
+# staged from HBM ONCE for all C queries (C·G rows ride the VMEM
+# resident block), so staged bytes per chunk are ~1/C of the per-row
+# decode cost.  The within-chunk causal self-attention block is a tiny
+# (C, C) problem handled outside (models.attention combines the two
+# partials with the flash merge), so these kernels mask only by the
+# per-page valid counts — which also lets dist.decode zero out pages a
+# shard does not own.
+
+def _chunk_prefix_kernel(tbl_ref, cnt_ref, q_ref, k_ref, v_ref,
+                         ot_ref, m_ref, l_ref, acc_ref, ms_ref, ls_ref,
+                         *, scale, n_logical):
+    j = pl.program_id(1)
+    _flash_init(j, acc_ref, ms_ref, ls_ref)
+    s = _scores(q_ref[0].astype(F32) * scale, k_ref[0, 0].astype(F32))
+    _flash_update(s, _count_valid(s, cnt_ref[j]),
+                  v_ref[0, 0].astype(F32), acc_ref, ms_ref, ls_ref)
+    _flash_store(j, n_logical - 1, ot_ref, m_ref, l_ref, acc_ref, ms_ref,
+                 ls_ref)
+
+
+def vwr_chunk_prefix_attend_p(q: jax.Array, k_pool: jax.Array,
+                              v_pool: jax.Array, table: jax.Array,
+                              counts: jax.Array, *,
+                              interpret: bool = False):
+    """Chunk-prefix flash attention over the paged pool.
+
+    q: (KV, C*G, D) chunk queries flattened per KV head (C = chunk
+    tokens, G = H // KV); k_pool, v_pool: head-major (n_pages, KV, ps,
+    D); table: (J,) physical page ids of the chunk's PRIOR pages in
+    prefix order; counts: (J,) valid tokens per page (page_size for
+    full prior pages, 0 for pages a shard does not own).  Returns fp32
+    partials (o_tilde (KV, C*G, D), m (KV, C*G), l (KV, C*G)) under the
+    shared flash combine contract.
+    """
+    KV, CG, D = q.shape
+    n_pages, KVp, ps, _ = k_pool.shape
+    assert KVp == KV, (KVp, KV)
+    J, = table.shape
+    assert counts.shape == (J,), (counts.shape, J)
+    kernel = functools.partial(_chunk_prefix_kernel,
+                               scale=1.0 / (D ** 0.5), n_logical=J)
+    page_of = lambda kv, j, tbl: tbl[j]                     # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,                  # table, counts
+        grid=(KV, J),
+        in_specs=[
+            pl.BlockSpec((1, CG, D), lambda kv, j, *_: (kv, 0, 0)),
+            _page_spec(ps, D, KV, page_of),
+            _page_spec(ps, D, KV, page_of),
+        ],
+        out_specs=_out_specs(CG, D, lambda kv, j, *_: (kv, 0, 0)),
+        scratch_shapes=_scratch(CG, D),
+    )
+    return _squeeze_stats(*pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=_out_shapes(KV, CG, D),
+        compiler_params=_params("parallel", "arbitrary"),
+        interpret=interpret,
+    )(table, counts, q, k_pool, v_pool))
+
+
+def _chunk_prefix_kernel_q8(tbl_ref, cnt_ref, ks_ref, vs_ref, q_ref,
+                            k_ref, v_ref, ot_ref, m_ref, l_ref,
+                            acc_ref, ms_ref, ls_ref, *, scale,
+                            n_logical):
+    kv = pl.program_id(0)
+    j = pl.program_id(1)
+    _flash_init(j, acc_ref, ms_ref, ls_ref)
+    page = tbl_ref[j]
+    s = _scores(q_ref[0].astype(F32) * scale,
+                k_ref[0, 0].astype(F32)) * ks_ref[page, kv]
+    _flash_update(s, _count_valid(s, cnt_ref[j]),
+                  v_ref[0, 0].astype(F32), acc_ref, ms_ref, ls_ref,
+                  v_scale=vs_ref[page, kv])
+    _flash_store(j, n_logical - 1, ot_ref, m_ref, l_ref, acc_ref, ms_ref,
+                 ls_ref)
+
+
+def vwr_chunk_prefix_attend_q8_p(q: jax.Array, k_pool: jax.Array,
+                                 v_pool: jax.Array, k_scale: jax.Array,
+                                 v_scale: jax.Array, table: jax.Array,
+                                 counts: jax.Array, *,
+                                 interpret: bool = False):
+    """``vwr_chunk_prefix_attend_p`` over int8 page pools with fp32
+    (n_pages, KV) scale sidecars, dequantized on the staged block."""
+    KV, CG, D = q.shape
+    n_pages, KVp, ps, _ = k_pool.shape
+    assert KVp == KV, (KVp, KV)
+    assert k_scale.shape == (n_pages, KV) and \
+        v_scale.shape == (n_pages, KV)
+    J, = table.shape
+    assert counts.shape == (J,), (counts.shape, J)
+    kernel = functools.partial(_chunk_prefix_kernel_q8,
+                               scale=1.0 / (D ** 0.5), n_logical=J)
+    page_of = lambda kv, j, tbl: tbl[j]                     # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,      # table, counts, k_scale, v_scale
+        grid=(KV, J),
+        in_specs=[
+            pl.BlockSpec((1, CG, D), lambda kv, j, *_: (kv, 0, 0)),
+            _page_spec(ps, D, KV, page_of),
+            _page_spec(ps, D, KV, page_of),
+        ],
+        out_specs=_out_specs(CG, D, lambda kv, j, *_: (kv, 0, 0)),
+        scratch_shapes=_scratch(CG, D),
+    )
+    return _squeeze_stats(*pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=_out_shapes(KV, CG, D),
+        compiler_params=_params("parallel", "arbitrary"),
+        interpret=interpret,
+    )(table, counts, k_scale, v_scale, q, k_pool, v_pool))
+
+
+# ----------------------------------------------------------------------
+# split-operand absorbed MLA (the MQA KV=1 problem): dense cache, paged
+# pool, chunk prefix — bf16 and int8
+# ----------------------------------------------------------------------
+#
+# The latent and rope-key caches ride in as SEPARATE BlockSpec
+# operands: each grid step stages one (rows x r) latent block and one
+# (rows x rope) rope block, computes ``s = q_abs.c_kv + q_rope.k_rope``
+# and takes values directly from the latent block — so the staged cache
+# bytes per token are exactly ``r + rope`` features/position, vs the
+# concatenated-MQA view's ``2*(r + rope)`` (one k_cat copy + one
+# zero-padded v_cat copy of the cache, rebuilt every step).
+
+def _mla_scores(qa, qr, ckv, kr, scale, cs=None, rs=None):
+    """Split-operand scores of one staged block: qa (rows, r) and qr
+    (rows, rope) queries against the (T, r) latent and (T, rope) rope
+    blocks; ``cs``/``rs`` the int8 blocks' scales."""
+    s_nope = _scores(qa.astype(F32) * scale, ckv)
+    s_rope = _scores(qr.astype(F32) * scale, kr)
+    if cs is not None:
+        s_nope, s_rope = s_nope * cs, s_rope * rs
+    return s_nope + s_rope
+
+
+def _mla_decode_kernel(lens_ref, qa_ref, qr_ref, ckv_ref, kr_ref,
+                       ot_ref, m_ref, l_ref, acc_ref, ms_ref, ls_ref,
+                       *, scale, bkv, t_valid, n_kv):
+    j = pl.program_id(1)
+    _flash_init(j, acc_ref, ms_ref, ls_ref)
+    ckv = ckv_ref[0].astype(F32)                        # (bkv, r)
+    s = _mla_scores(qa_ref[0], qr_ref[0], ckv, kr_ref[0].astype(F32),
+                    scale)
+    _flash_update(s, _dense_valid(s, j, bkv, t_valid, lens_ref), ckv,
+                  acc_ref, ms_ref, ls_ref)
+    _flash_store(j, n_kv - 1, ot_ref, m_ref, l_ref, acc_ref, ms_ref,
+                 ls_ref)
+
+
+def vwr_mla_flash_decode_p(q_abs: jax.Array, q_rope: jax.Array,
+                           c_kv: jax.Array, k_rope: jax.Array,
+                           lens: jax.Array, *, scale: float, bkv: int,
+                           t_valid: int, interpret: bool = False):
+    """Split-operand absorbed-MLA flash decode over a dense cache.
+
+    q_abs: (B, H, r) nope queries folded through wk_b; q_rope: (B, H,
+    rope); c_kv: (B, Tp, r); k_rope: (B, Tp, rope), Tp padded to a bkv
+    multiple; lens: (1, 2) int32 [cur_len, pos0]; ``scale`` the
+    absorbed-MLA 1/sqrt(nope+rope).  Returns fp32 (o_tilde (B, H, r),
+    m (B, H), l (B, H)) — the ``vwr_flash_decode_p`` contract.
+    """
+    B, H, r = q_abs.shape
+    rope = q_rope.shape[2]
+    Tp = c_kv.shape[1]
+    assert q_rope.shape == (B, H, rope)
+    assert c_kv.shape == (B, Tp, r) and k_rope.shape == (B, Tp, rope)
+    assert Tp % bkv == 0, (Tp, bkv)
+    n_kv = Tp // bkv
+    kernel = functools.partial(_mla_decode_kernel, scale=scale, bkv=bkv,
+                               t_valid=t_valid, n_kv=n_kv)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,              # lens
+        grid=(B, n_kv),
+        in_specs=[
+            pl.BlockSpec((1, H, r), lambda b, j, ln: (b, 0, 0)),
+            pl.BlockSpec((1, H, rope), lambda b, j, ln: (b, 0, 0)),
+            pl.BlockSpec((1, bkv, r), lambda b, j, ln: (b, j, 0)),
+            pl.BlockSpec((1, bkv, rope), lambda b, j, ln: (b, j, 0)),
+        ],
+        out_specs=_out_specs(H, r, lambda b, j, ln: (b, 0, 0)),
+        scratch_shapes=_scratch(H, r),
+    )
+    return _squeeze_stats(*pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=_out_shapes(B, H, r),
+        compiler_params=_params("parallel", "arbitrary"),
+        interpret=interpret,
+    )(lens, q_abs, q_rope, c_kv, k_rope))
+
+
+def _mla_decode_kernel_q8(lens_ref, cs_ref, rs_ref, qa_ref, qr_ref,
+                          ckv_ref, kr_ref, ot_ref, m_ref, l_ref,
                           acc_ref, ms_ref, ls_ref, *, scale, bkv,
                           t_valid, n_kv):
     b = pl.program_id(0)
     j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        ms_ref[...] = jnp.full_like(ms_ref, NEG_INF)
-        ls_ref[...] = jnp.zeros_like(ls_ref)
-
-    cur = lens_ref[0, 0]
-    pos0 = lens_ref[0, 1]
+    _flash_init(j, acc_ref, ms_ref, ls_ref)
     cs = cs_ref[b]                                      # latent scale
-    rs = rs_ref[b]                                      # rope-key scale
-    qa = qa_ref[0].astype(jnp.float32) * scale          # (H, r)
-    qr = qr_ref[0].astype(jnp.float32) * scale          # (H, rope)
-    ckv = ckv_ref[0].astype(jnp.float32)                # (bkv, r) int8
-    kr = kr_ref[0].astype(jnp.float32)                  # (bkv, rope) int8
-    s = jax.lax.dot_general(qa, ckv, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * cs
-    s = s + jax.lax.dot_general(qr, kr, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * rs
-    idx = j * bkv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    valid = (idx < t_valid) & (pos0 + idx < cur)
-    s = jnp.where(valid, s, NEG_INF)
-    m_prev = ms_ref[:, 0]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))         # (H,)
-    p = jnp.exp(s - m_new[:, None])
-    p = jnp.where((m_new > NEG_INF / 2)[:, None], p, 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    ls_ref[:, 0] = ls_ref[:, 0] * corr + p.sum(axis=-1)
-    pv = jnp.dot(p, ckv, preferred_element_type=jnp.float32) * cs
-    acc_ref[...] = acc_ref[...] * corr[:, None] + pv
-    ms_ref[:, 0] = m_new
-
-    @pl.when(j == n_kv - 1)
-    def _store():
-        ot_ref[0] = acc_ref[...]
-        m_ref[0] = ms_ref[:, 0]
-        l_ref[0] = ls_ref[:, 0]
+    ckv = ckv_ref[0].astype(F32)                        # (bkv, r) int8
+    s = _mla_scores(qa_ref[0], qr_ref[0], ckv, kr_ref[0].astype(F32),
+                    scale, cs, rs_ref[b])
+    _flash_update(s, _dense_valid(s, j, bkv, t_valid, lens_ref), ckv,
+                  acc_ref, ms_ref, ls_ref, v_scale=cs)
+    _flash_store(j, n_kv - 1, ot_ref, m_ref, l_ref, acc_ref, ms_ref,
+                 ls_ref)
 
 
 def vwr_mla_flash_decode_q8_p(q_abs: jax.Array, q_rope: jax.Array,
@@ -665,39 +588,82 @@ def vwr_mla_flash_decode_q8_p(q_abs: jax.Array, q_rope: jax.Array,
     n_kv = Tp // bkv
     kernel = functools.partial(_mla_decode_kernel_q8, scale=scale,
                                bkv=bkv, t_valid=t_valid, n_kv=n_kv)
-    f32 = jnp.float32
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,          # ckv_scale, krope_scale
+        num_scalar_prefetch=3,              # lens, ckv_scale, krope_scale
         grid=(B, n_kv),
         in_specs=[
-            pl.BlockSpec((1, H, r), lambda b, j, cs, rs: (b, 0, 0)),
-            pl.BlockSpec((1, H, rope), lambda b, j, cs, rs: (b, 0, 0)),
-            pl.BlockSpec((1, bkv, r), lambda b, j, cs, rs: (b, j, 0)),
-            pl.BlockSpec((1, bkv, rope), lambda b, j, cs, rs: (b, j, 0)),
-            pl.BlockSpec((1, 2), lambda b, j, cs, rs: (0, 0)),
+            pl.BlockSpec((1, H, r), lambda b, j, *_: (b, 0, 0)),
+            pl.BlockSpec((1, H, rope), lambda b, j, *_: (b, 0, 0)),
+            pl.BlockSpec((1, bkv, r), lambda b, j, *_: (b, j, 0)),
+            pl.BlockSpec((1, bkv, rope), lambda b, j, *_: (b, j, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, H, r), lambda b, j, cs, rs: (b, 0, 0)),
-            pl.BlockSpec((1, H), lambda b, j, cs, rs: (b, 0)),
-            pl.BlockSpec((1, H), lambda b, j, cs, rs: (b, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((H, r), f32),
-            pltpu.VMEM((H, 1), f32),
-            pltpu.VMEM((H, 1), f32),
-        ],
+        out_specs=_out_specs(H, r, lambda b, j, *_: (b, 0, 0)),
+        scratch_shapes=_scratch(H, r),
     )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, r), f32),
-            jax.ShapeDtypeStruct((B, H), f32),
-            jax.ShapeDtypeStruct((B, H), f32),
-        ],
-        compiler_params=tpu_compiler_params("parallel", "arbitrary"),
+    return _squeeze_stats(*pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=_out_shapes(B, H, r),
+        compiler_params=_params("parallel", "arbitrary"),
         interpret=interpret,
-    )(ckv_scale, krope_scale, q_abs, q_rope, c_kv, k_rope, lens)
+    )(lens, ckv_scale, krope_scale, q_abs, q_rope, c_kv, k_rope))
+
+
+def _mla_paged_decode_kernel(tbl_ref, cnt_ref, qa_ref, qr_ref, ckv_ref,
+                             kr_ref, ot_ref, m_ref, l_ref, acc_ref,
+                             ms_ref, ls_ref, *, scale, n_logical):
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    _flash_init(j, acc_ref, ms_ref, ls_ref)
+    ckv = ckv_ref[0].astype(F32)                        # (ps, r)
+    s = _mla_scores(qa_ref[0], qr_ref[0], ckv, kr_ref[0].astype(F32),
+                    scale)
+    _flash_update(s, _count_valid(s, cnt_ref[b, j]), ckv, acc_ref,
+                  ms_ref, ls_ref)
+    _flash_store(j, n_logical - 1, ot_ref, m_ref, l_ref, acc_ref, ms_ref,
+                 ls_ref)
+
+
+def vwr_mla_paged_flash_decode_p(q_abs: jax.Array, q_rope: jax.Array,
+                                 ckv_pool: jax.Array,
+                                 krope_pool: jax.Array,
+                                 table: jax.Array, counts: jax.Array, *,
+                                 scale: float, interpret: bool = False):
+    """Split-operand absorbed-MLA flash decode over paged latent pools.
+
+    The block table rides in as a scalar-prefetch operand and each
+    (slot, logical-page) grid step stages ONE physical latent page
+    (page_size x r) plus its rope page (page_size x rope).
+
+    q_abs: (B, H, r); q_rope: (B, H, rope); ckv_pool: (n_pages,
+    page_size, r); krope_pool: (n_pages, page_size, rope); table,
+    counts: (B, max_pages) int32, table pre-clamped to [0, n_pages).
+    Returns fp32 (o_tilde (B, H, r), m (B, H), l (B, H)).
+    """
+    B, H, r = q_abs.shape
+    rope = q_rope.shape[2]
+    n_pages, ps, _ = ckv_pool.shape
+    assert krope_pool.shape == (n_pages, ps, rope)
+    Bt, J = table.shape
+    assert Bt == B and counts.shape == (B, J), (table.shape, B)
+    kernel = functools.partial(_mla_paged_decode_kernel, scale=scale,
+                               n_logical=J)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,          # table, counts
+        grid=(B, J),
+        in_specs=[
+            pl.BlockSpec((1, H, r), lambda b, j, *_: (b, 0, 0)),
+            pl.BlockSpec((1, H, rope), lambda b, j, *_: (b, 0, 0)),
+            pl.BlockSpec((1, ps, r), lambda b, j, tbl, *_: (tbl[b, j], 0, 0)),
+            pl.BlockSpec((1, ps, rope),
+                         lambda b, j, tbl, *_: (tbl[b, j], 0, 0)),
+        ],
+        out_specs=_out_specs(H, r, lambda b, j, *_: (b, 0, 0)),
+        scratch_shapes=_scratch(H, r),
+    )
+    return _squeeze_stats(*pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=_out_shapes(B, H, r),
+        compiler_params=_params("parallel", "arbitrary"),
+        interpret=interpret,
+    )(table, counts, q_abs, q_rope, ckv_pool, krope_pool))
 
 
 def _mla_paged_decode_kernel_q8(tbl_ref, cnt_ref, cs_ref, rs_ref,
@@ -706,42 +672,16 @@ def _mla_paged_decode_kernel_q8(tbl_ref, cnt_ref, cs_ref, rs_ref,
                                 *, scale, n_logical):
     b = pl.program_id(0)
     j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        ms_ref[...] = jnp.full_like(ms_ref, NEG_INF)
-        ls_ref[...] = jnp.zeros_like(ls_ref)
-
-    count = cnt_ref[b, j]                               # tokens valid here
+    _flash_init(j, acc_ref, ms_ref, ls_ref)
     page = tbl_ref[b, j]
     cs = cs_ref[page]                                   # per-page scales
-    rs = rs_ref[page]
-    qa = qa_ref[0].astype(jnp.float32) * scale          # (H, r)
-    qr = qr_ref[0].astype(jnp.float32) * scale          # (H, rope)
-    ckv = ckv_ref[0].astype(jnp.float32)                # (ps, r) int8
-    kr = kr_ref[0].astype(jnp.float32)                  # (ps, rope) int8
-    s = jax.lax.dot_general(qa, ckv, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * cs
-    s = s + jax.lax.dot_general(qr, kr, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * rs
-    idx = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(idx < count, s, NEG_INF)
-    m_prev = ms_ref[:, 0]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))         # (H,)
-    p = jnp.exp(s - m_new[:, None])
-    p = jnp.where((m_new > NEG_INF / 2)[:, None], p, 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    ls_ref[:, 0] = ls_ref[:, 0] * corr + p.sum(axis=-1)
-    pv = jnp.dot(p, ckv, preferred_element_type=jnp.float32) * cs
-    acc_ref[...] = acc_ref[...] * corr[:, None] + pv
-    ms_ref[:, 0] = m_new
-
-    @pl.when(j == n_logical - 1)
-    def _store():
-        ot_ref[0] = acc_ref[...]
-        m_ref[0] = ms_ref[:, 0]
-        l_ref[0] = ls_ref[:, 0]
+    ckv = ckv_ref[0].astype(F32)                        # (ps, r) int8
+    s = _mla_scores(qa_ref[0], qr_ref[0], ckv, kr_ref[0].astype(F32),
+                    scale, cs, rs_ref[page])
+    _flash_update(s, _count_valid(s, cnt_ref[b, j]), ckv, acc_ref,
+                  ms_ref, ls_ref, v_scale=cs)
+    _flash_store(j, n_logical - 1, ot_ref, m_ref, l_ref, acc_ref, ms_ref,
+                 ls_ref)
 
 
 def vwr_mla_paged_flash_decode_q8_p(q_abs: jax.Array, q_rope: jax.Array,
@@ -769,296 +709,39 @@ def vwr_mla_paged_flash_decode_q8_p(q_abs: jax.Array, q_rope: jax.Array,
     assert Bt == B and counts.shape == (B, J), (table.shape, B)
     kernel = functools.partial(_mla_paged_decode_kernel_q8, scale=scale,
                                n_logical=J)
-    f32 = jnp.float32
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,      # table, counts, ckv_scale, kr_scale
         grid=(B, J),
         in_specs=[
-            pl.BlockSpec((1, H, r),
-                         lambda b, j, tbl, cnt, cs, rs: (b, 0, 0)),
-            pl.BlockSpec((1, H, rope),
-                         lambda b, j, tbl, cnt, cs, rs: (b, 0, 0)),
-            pl.BlockSpec((1, ps, r),
-                         lambda b, j, tbl, cnt, cs, rs:
-                         (tbl[b, j], 0, 0)),
+            pl.BlockSpec((1, H, r), lambda b, j, *_: (b, 0, 0)),
+            pl.BlockSpec((1, H, rope), lambda b, j, *_: (b, 0, 0)),
+            pl.BlockSpec((1, ps, r), lambda b, j, tbl, *_: (tbl[b, j], 0, 0)),
             pl.BlockSpec((1, ps, rope),
-                         lambda b, j, tbl, cnt, cs, rs:
-                         (tbl[b, j], 0, 0)),
+                         lambda b, j, tbl, *_: (tbl[b, j], 0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, H, r),
-                         lambda b, j, tbl, cnt, cs, rs: (b, 0, 0)),
-            pl.BlockSpec((1, H),
-                         lambda b, j, tbl, cnt, cs, rs: (b, 0)),
-            pl.BlockSpec((1, H),
-                         lambda b, j, tbl, cnt, cs, rs: (b, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((H, r), f32),
-            pltpu.VMEM((H, 1), f32),
-            pltpu.VMEM((H, 1), f32),
-        ],
+        out_specs=_out_specs(H, r, lambda b, j, *_: (b, 0, 0)),
+        scratch_shapes=_scratch(H, r),
     )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, r), f32),
-            jax.ShapeDtypeStruct((B, H), f32),
-            jax.ShapeDtypeStruct((B, H), f32),
-        ],
-        compiler_params=tpu_compiler_params("parallel", "arbitrary"),
+    return _squeeze_stats(*pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=_out_shapes(B, H, r),
+        compiler_params=_params("parallel", "arbitrary"),
         interpret=interpret,
     )(table, counts, ckv_scale, krope_scale, q_abs, q_rope, ckv_pool,
-      krope_pool)
-
-
-# ----------------------------------------------------------------------
-# chunked prefill: a (C, d) query chunk against the paged pool
-# ----------------------------------------------------------------------
-#
-# Chunked prefill attends C chunk queries (one in-flight prompt's next
-# slice) against the PRIOR pages of that prompt — earlier chunks and
-# prefix-cache hits already resident in the pool via the block table.
-# The payoff vs replaying the decode kernel C times: each prior page is
-# staged from HBM ONCE for all C queries (C·G rows ride the VMEM
-# resident block), so staged bytes per chunk are ~1/C of the per-row
-# decode cost.  The within-chunk causal self-attention block is a tiny
-# (C, C) problem handled outside (models.attention combines the two
-# partials with the flash merge), so these kernels mask only by the
-# per-page valid counts — which also lets dist.decode zero out pages a
-# shard does not own.
-
-def _chunk_prefix_kernel(tbl_ref, cnt_ref, q_ref, k_ref, v_ref,
-                         ot_ref, m_ref, l_ref, acc_ref, ms_ref, ls_ref,
-                         *, scale, n_logical):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        ms_ref[...] = jnp.full_like(ms_ref, NEG_INF)
-        ls_ref[...] = jnp.zeros_like(ls_ref)
-
-    count = cnt_ref[j]                                  # tokens valid here
-    q = q_ref[0].astype(jnp.float32) * scale            # (C*G, D)
-    k = k_ref[0, :, 0, :]                               # (ps, D)
-    v = v_ref[0, :, 0, :]
-    s = jax.lax.dot_general(q, k.astype(jnp.float32),
-                            (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    idx = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(idx < count, s, NEG_INF)
-    m_prev = ms_ref[:, 0]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))         # (C*G,)
-    p = jnp.exp(s - m_new[:, None])
-    p = jnp.where((m_new > NEG_INF / 2)[:, None], p, 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    ls_ref[:, 0] = ls_ref[:, 0] * corr + p.sum(axis=-1)
-    pv = jnp.dot(p, v.astype(jnp.float32),
-                 preferred_element_type=jnp.float32)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + pv
-    ms_ref[:, 0] = m_new
-
-    @pl.when(j == n_logical - 1)
-    def _store():
-        ot_ref[0] = acc_ref[...]
-        m_ref[0] = ms_ref[:, 0]
-        l_ref[0] = ls_ref[:, 0]
-
-
-def vwr_chunk_prefix_attend_p(q: jax.Array, k_pool: jax.Array,
-                              v_pool: jax.Array, table: jax.Array,
-                              counts: jax.Array, *,
-                              interpret: bool = False):
-    """Chunk-prefix flash attention over the paged pool.
-
-    q: (KV, C*G, D) chunk queries flattened per KV head (C = chunk
-    tokens, G = H // KV); table: (J,) physical page ids of the chunk's
-    PRIOR pages in prefix order; counts: (J,) valid tokens per page
-    (page_size for full prior pages, 0 for pages a shard does not
-    own).  Returns fp32 partials (o_tilde (KV, C*G, D), m (KV, C*G),
-    l (KV, C*G)) under the shared flash combine contract.
-    """
-    KV, CG, D = q.shape
-    n_pages, ps, KVp, _ = k_pool.shape
-    assert KVp == KV, (KVp, KV)
-    J, = table.shape
-    assert counts.shape == (J,), (counts.shape, J)
-    scale = 1.0 / (D ** 0.5)
-    kernel = functools.partial(_chunk_prefix_kernel, scale=scale,
-                               n_logical=J)
-    f32 = jnp.float32
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                  # table, counts
-        grid=(KV, J),
-        in_specs=[
-            pl.BlockSpec((1, CG, D), lambda kv, j, tbl, cnt: (kv, 0, 0)),
-            pl.BlockSpec((1, ps, 1, D),
-                         lambda kv, j, tbl, cnt: (tbl[j], 0, kv, 0)),
-            pl.BlockSpec((1, ps, 1, D),
-                         lambda kv, j, tbl, cnt: (tbl[j], 0, kv, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, CG, D), lambda kv, j, tbl, cnt: (kv, 0, 0)),
-            pl.BlockSpec((1, CG), lambda kv, j, tbl, cnt: (kv, 0)),
-            pl.BlockSpec((1, CG), lambda kv, j, tbl, cnt: (kv, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((CG, D), f32),
-            pltpu.VMEM((CG, 1), f32),
-            pltpu.VMEM((CG, 1), f32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((KV, CG, D), f32),
-            jax.ShapeDtypeStruct((KV, CG), f32),
-            jax.ShapeDtypeStruct((KV, CG), f32),
-        ],
-        compiler_params=tpu_compiler_params("parallel", "arbitrary"),
-        interpret=interpret,
-    )(table, counts, q, k_pool, v_pool)
-
-
-def _chunk_prefix_kernel_q8(tbl_ref, cnt_ref, ks_ref, vs_ref, q_ref,
-                            k_ref, v_ref, ot_ref, m_ref, l_ref,
-                            acc_ref, ms_ref, ls_ref, *, scale,
-                            n_logical):
-    kv = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        ms_ref[...] = jnp.full_like(ms_ref, NEG_INF)
-        ls_ref[...] = jnp.zeros_like(ls_ref)
-
-    count = cnt_ref[j]
-    page = tbl_ref[j]
-    ks = ks_ref[page, kv]                               # per-page scales
-    vs = vs_ref[page, kv]
-    q = q_ref[0].astype(jnp.float32) * scale            # (C*G, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)           # (ps, D) int8
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * ks
-    idx = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(idx < count, s, NEG_INF)
-    m_prev = ms_ref[:, 0]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
-    p = jnp.exp(s - m_new[:, None])
-    p = jnp.where((m_new > NEG_INF / 2)[:, None], p, 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    ls_ref[:, 0] = ls_ref[:, 0] * corr + p.sum(axis=-1)
-    pv = jnp.dot(p, v, preferred_element_type=jnp.float32) * vs
-    acc_ref[...] = acc_ref[...] * corr[:, None] + pv
-    ms_ref[:, 0] = m_new
-
-    @pl.when(j == n_logical - 1)
-    def _store():
-        ot_ref[0] = acc_ref[...]
-        m_ref[0] = ms_ref[:, 0]
-        l_ref[0] = ls_ref[:, 0]
-
-
-def vwr_chunk_prefix_attend_q8_p(q: jax.Array, k_pool: jax.Array,
-                                 v_pool: jax.Array, k_scale: jax.Array,
-                                 v_scale: jax.Array, table: jax.Array,
-                                 counts: jax.Array, *,
-                                 interpret: bool = False):
-    """``vwr_chunk_prefix_attend_p`` over int8 page pools with fp32
-    (n_pages, KV) scale sidecars, dequantized on the staged block."""
-    KV, CG, D = q.shape
-    n_pages, ps, KVp, _ = k_pool.shape
-    assert KVp == KV, (KVp, KV)
-    assert k_scale.shape == (n_pages, KV) and \
-        v_scale.shape == (n_pages, KV)
-    J, = table.shape
-    assert counts.shape == (J,), (counts.shape, J)
-    scale = 1.0 / (D ** 0.5)
-    kernel = functools.partial(_chunk_prefix_kernel_q8, scale=scale,
-                               n_logical=J)
-    f32 = jnp.float32
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,      # table, counts, k_scale, v_scale
-        grid=(KV, J),
-        in_specs=[
-            pl.BlockSpec((1, CG, D),
-                         lambda kv, j, tbl, cnt, ks, vs: (kv, 0, 0)),
-            pl.BlockSpec((1, ps, 1, D),
-                         lambda kv, j, tbl, cnt, ks, vs:
-                         (tbl[j], 0, kv, 0)),
-            pl.BlockSpec((1, ps, 1, D),
-                         lambda kv, j, tbl, cnt, ks, vs:
-                         (tbl[j], 0, kv, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, CG, D),
-                         lambda kv, j, tbl, cnt, ks, vs: (kv, 0, 0)),
-            pl.BlockSpec((1, CG),
-                         lambda kv, j, tbl, cnt, ks, vs: (kv, 0)),
-            pl.BlockSpec((1, CG),
-                         lambda kv, j, tbl, cnt, ks, vs: (kv, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((CG, D), f32),
-            pltpu.VMEM((CG, 1), f32),
-            pltpu.VMEM((CG, 1), f32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((KV, CG, D), f32),
-            jax.ShapeDtypeStruct((KV, CG), f32),
-            jax.ShapeDtypeStruct((KV, CG), f32),
-        ],
-        compiler_params=tpu_compiler_params("parallel", "arbitrary"),
-        interpret=interpret,
-    )(table, counts, k_scale, v_scale, q, k_pool, v_pool)
+      krope_pool))
 
 
 def _mla_chunk_prefix_kernel(tbl_ref, cnt_ref, qa_ref, qr_ref, ckv_ref,
                              kr_ref, ot_ref, m_ref, l_ref, acc_ref,
                              ms_ref, ls_ref, *, scale, n_logical):
     j = pl.program_id(0)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        ms_ref[...] = jnp.full_like(ms_ref, NEG_INF)
-        ls_ref[...] = jnp.zeros_like(ls_ref)
-
-    count = cnt_ref[j]
-    qa = qa_ref[...].astype(jnp.float32) * scale        # (C*H, r)
-    qr = qr_ref[...].astype(jnp.float32) * scale        # (C*H, rope)
-    ckv = ckv_ref[0].astype(jnp.float32)                # (ps, r)
-    kr = kr_ref[0].astype(jnp.float32)                  # (ps, rope)
-    s = jax.lax.dot_general(qa, ckv, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    s = s + jax.lax.dot_general(qr, kr, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-    idx = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(idx < count, s, NEG_INF)
-    m_prev = ms_ref[:, 0]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))         # (C*H,)
-    p = jnp.exp(s - m_new[:, None])
-    p = jnp.where((m_new > NEG_INF / 2)[:, None], p, 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    ls_ref[:, 0] = ls_ref[:, 0] * corr + p.sum(axis=-1)
-    pv = jnp.dot(p, ckv, preferred_element_type=jnp.float32)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + pv
-    ms_ref[:, 0] = m_new
-
-    @pl.when(j == n_logical - 1)
-    def _store():
-        ot_ref[...] = acc_ref[...]
-        m_ref[0] = ms_ref[:, 0]
-        l_ref[0] = ls_ref[:, 0]
+    _flash_init(j, acc_ref, ms_ref, ls_ref)
+    ckv = ckv_ref[0].astype(F32)                        # (ps, r)
+    s = _mla_scores(qa_ref[...], qr_ref[...], ckv,
+                    kr_ref[0].astype(F32), scale)
+    _flash_update(s, _count_valid(s, cnt_ref[j]), ckv, acc_ref, ms_ref,
+                  ls_ref)
+    _flash_store(j, n_logical - 1, ot_ref, m_ref, l_ref, acc_ref, ms_ref,
+                 ls_ref)
 
 
 def vwr_mla_chunk_prefix_attend_p(q_abs: jax.Array, q_rope: jax.Array,
@@ -1071,7 +754,7 @@ def vwr_mla_chunk_prefix_attend_p(q_abs: jax.Array, q_rope: jax.Array,
 
     q_abs: (C*H, r) absorbed chunk queries; q_rope: (C*H, rope);
     table/counts: (J,) prior pages + per-page valid counts.  Returns
-    fp32 partials (o_tilde (C*H, r), m (1, C*H), l (1, C*H)).
+    fp32 partials (o_tilde (1, C*H, r), m (1, C*H), l (1, C*H)).
     """
     CH, r = q_abs.shape
     rope = q_rope.shape[1]
@@ -1081,39 +764,23 @@ def vwr_mla_chunk_prefix_attend_p(q_abs: jax.Array, q_rope: jax.Array,
     assert counts.shape == (J,), (counts.shape, J)
     kernel = functools.partial(_mla_chunk_prefix_kernel, scale=scale,
                                n_logical=J)
-    f32 = jnp.float32
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(J,),
         in_specs=[
-            pl.BlockSpec((CH, r), lambda j, tbl, cnt: (0, 0)),
-            pl.BlockSpec((CH, rope), lambda j, tbl, cnt: (0, 0)),
-            pl.BlockSpec((1, ps, r), lambda j, tbl, cnt: (tbl[j], 0, 0)),
-            pl.BlockSpec((1, ps, rope),
-                         lambda j, tbl, cnt: (tbl[j], 0, 0)),
+            pl.BlockSpec((CH, r), lambda j, *_: (0, 0)),
+            pl.BlockSpec((CH, rope), lambda j, *_: (0, 0)),
+            pl.BlockSpec((1, ps, r), lambda j, tbl, *_: (tbl[j], 0, 0)),
+            pl.BlockSpec((1, ps, rope), lambda j, tbl, *_: (tbl[j], 0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((CH, r), lambda j, tbl, cnt: (0, 0)),
-            pl.BlockSpec((1, CH), lambda j, tbl, cnt: (0, 0)),
-            pl.BlockSpec((1, CH), lambda j, tbl, cnt: (0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((CH, r), f32),
-            pltpu.VMEM((CH, 1), f32),
-            pltpu.VMEM((CH, 1), f32),
-        ],
+        out_specs=_out_specs(CH, r, lambda j, *_: (0, 0, 0)),
+        scratch_shapes=_scratch(CH, r),
     )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((CH, r), f32),
-            jax.ShapeDtypeStruct((1, CH), f32),
-            jax.ShapeDtypeStruct((1, CH), f32),
-        ],
-        compiler_params=tpu_compiler_params("arbitrary"),
+    return _squeeze_stats(*pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=_out_shapes(1, CH, r),
+        compiler_params=_params("arbitrary"),
         interpret=interpret,
-    )(table, counts, q_abs, q_rope, ckv_pool, krope_pool)
+    )(table, counts, q_abs, q_rope, ckv_pool, krope_pool))
 
 
 def _mla_chunk_prefix_kernel_q8(tbl_ref, cnt_ref, cs_ref, rs_ref,
@@ -1121,42 +788,16 @@ def _mla_chunk_prefix_kernel_q8(tbl_ref, cnt_ref, cs_ref, rs_ref,
                                 ot_ref, m_ref, l_ref, acc_ref, ms_ref,
                                 ls_ref, *, scale, n_logical):
     j = pl.program_id(0)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        ms_ref[...] = jnp.full_like(ms_ref, NEG_INF)
-        ls_ref[...] = jnp.zeros_like(ls_ref)
-
-    count = cnt_ref[j]
+    _flash_init(j, acc_ref, ms_ref, ls_ref)
     page = tbl_ref[j]
     cs = cs_ref[page]                                   # per-page scales
-    rs = rs_ref[page]
-    qa = qa_ref[...].astype(jnp.float32) * scale
-    qr = qr_ref[...].astype(jnp.float32) * scale
-    ckv = ckv_ref[0].astype(jnp.float32)                # (ps, r) int8
-    kr = kr_ref[0].astype(jnp.float32)                  # (ps, rope) int8
-    s = jax.lax.dot_general(qa, ckv, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * cs
-    s = s + jax.lax.dot_general(qr, kr, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * rs
-    idx = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(idx < count, s, NEG_INF)
-    m_prev = ms_ref[:, 0]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
-    p = jnp.exp(s - m_new[:, None])
-    p = jnp.where((m_new > NEG_INF / 2)[:, None], p, 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    ls_ref[:, 0] = ls_ref[:, 0] * corr + p.sum(axis=-1)
-    pv = jnp.dot(p, ckv, preferred_element_type=jnp.float32) * cs
-    acc_ref[...] = acc_ref[...] * corr[:, None] + pv
-    ms_ref[:, 0] = m_new
-
-    @pl.when(j == n_logical - 1)
-    def _store():
-        ot_ref[...] = acc_ref[...]
-        m_ref[0] = ms_ref[:, 0]
-        l_ref[0] = ls_ref[:, 0]
+    ckv = ckv_ref[0].astype(F32)                        # (ps, r) int8
+    s = _mla_scores(qa_ref[...], qr_ref[...], ckv,
+                    kr_ref[0].astype(F32), scale, cs, rs_ref[page])
+    _flash_update(s, _count_valid(s, cnt_ref[j]), ckv, acc_ref, ms_ref,
+                  ls_ref, v_scale=cs)
+    _flash_store(j, n_logical - 1, ot_ref, m_ref, l_ref, acc_ref, ms_ref,
+                 ls_ref)
 
 
 def vwr_mla_chunk_prefix_attend_q8_p(q_abs: jax.Array,
@@ -1181,82 +822,21 @@ def vwr_mla_chunk_prefix_attend_q8_p(q_abs: jax.Array,
     assert counts.shape == (J,), (counts.shape, J)
     kernel = functools.partial(_mla_chunk_prefix_kernel_q8, scale=scale,
                                n_logical=J)
-    f32 = jnp.float32
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,      # table, counts, ckv_scale, kr_scale
         grid=(J,),
         in_specs=[
-            pl.BlockSpec((CH, r), lambda j, tbl, cnt, cs, rs: (0, 0)),
-            pl.BlockSpec((CH, rope),
-                         lambda j, tbl, cnt, cs, rs: (0, 0)),
-            pl.BlockSpec((1, ps, r),
-                         lambda j, tbl, cnt, cs, rs: (tbl[j], 0, 0)),
-            pl.BlockSpec((1, ps, rope),
-                         lambda j, tbl, cnt, cs, rs: (tbl[j], 0, 0)),
+            pl.BlockSpec((CH, r), lambda j, *_: (0, 0)),
+            pl.BlockSpec((CH, rope), lambda j, *_: (0, 0)),
+            pl.BlockSpec((1, ps, r), lambda j, tbl, *_: (tbl[j], 0, 0)),
+            pl.BlockSpec((1, ps, rope), lambda j, tbl, *_: (tbl[j], 0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((CH, r), lambda j, tbl, cnt, cs, rs: (0, 0)),
-            pl.BlockSpec((1, CH), lambda j, tbl, cnt, cs, rs: (0, 0)),
-            pl.BlockSpec((1, CH), lambda j, tbl, cnt, cs, rs: (0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((CH, r), f32),
-            pltpu.VMEM((CH, 1), f32),
-            pltpu.VMEM((CH, 1), f32),
-        ],
+        out_specs=_out_specs(CH, r, lambda j, *_: (0, 0, 0)),
+        scratch_shapes=_scratch(CH, r),
     )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((CH, r), f32),
-            jax.ShapeDtypeStruct((1, CH), f32),
-            jax.ShapeDtypeStruct((1, CH), f32),
-        ],
-        compiler_params=tpu_compiler_params("arbitrary"),
+    return _squeeze_stats(*pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=_out_shapes(1, CH, r),
+        compiler_params=_params("arbitrary"),
         interpret=interpret,
     )(table, counts, ckv_scale, krope_scale, q_abs, q_rope, ckv_pool,
-      krope_pool)
-
-
-def vwr_flash_decode_p(q: jax.Array, k: jax.Array, v: jax.Array,
-                       lens: jax.Array, *, bkv: int, t_valid: int,
-                       interpret: bool = False):
-    """Returns (o_tilde (BKV, G, Dh) f32, m (BKV, G) f32,
-    l (BKV, G) f32)."""
-    BKV, G, D = q.shape
-    Tp = k.shape[1]
-    assert k.shape == (BKV, Tp, D) and v.shape == k.shape
-    assert Tp % bkv == 0, (Tp, bkv)
-    n_kv = Tp // bkv
-    scale = 1.0 / (D ** 0.5)
-    kernel = functools.partial(_decode_kernel, scale=scale, bkv=bkv,
-                               t_valid=t_valid, n_kv=n_kv)
-    f32 = jnp.float32
-    return pl.pallas_call(
-        kernel,
-        grid=(BKV, n_kv),
-        in_specs=[
-            pl.BlockSpec((1, G, D), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, bkv, D), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, bkv, D), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, 2), lambda b, j: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, G, D), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, G), lambda b, j: (b, 0)),
-            pl.BlockSpec((1, G), lambda b, j: (b, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BKV, G, D), f32),
-            jax.ShapeDtypeStruct((BKV, G), f32),
-            jax.ShapeDtypeStruct((BKV, G), f32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((G, D), f32),
-            pltpu.VMEM((G, 1), f32),
-            pltpu.VMEM((G, 1), f32),
-        ],
-        compiler_params=tpu_compiler_params("parallel", "arbitrary"),
-        interpret=interpret,
-    )(q, k, v, lens)
+      krope_pool))

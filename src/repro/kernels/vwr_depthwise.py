@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import halo_block_spec, tpu_compiler_params
-
 
 def _dw_kernel(x_ref, w_ref, o_ref, *, KH, KW, bh, W_out):
     x = x_ref[0]                                   # (bh+KH-1, W, C)
@@ -42,14 +40,16 @@ def vwr_depthwise_p(x: jax.Array, w: jax.Array, *, bh: int = 8,
     assert H_out % bh == 0
     kernel = functools.partial(_dw_kernel, KH=KH, KW=KW, bh=bh,
                                W_out=W_out)
-    params = tpu_compiler_params("parallel", "parallel")
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel"))
     return pl.pallas_call(
         kernel,
         grid=(N, H_out // bh),
         in_specs=[
-            halo_block_spec((1, bh + KH - 1, W, C),
-                            lambda n, r: (n, r * bh, 0, 0),
-                            halo_dim=1),
+            # overlapping (halo'd) row windows: the row index map
+            # returns ELEMENT offsets
+            pl.BlockSpec((1, pl.Element(bh + KH - 1), W, C),
+                         lambda n, r: (n, r * bh, 0, 0)),
             pl.BlockSpec((KH, KW, C), lambda n, r: (0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, bh, W_out, C), lambda n, r: (n, r, 0, 0)),

@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import tpu_compiler_params
-
 ACTIVATIONS = {
     "relu": lambda x: jnp.maximum(x, 0.0),
     "gelu": jax.nn.gelu,
@@ -111,8 +109,8 @@ def vwr_swiglu_p(x: jax.Array, wg: jax.Array, wi: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
                         pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            "parallel", "parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, wg, wi)
 
@@ -153,7 +151,7 @@ def vwr_matmul_p(x: jax.Array, w: jax.Array, bias=None, residual=None, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            "parallel", "parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)

@@ -30,13 +30,16 @@ import numpy as np
 from repro.configs import get_config, reduced
 from repro.engine import DecodeEngine, EngineConfig
 from repro.engine import pad_cache_from_prefill  # noqa: F401  (compat)
+from repro.launch import compile_cache
 from repro.launch.train import width_reduce
 
 
 def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
-    ap.add_argument("--reduce", choices=["smoke", "width"], default="width")
+    ap.add_argument("--reduce", choices=["none", "smoke", "width"],
+                    default="width",
+                    help="'none' serves the published widths")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--gen", type=int, default=32)
@@ -348,11 +351,30 @@ def _serve_stream(engine, args):
     return sched.finished
 
 
+def _exit_if_lost(finished):
+    """A stream without injected faults must finish every request: a
+    FAILED or REJECTED one (a prefill that raised — e.g. a kernel the
+    compiler refused — or a request the pool can never fit) ends the
+    process with a non-zero exit code."""
+    from repro.engine import RequestStatus
+    lost = {i: v for i, v in sorted(finished.items())
+            if v.status in (RequestStatus.FAILED, RequestStatus.REJECTED)}
+    for i, v in lost.items():
+        print(f"    req {i} {v.status.value}: {v.error}")
+    if lost:
+        raise SystemExit(f"[serve] {len(lost)} request(s) ended FAILED "
+                         "or REJECTED")
+
+
 def main(argv=None):
     args = build_parser().parse_args(argv)
+    compile_cache.enable()
 
     cfg = get_config(args.arch)
-    cfg = reduced(cfg) if args.reduce == "smoke" else width_reduce(cfg)
+    if args.reduce == "smoke":
+        cfg = reduced(cfg)
+    elif args.reduce == "width":
+        cfg = width_reduce(cfg)
     if cfg.mamba2 is not None or cfg.xlstm is not None:
         chunk = (cfg.mamba2 or cfg.xlstm).chunk
         assert args.prompt_len % chunk == 0
@@ -361,9 +383,11 @@ def main(argv=None):
     cfg = engine.cfg
 
     if args.stream:
-        if args.snapshot_dir:
-            return _serve_durable(engine, args)
-        return _serve_stream(engine, args)
+        serve = _serve_durable if args.snapshot_dir else _serve_stream
+        finished = serve(engine, args)
+        if not args.inject:
+            _exit_if_lost(finished)
+        return finished
 
     B, P, G = args.batch, args.prompt_len, args.gen
     rng = np.random.default_rng(0)

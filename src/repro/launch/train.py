@@ -27,6 +27,7 @@ from repro.common.config import ModelConfig
 from repro.configs import get_config, reduced
 from repro.data import DataConfig, Prefetcher, SyntheticLM
 from repro.dist import sharding as SH
+from repro.launch import compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.launch.steps import build_train_step
 from repro.models import lm
@@ -106,6 +107,7 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--metrics-out", default="")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg = get_config(args.arch)
     if args.reduce == "smoke":

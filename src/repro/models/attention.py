@@ -20,7 +20,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as PS
 
 from repro.common.hints import shard_hint
-from repro.common.module import ParamDef, zeros_init
+from repro.common.module import (ParamDef, head_out_init, head_proj_init,
+                                 zeros_init)
 from repro.kernels import dispatch as D
 from repro.models.layers import apply_rope
 
@@ -33,10 +34,14 @@ def gqa_spec(cfg):
     d, H, KV, Dh = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     dtype = jnp.dtype(cfg.dtype)
     spec = {
-        "wq": ParamDef((d, H, Dh), dtype, ("embed", "heads", "head_dim")),
-        "wk": ParamDef((d, KV, Dh), dtype, ("embed", "kv", "head_dim")),
-        "wv": ParamDef((d, KV, Dh), dtype, ("embed", "kv", "head_dim")),
-        "wo": ParamDef((H, Dh, d), dtype, ("heads", "head_dim", "embed")),
+        "wq": ParamDef((d, H, Dh), dtype, ("embed", "heads", "head_dim"),
+                       head_proj_init),
+        "wk": ParamDef((d, KV, Dh), dtype, ("embed", "kv", "head_dim"),
+                       head_proj_init),
+        "wv": ParamDef((d, KV, Dh), dtype, ("embed", "kv", "head_dim"),
+                       head_proj_init),
+        "wo": ParamDef((H, Dh, d), dtype, ("heads", "head_dim", "embed"),
+                       head_out_init),
     }
     if cfg.qkv_bias:
         spec["bq"] = ParamDef((H, Dh), dtype, ("heads", "head_dim"), zeros_init)
@@ -46,7 +51,7 @@ def gqa_spec(cfg):
 
 
 @D.register("qkv_proj", "xla")
-def _qkv_proj_xla(p, x):
+def _qkv_proj_xla(p, x, mesh=None):
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
@@ -57,8 +62,7 @@ def _qkv_proj_xla(p, x):
     return q, k, v
 
 
-@D.register("qkv_proj", "pallas")
-def _qkv_proj_pallas(p, x):
+def _qkv_kernels(p, x):
     from repro.kernels import ops
     B, S, d = x.shape
     x2 = x.reshape(B * S, d)
@@ -74,15 +78,37 @@ def _qkv_proj_pallas(p, x):
             proj(p["wv"], p.get("bv")))
 
 
+@D.register("qkv_proj", "pallas")
+def _qkv_proj_pallas(p, x, mesh=None):
+    from repro.dist import sharding as SH
+    if not SH.multi_device(mesh):
+        return _qkv_kernels(p, x)
+    # column-parallel over 'model': each device projects its own heads
+    hq = SH.tp_entry(mesh, p["wq"].shape[1])
+    hk = SH.tp_entry(mesh, p["wk"].shape[1])
+    dp = SH.batch_entry(mesh, x.shape[0])
+    sub = {k: p[k] for k in ("wq", "wk", "wv", "bq", "bk", "bv")
+           if k in p}
+
+    def spec(name):                     # w* (d, heads, Dh), b* (heads, Dh)
+        ax = hq if name[1] == "q" else hk
+        return PS(None, ax, None) if name[0] == "w" else PS(ax, None)
+
+    return SH.per_device(
+        _qkv_kernels, mesh, ({k: spec(k) for k in sub}, PS(dp, None, None)),
+        (PS(dp, None, hq, None), PS(dp, None, hk, None),
+         PS(dp, None, hk, None)))(sub, x)
+
+
 def qkv_proj(p, x, positions, rope_theta, backend="xla", *,
-             kernel_impl=None):
+             kernel_impl=None, mesh=None):
     """QKV projection (+rope) via the dispatch registry.  ``backend``
     is a backend string or a ModelConfig; the legacy ``kernel_impl=``
     kwarg still works but is deprecated."""
     if kernel_impl is not None:
         D.warn_kernel_impl_kwarg("attention.qkv_proj")
         backend = kernel_impl
-    q, k, v = D.dispatch("qkv_proj", backend, p, x)
+    q, k, v = D.dispatch("qkv_proj", backend, p, x, mesh=mesh)
     if rope_theta:
         q = apply_rope(q, positions, rope_theta)
         k = apply_rope(k, positions, rope_theta)
@@ -90,30 +116,50 @@ def qkv_proj(p, x, positions, rope_theta, backend="xla", *,
 
 
 @D.register("o_proj", "xla")
-def _o_proj_xla(p, o, residual=None):
+def _o_proj_xla(p, o, residual=None, mesh=None):
     out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
     return out if residual is None else residual + out
 
 
-@D.register("o_proj", "pallas")
-def _o_proj_pallas(p, o, residual=None):
+def _o_kernel(wo, o, residual=None):
     from repro.kernels import ops
     B, S, H, Dh = o.shape
-    d = p["wo"].shape[-1]
+    d = wo.shape[-1]
     r2 = None if residual is None else residual.reshape(B * S, d)
-    out = ops.vwr_matmul(o.reshape(B * S, H * Dh),
-                         p["wo"].reshape(H * Dh, d), residual=r2)
+    out = ops.vwr_matmul(o.reshape(B * S, H * Dh), wo.reshape(H * Dh, d),
+                         residual=r2)
     return out.reshape(B, S, d)
 
 
-def o_proj(p, o, backend="xla", residual=None, *, kernel_impl=None):
+@D.register("o_proj", "pallas")
+def _o_proj_pallas(p, o, residual=None, mesh=None):
+    from repro.dist import sharding as SH
+    if not SH.multi_device(mesh):
+        return _o_kernel(p["wo"], o, residual)
+    # row-parallel over 'model': per-device heads, one psum
+    hq = SH.tp_entry(mesh, p["wo"].shape[0])
+    dp = SH.batch_entry(mesh, o.shape[0])
+
+    def local(wo, o):
+        out = _o_kernel(wo, o)
+        return out if hq is None else jax.lax.psum(out, hq)
+
+    out = SH.per_device(local, mesh,
+                        (PS(hq, None, None), PS(dp, None, hq, None)),
+                        PS(dp, None, None))(p["wo"], o)
+    return out if residual is None else residual + out
+
+
+def o_proj(p, o, backend="xla", residual=None, *, kernel_impl=None,
+           mesh=None):
     """Output projection; with ``residual`` returns residual + o@wo —
     fused into the matmul's final-K store on the pallas path.  The
     legacy ``kernel_impl=`` kwarg still works but is deprecated."""
     if kernel_impl is not None:
         D.warn_kernel_impl_kwarg("attention.o_proj")
         backend = kernel_impl
-    return D.dispatch("o_proj", backend, p, o, residual=residual)
+    return D.dispatch("o_proj", backend, p, o, residual=residual,
+                      mesh=mesh)
 
 
 # ---------------- blockwise flash attention (training / prefill) ----------------
@@ -248,8 +294,20 @@ def _attention_pallas(q, k, v, *, causal, q_positions=None,
     (encoder) path keeps the blockwise formulation, whose kv-padding
     masks don't require S % block == 0."""
     if causal:
+        from repro.dist import sharding as SH
         from repro.kernels import ops
-        return ops.vwr_attention(q, k, v, causal=True)
+
+        def kernel(q, k, v):
+            return ops.vwr_attention(q, k, v, causal=True)
+
+        if not SH.multi_device(mesh):
+            return kernel(q, k, v)
+        # per-device heads: a kv head and its whole query group stay on
+        # one device (kv-major head order h = kv * G + g)
+        hk = SH.tp_entry(mesh, k.shape[2])
+        spec = PS(SH.batch_entry(mesh, q.shape[0]), None, hk, None)
+        return SH.per_device(kernel, mesh, (spec, spec, spec),
+                             spec)(q, k, v)
     return _attention_xla(q, k, v, causal=causal, q_positions=q_positions,
                           kv_positions=kv_positions, block_q=block_q,
                           block_kv=block_kv, head_axis=head_axis,
@@ -331,7 +389,7 @@ def _decode_partial_xla(q, k, v, cur_len, pos0=0, *, tune=True):
 
 def paged_flash_decode_partial(
     q: jax.Array,            # (B, H, Dh) — one new token per slot
-    k_pool: jax.Array,       # (n_pages, page_size, KV, Dh) shared pool
+    k_pool: jax.Array,       # (n_pages, KV, page_size, Dh) shared pool
     v_pool: jax.Array,
     block_table: jax.Array,  # (B, max_pages) int32 physical page ids
     page_counts: jax.Array,  # (B, max_pages) int32 valid tokens per page
@@ -345,16 +403,17 @@ def paged_flash_decode_partial(
     Returns fp32 (o_tilde (B,H,Dh), m (B,H), l (B,H)).
     """
     B, H, Dh = q.shape
-    n_pages, ps, KV, _ = k_pool.shape
+    n_pages, KV, ps, _ = k_pool.shape
     G = H // KV
     J = block_table.shape[1]
     tbl = jnp.clip(block_table, 0, n_pages - 1)
-    k = k_pool[tbl]                              # (B, J, ps, KV, Dh)
-    v = v_pool[tbl]
+
+    def gather(pool):                            # -> (B, J*ps, KV, Dh)
+        return jnp.swapaxes(pool[tbl], 2, 3).reshape(B, J * ps, KV, Dh)
+
+    k, v = gather(k_pool), gather(v_pool)
     valid = (jnp.arange(ps)[None, None, :]
              < page_counts[..., None]).reshape(B, J * ps)
-    k = k.reshape(B, J * ps, KV, Dh)
-    v = v.reshape(B, J * ps, KV, Dh)
     qf = q.astype(jnp.float32).reshape(B, KV, G, Dh) / (Dh ** 0.5)
     s = jnp.einsum("bhgd,bthd->bhgt", qf, k.astype(jnp.float32))
     s = jnp.where(valid[:, None, None, :], s, NEG_INF)
@@ -445,8 +504,8 @@ def _decode_partial_paged_q8_xla(q, k_pool, v_pool, k_scale, v_scale,
                                  max_pages=None, tune=True):
     # dequantize the whole pool: honest reference semantics (and the
     # honest cost of NOT dequantizing in-kernel)
-    kf = k_pool.astype(jnp.float32) * k_scale[:, None, :, None]
-    vf = v_pool.astype(jnp.float32) * v_scale[:, None, :, None]
+    kf = k_pool.astype(jnp.float32) * k_scale[:, :, None, None]
+    vf = v_pool.astype(jnp.float32) * v_scale[:, :, None, None]
     return paged_flash_decode_partial(q, kf, vf, table, counts)
 
 
@@ -495,7 +554,7 @@ def normalize_partial(o_t, l, dtype):
 
 def chunk_prefix_attend_partial(
     q: jax.Array,            # (C, H, Dh) — one prompt's query chunk
-    k_pool: jax.Array,       # (n_pages, page_size, KV, Dh) shared pool
+    k_pool: jax.Array,       # (n_pages, KV, page_size, Dh) shared pool
     v_pool: jax.Array,
     table: jax.Array,        # (J,) int32 the chunk's PRIOR pages
     counts: jax.Array,       # (J,) int32 valid tokens per page
@@ -503,12 +562,12 @@ def chunk_prefix_attend_partial(
     """XLA gather reference for the chunk-prefix contract.  Returns
     fp32 (o_tilde (C,H,Dh), m (C,H), l (C,H))."""
     C, H, Dh = q.shape
-    n_pages, ps, KV, _ = k_pool.shape
+    n_pages, KV, ps, _ = k_pool.shape
     G = H // KV
     J = table.shape[0]
     tbl = jnp.clip(table, 0, n_pages - 1)
-    k = k_pool[tbl].reshape(J * ps, KV, Dh)
-    v = v_pool[tbl].reshape(J * ps, KV, Dh)
+    k = jnp.swapaxes(k_pool[tbl], 1, 2).reshape(J * ps, KV, Dh)
+    v = jnp.swapaxes(v_pool[tbl], 1, 2).reshape(J * ps, KV, Dh)
     valid = (jnp.arange(ps)[None, :] < counts[:, None]).reshape(J * ps)
     qf = q.astype(jnp.float32).reshape(C, KV, G, Dh) / (Dh ** 0.5)
     s = jnp.einsum("chgd,thd->chgt", qf, k.astype(jnp.float32))
@@ -547,7 +606,7 @@ def chunk_prefill_attend(q, chunk_k, chunk_v, k_pool, v_pool, table,
     scale sidecars being passed as ``k_pool``/``v_pool`` dequantized
     upstream) merged with the within-chunk causal partial, normalized.
     Returns (C, H, Dh) in q's dtype."""
-    ps = k_pool.shape[1]
+    ps = k_pool.shape[2]
     J = table.shape[0]
     prefix = D.dispatch("chunk_prefix_paged", backend, q, k_pool,
                         v_pool, table, counts, page_size=ps,
@@ -575,8 +634,8 @@ def _chunk_prefix_paged_pallas(q, k_pool, v_pool, table, counts, *,
 def _chunk_prefix_paged_q8_xla(q, k_pool, v_pool, k_scale, v_scale,
                                table, counts, *, page_size=None,
                                max_pages=None, tune=True):
-    kf = k_pool.astype(jnp.float32) * k_scale[:, None, :, None]
-    vf = v_pool.astype(jnp.float32) * v_scale[:, None, :, None]
+    kf = k_pool.astype(jnp.float32) * k_scale[:, :, None, None]
+    vf = v_pool.astype(jnp.float32) * v_scale[:, :, None, None]
     return chunk_prefix_attend_partial(q, kf, vf, table, counts)
 
 

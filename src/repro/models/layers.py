@@ -15,6 +15,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as PS
 
 from repro.common.module import ParamDef, embed_init, ones_init, zeros_init
 from repro.kernels import dispatch as D
@@ -118,7 +119,7 @@ def _swiglu_pallas(x2, wg, wi):
 
 
 @D.register("mlp", "xla")
-def _mlp_xla(p, x, act, residual=None):
+def _mlp_xla(p, x, act, residual=None, mesh=None):
     if act == "swiglu":
         lead, d = x.shape[:-1], x.shape[-1]
         h = D.dispatch("swiglu", "xla", x.reshape(-1, d),
@@ -131,39 +132,58 @@ def _mlp_xla(p, x, act, residual=None):
     return out if residual is None else residual + out
 
 
-@D.register("mlp", "pallas")
-def _mlp_pallas(p, x, act, residual=None):
+def _mlp_kernels(p, x2, act, r2=None):
     from repro.kernels import ops
-    lead, d = x.shape[:-1], x.shape[-1]
-    x2 = x.reshape(-1, d)
-    r2 = None if residual is None else residual.reshape(
-        -1, residual.shape[-1])
     if act == "swiglu":
         # dual-matmul fused swiglu: one staged x block feeds both
         # projections and silu(g) * h happens on the fp32 accumulators
         # in the final-K store — no separate elementwise pass
         h = D.dispatch("swiglu", "pallas", x2,
-                       p["wg"], p["wi"]).astype(x.dtype)
+                       p["wg"], p["wi"]).astype(x2.dtype)
     else:
         h = ops.vwr_matmul(x2, p["wi"],
                            activation="gelu" if act == "gelu" else "relu")
-    out = ops.vwr_matmul(h, p["wo"], residual=r2)
-    return out.reshape(*lead, out.shape[-1])
+    return ops.vwr_matmul(h, p["wo"], residual=r2)
+
+
+@D.register("mlp", "pallas")
+def _mlp_pallas(p, x, act, residual=None, mesh=None):
+    from repro.dist import sharding as SH
+    lead, d = x.shape[:-1], x.shape[-1]
+    x2 = x.reshape(-1, d)
+    if not SH.multi_device(mesh):
+        r2 = None if residual is None else residual.reshape(-1, d)
+        return _mlp_kernels(p, x2, act, r2).reshape(*lead, d)
+    # tensor parallel over 'model': column-split up-projections, row-
+    # split down-projection, one psum
+    ff = SH.tp_entry(mesh, p["wo"].shape[0])
+    dp = SH.batch_entry(mesh, x2.shape[0])
+
+    def local(p, x2):
+        out = _mlp_kernels(p, x2, act)
+        return out if ff is None else jax.lax.psum(out, ff)
+
+    specs = {k: PS(ff, None) if k == "wo" else PS(None, ff) for k in p}
+    out = SH.per_device(local, mesh, (specs, PS(dp, None)),
+                        PS(dp, None))(p, x2).reshape(*lead, d)
+    return out if residual is None else residual + out
 
 
 def mlp(p, x, act: str, *, backend="xla", residual=None,
-        kernel_impl=None):
+        kernel_impl=None, mesh=None):
     """FFN block via the dispatch registry.  With ``residual`` the
     residual add is part of the block (``residual + mlp(x)``); on the
     pallas path it is fused into the down-projection's final-K store
     (one HBM round-trip), the non-gated activation into the
-    up-projection, and swiglu runs the dual-matmul fused kernel.
-    ``backend`` is a backend string or a ModelConfig; the legacy
-    ``kernel_impl=`` kwarg still works but is deprecated."""
+    up-projection, and swiglu runs the dual-matmul fused kernel.  On a
+    multi-device ``mesh`` the pallas path runs tensor-parallel per
+    device.  ``backend`` is a backend string or a ModelConfig; the
+    legacy ``kernel_impl=`` kwarg still works but is deprecated."""
     if kernel_impl is not None:
         D.warn_kernel_impl_kwarg("layers.mlp")
         backend = kernel_impl
-    return D.dispatch("mlp", backend, p, x, act, residual=residual)
+    return D.dispatch("mlp", backend, p, x, act, residual=residual,
+                      mesh=mesh)
 
 
 # ---------------- frontends (stubs per brief) ----------------

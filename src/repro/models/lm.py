@@ -260,7 +260,8 @@ def _attn_delta(cfg, ap, h, positions, *, causal=True, residual=None,
                                        head_axis=_head_axis(cfg),
                                        mesh=mesh)
         return (out if residual is None else residual + out), cache
-    q, k, v = A.qkv_proj(ap, h, positions, cfg.rope_theta, cfg)
+    q, k, v = A.qkv_proj(ap, h, positions, cfg.rope_theta, cfg,
+                         mesh=mesh)
     if cfg.accounting:
         o = A.full_attn_ref(q, k, v, causal=causal, q_positions=positions,
                             kv_positions=positions)
@@ -270,7 +271,7 @@ def _attn_delta(cfg, ap, h, positions, *, causal=True, residual=None,
                        block_q=cfg.attn_block_q,
                        block_kv=cfg.attn_block_kv,
                        head_axis=_head_axis(cfg), mesh=mesh)
-    return A.o_proj(ap, o, cfg, residual=residual), (k, v)
+    return A.o_proj(ap, o, cfg, residual=residual, mesh=mesh), (k, v)
 
 
 def _head_axis(cfg):
@@ -286,7 +287,7 @@ def _dense_body(cfg, positions, x, lp, _ex, *, causal=True, collect=False,
     x, kv = _attn_delta(cfg, lp["attn"], _norm(cfg, lp["attn_norm"], x),
                         positions, causal=causal, residual=x, mesh=mesh)
     x = L.mlp(lp["mlp"], _norm(cfg, lp["mlp_norm"], x), cfg.act,
-              backend=cfg, residual=x)
+              backend=cfg, residual=x, mesh=mesh)
     return x, (kv if collect else None)
 
 
@@ -313,9 +314,9 @@ def _xattn_body(cfg, positions, enc_out, enc_valid, x, lp, _ex, *,
         o = A.blockwise_attn(q, k, v, causal=False, kv_valid=enc_valid,
                              block_q=cfg.attn_block_q,
                              block_kv=cfg.attn_block_kv, mesh=mesh)
-    x = A.o_proj(lp["cross"], o, cfg, residual=x)
+    x = A.o_proj(lp["cross"], o, cfg, residual=x, mesh=mesh)
     x = L.mlp(lp["mlp"], _norm(cfg, lp["mlp_norm"], x), cfg.act,
-              backend=cfg, residual=x)
+              backend=cfg, residual=x, mesh=mesh)
     return x, ((kv, (k, v)) if collect else None)
 
 
@@ -323,7 +324,7 @@ def _shared_attn_apply(cfg, sp, x, positions, *, collect=False, mesh=None):
     x, kv = _attn_delta(cfg, sp["attn"], _norm(cfg, sp["attn_norm"], x),
                         positions, residual=x, mesh=mesh)
     x = L.mlp(sp["mlp"], _norm(cfg, sp["mlp_norm"], x), cfg.act,
-              backend=cfg, residual=x)
+              backend=cfg, residual=x, mesh=mesh)
     return x, (kv if collect else None)
 
 
@@ -755,12 +756,12 @@ def _page_write_ids(table, lens, page_size, n_pages):
 
 def _decode_gqa_paged(cfg, lp, h, kp, vp, table, lens, mesh=None,
                       kscale=None, vscale=None):
-    """h: (B,D) normed; kp/vp: (n_pages, ps, KV, Dh) pools; lens: (B,)
+    """h: (B,D) normed; kp/vp: (n_pages, KV, ps, Dh) pools; lens: (B,)
     per-slot valid positions (the new token writes at position lens).
     With ``kscale``/``vscale`` ((n_pages, KV) fp32 sidecars) the pools
     are int8: the token quantizes on write and attention dequantizes
     in-kernel.  Returns (delta, kp, vp, kscale, vscale)."""
-    n_pages, ps = kp.shape[0], kp.shape[1]
+    n_pages, ps = kp.shape[0], kp.shape[2]
     q = jnp.einsum("bd,dhk->bhk", h, lp["wq"])
     k = jnp.einsum("bd,dhk->bhk", h, lp["wk"])
     v = jnp.einsum("bd,dhk->bhk", h, lp["wv"])
@@ -774,8 +775,8 @@ def _decode_gqa_paged(cfg, lp, h, kp, vp, table, lens, mesh=None,
         kp, kscale = PC.quantized_page_write(kp, kscale, pages, offs, k)
         vp, vscale = PC.quantized_page_write(vp, vscale, pages, offs, v)
     else:
-        kp = kp.at[pages, offs].set(k.astype(kp.dtype), mode="drop")
-        vp = vp.at[pages, offs].set(v.astype(vp.dtype), mode="drop")
+        kp = kp.at[pages, :, offs].set(k.astype(kp.dtype), mode="drop")
+        vp = vp.at[pages, :, offs].set(v.astype(vp.dtype), mode="drop")
     o = _paged_attend(cfg, q, kp, vp, table, n_valid, mesh,
                       k_scale=kscale, v_scale=vscale)
     delta = jnp.einsum("bhk,hkd->bd", o, lp["wo"])
@@ -830,14 +831,19 @@ def _decode_cross_paged(cfg, lp, h, xk, xv, enc_lens, page_size,
                         mesh=None):
     """Cross-attention against the slot-dense encoder cache, VIEWED as
     an identity-paged pool (slot b's pages are rows [b*Jx, (b+1)*Jx) of
-    the reshaped cache — a zero-copy reshape, no gather), so per-slot
-    encoder lengths ride the same paged masking as self-attention.
-    Cross KV is static per slot and attended shard-locally."""
+    the reshaped cache, turned head-major like the self-attention
+    pools), so per-slot encoder lengths ride the same paged masking as
+    self-attention.  Cross KV is static per slot and attended
+    shard-locally."""
     from repro.dist import decode as DD
     B, Tx = xk.shape[0], xk.shape[1]
     Jx = Tx // page_size
-    kp = xk.reshape(B * Jx, page_size, *xk.shape[2:])
-    vp = xv.reshape(B * Jx, page_size, *xv.shape[2:])
+
+    def pool_view(x):                 # (B, Tx, KV, Dh) -> (B*Jx, KV, ps, Dh)
+        return jnp.swapaxes(
+            x.reshape(B * Jx, page_size, *x.shape[2:]), 1, 2)
+
+    kp, vp = pool_view(xk), pool_view(xv)
     tbl = (jnp.arange(B, dtype=jnp.int32)[:, None] * Jx
            + jnp.arange(Jx, dtype=jnp.int32)[None, :])
     q = jnp.einsum("bd,dhk->bhk", h, lp["wq"])
@@ -876,7 +882,7 @@ def _dense_paged_body(cfg, lens, table, x, lp, cache_slice, mesh=None):
                                mesh)
     x = x + d
     x = x + L.mlp(lp["mlp"], _norm(cfg, lp["mlp_norm"], x), cfg.act,
-                  backend=cfg)
+                  backend=cfg, mesh=mesh)
     return x, new
 
 
@@ -899,9 +905,9 @@ def _audio_paged_body(cfg, lens, table, enc_lens, x, lp, cs, mesh=None):
     h = _norm(cfg, lp["cross_norm"], x)
     x = x + _decode_cross_paged(cfg, lp["cross"], h, cs["cross_k"],
                                 cs["cross_v"], enc_lens,
-                                cs["self_k"].shape[1], mesh)
+                                cs["self_k"].shape[2], mesh)
     x = x + L.mlp(lp["mlp"], _norm(cfg, lp["mlp_norm"], x), cfg.act,
-                  backend=cfg)
+                  backend=cfg, mesh=mesh)
     return x, {"self_k": kp, "self_v": vp}
 
 
@@ -977,7 +983,7 @@ def _dense_decode_body(cfg, cur_len, x, lp, cache_slice, mesh=None):
         new = {"k": ck, "v": cv}
     x = x + d
     x = x + L.mlp(lp["mlp"], _norm(cfg, lp["mlp_norm"], x), cfg.act,
-                  backend=cfg)
+                  backend=cfg, mesh=mesh)
     return x, new
 
 
@@ -1054,7 +1060,7 @@ def decode_step(params, batch, cfg, mesh=None):
             d, ck, cv = _decode_gqa(cfg, sp["attn"], h, ck, cv, cur, mesh)
             x = x + d
             x = x + L.mlp(sp["mlp"], _norm(cfg, sp["mlp_norm"], x), cfg.act,
-                          backend=cfg)
+                          backend=cfg, mesh=mesh)
             return x, ck, cv
 
         def group_dec(x, gp, ex):
@@ -1108,7 +1114,7 @@ def decode_step(params, batch, cfg, mesh=None):
             x = x + _decode_cross(cfg, lp["cross"], h, cs["cross_k"],
                                   cs["cross_v"], mesh)
             x = x + L.mlp(lp["mlp"], _norm(cfg, lp["mlp_norm"], x), cfg.act,
-                          backend=cfg)
+                          backend=cfg, mesh=mesh)
             return x, {"self_k": ck, "self_v": cv}
 
         xs_cache = {"self_k": cache["self_k"], "self_v": cache["self_v"],
@@ -1152,15 +1158,13 @@ def _gather_prefix_kv(sub, keys, pages):
     are bit-identical to the KV the original prefill wrote."""
     out = []
     for kk in keys:
-        pool = sub[kk]                       # (L, n_pages, ps, ...)
-        g = pool[:, pages]                   # (L, J, ps, ...)
+        g = sub[kk][:, pages]       # GQA (L, J, KV, ps, Dh), MLA (L, J, ps, r)
         if kk + "_scale" in sub:
             s = sub[kk + "_scale"][:, pages]  # (L, J[, KV])
-            if g.ndim == 5:                   # GQA: per-page per-head
-                s = s[:, :, None, :, None]
-            else:                             # MLA latent: per-page
-                s = s[:, :, None, None]
-            g = g.astype(jnp.float32) * s
+            g = g.astype(jnp.float32) * s.reshape(
+                s.shape + (1,) * (g.ndim - s.ndim))
+        if g.ndim == 5:                       # GQA: back to (ps, KV, Dh)
+            g = jnp.swapaxes(g, 2, 3)
         L, J, ps = g.shape[:3]
         out.append(g.reshape(L, 1, J * ps, *g.shape[3:]))
     return tuple(out)
@@ -1183,7 +1187,8 @@ def _suffix_attn_delta(cfg, ap, h, q_pos, kv_pos, prefix, *,
             head_axis=_head_axis(cfg), mesh=mesh)
         return (out if residual is None else residual + out), cache
     pk, pv = prefix
-    q, k, v = A.qkv_proj(ap, h, q_pos, cfg.rope_theta, cfg)
+    q, k, v = A.qkv_proj(ap, h, q_pos, cfg.rope_theta, cfg,
+                         mesh=mesh)
     k_all = jnp.concatenate([pk.astype(k.dtype), k], axis=1)
     v_all = jnp.concatenate([pv.astype(v.dtype), v], axis=1)
     if cfg.accounting:
@@ -1195,7 +1200,7 @@ def _suffix_attn_delta(cfg, ap, h, q_pos, kv_pos, prefix, *,
                              block_q=cfg.attn_block_q,
                              block_kv=cfg.attn_block_kv,
                              head_axis=_head_axis(cfg), mesh=mesh)
-    return A.o_proj(ap, o, cfg, residual=residual), (k, v)
+    return A.o_proj(ap, o, cfg, residual=residual, mesh=mesh), (k, v)
 
 
 def _dense_suffix_body(cfg, q_pos, kv_pos, x, lp, prefix, *, mesh=None):
@@ -1204,7 +1209,7 @@ def _dense_suffix_body(cfg, q_pos, kv_pos, x, lp, prefix, *, mesh=None):
                                q_pos, kv_pos, prefix, residual=x,
                                mesh=mesh)
     x = L.mlp(lp["mlp"], _norm(cfg, lp["mlp_norm"], x), cfg.act,
-              backend=cfg, residual=x)
+              backend=cfg, residual=x, mesh=mesh)
     return x, kv
 
 
@@ -1249,7 +1254,7 @@ def prefill_suffix(params, batch, cfg, mesh=None):
     cache = batch["cache"]
     keys = ("ckv", "krope") if cfg.mla is not None else ("k", "v")
     sub = cache["moe"] if fam == "moe" else cache
-    ps = sub[keys[0]].shape[2]
+    ps = sub[keys[0]].shape[-2]
     M = pages.shape[0] * ps
     S = tokens.shape[1]
     q_pos = jnp.arange(S) + M

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as PS
 
 from repro.common.hints import shard_hint
-from repro.common.module import ParamDef
+from repro.common.module import ParamDef, head_out_init, head_proj_init
 from repro.kernels import dispatch as D
 from repro.models.attention import NEG_INF, blockwise_attn
 from repro.models.layers import apply_rope, rmsnorm, rmsnorm_spec
@@ -33,16 +33,18 @@ def mla_spec(cfg):
     return {
         "wq_a": ParamDef((d, m.q_lora_rank), dtype, ("embed", "q_lora")),
         "q_norm": rmsnorm_spec(m.q_lora_rank, dtype),
-        "wq_b": ParamDef((m.q_lora_rank, H, qd), dtype, ("q_lora", "heads", "head_dim")),
+        "wq_b": ParamDef((m.q_lora_rank, H, qd), dtype,
+                         ("q_lora", "heads", "head_dim"), head_proj_init),
         "wkv_a": ParamDef(
             (d, m.kv_lora_rank + m.rope_head_dim), dtype, ("embed", "kv_lora")
         ),
         "kv_norm": rmsnorm_spec(m.kv_lora_rank, dtype),
         "wk_b": ParamDef((m.kv_lora_rank, H, m.nope_head_dim), dtype,
-                         ("kv_lora", "heads", "head_dim")),
+                         ("kv_lora", "heads", "head_dim"), head_proj_init),
         "wv_b": ParamDef((m.kv_lora_rank, H, m.v_head_dim), dtype,
-                         ("kv_lora", "heads", "head_dim")),
-        "wo": ParamDef((H, m.v_head_dim, d), dtype, ("heads", "head_dim", "embed")),
+                         ("kv_lora", "heads", "head_dim"), head_proj_init),
+        "wo": ParamDef((H, m.v_head_dim, d), dtype,
+                       ("heads", "head_dim", "embed"), head_out_init),
     }
 
 
@@ -498,25 +500,28 @@ def mla_absorbed_mqa(p, q_nope, q_rope, cache_ckv, cache_krope, cfg):
     return q_cat, k_cat, v_cat, r
 
 
-def mla_concat_view(q_abs, q_rope, c_kv, k_rope, scale: float):
+def mla_concat_view(q_abs, q_rope, c_kv, k_rope, scale: float, *,
+                    paged: bool = False):
     """Concatenated k_cat/v_cat view of the SPLIT decode operands —
     equivalence reference only (tests, ``mla_concat`` benchmark rows).
 
     q_abs: (B,H,r) fp32; q_rope: (B,H,rope); c_kv / k_rope: the latent
     and rope caches with trailing feature dims — dense ``(B,T,...)``
-    and paged ``(n_pages, ps, ...)`` layouts both work.  Returns
+    or paged ``(n_pages, ps, ...)`` (``paged=True``).  Returns
     (q_cat, k_cat, v_cat, r): q_cat is pre-scaled by
     ``scale * sqrt(Dc)`` so the plain decode ops' 1/sqrt(Dc) nets to
-    the absorbed-MLA ``scale``; k_cat/v_cat grow a KV=1 head axis and
-    v_cat zero-pads the rope features.  Every site pinning
+    the absorbed-MLA ``scale``; k_cat/v_cat grow a KV=1 head axis —
+    ``(B, T, 1, Dc)`` dense, head-major ``(n_pages, 1, ps, Dc)`` paged
+    — and v_cat zero-pads the rope features.  Every site pinning
     split-vs-concat equivalence must build the concat side HERE so the
     baselines cannot drift apart."""
     r = c_kv.shape[-1]
     Dc = r + k_rope.shape[-1]
     q_cat = jnp.concatenate([q_abs, q_rope], -1) * (scale * Dc ** 0.5)
-    k_cat = jnp.concatenate([c_kv, k_rope], -1)[..., None, :]
-    v_cat = jnp.concatenate([c_kv, jnp.zeros_like(k_rope)],
-                            -1)[..., None, :]
+    head = 1 if paged else -2
+    k_cat = jnp.expand_dims(jnp.concatenate([c_kv, k_rope], -1), head)
+    v_cat = jnp.expand_dims(
+        jnp.concatenate([c_kv, jnp.zeros_like(k_rope)], -1), head)
     return q_cat, k_cat, v_cat, r
 
 

@@ -184,7 +184,7 @@ def moe_ffn(p, x, cfg, mesh=None) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     y = y.astype(x.dtype)
 
     if m.n_shared:
-        y = y + mlp(p["shared"], x, "swiglu", backend=cfg)
+        y = y + mlp(p["shared"], x, "swiglu", backend=cfg, mesh=mesh)
 
     # aux metrics / losses (fp32)
     me = probs.mean(axis=(0, 1))                                # (E,) mean prob

@@ -23,7 +23,8 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.common.module import ParamDef, const_init, zeros_init
+from repro.common.module import (ParamDef, const_init, head_proj_init,
+                                 zeros_init)
 from repro.models.layers import rmsnorm, rmsnorm_spec
 
 # ======================================================================
@@ -42,9 +43,12 @@ def mlstm_spec(cfg):
         "in_proj": ParamDef((D, 2 * d_inner), dtype, ("embed", "inner_all")),
         "conv_w": ParamDef((K, d_inner), dtype, ("conv_k", "inner")),
         "conv_b": ParamDef((d_inner,), dtype, ("inner",), zeros_init),
-        "wq": ParamDef((d_inner, H, P), dtype, ("inner", "heads", "head_dim")),
-        "wk": ParamDef((d_inner, H, P), dtype, ("inner", "heads", "head_dim")),
-        "wv": ParamDef((d_inner, H, P), dtype, ("inner", "heads", "head_dim")),
+        "wq": ParamDef((d_inner, H, P), dtype, ("inner", "heads", "head_dim"),
+                       head_proj_init),
+        "wk": ParamDef((d_inner, H, P), dtype, ("inner", "heads", "head_dim"),
+                       head_proj_init),
+        "wv": ParamDef((d_inner, H, P), dtype, ("inner", "heads", "head_dim"),
+                       head_proj_init),
         "w_i": ParamDef((d_inner, H), jnp.float32, ("inner", "heads"), zeros_init),
         "b_i": ParamDef((H,), jnp.float32, ("heads",), zeros_init),
         "w_f": ParamDef((d_inner, H), jnp.float32, ("inner", "heads"), zeros_init),

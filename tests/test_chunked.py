@@ -100,8 +100,8 @@ def test_pack_chunk_boundaries(remaining, n_decode, budget, want):
 
 
 def _pool(rng, n_pages=6, KV=2, Dh=16):
-    k = rng.standard_normal((n_pages, PS, KV, Dh)).astype(np.float32)
-    v = rng.standard_normal((n_pages, PS, KV, Dh)).astype(np.float32)
+    k = rng.standard_normal((n_pages, KV, PS, Dh)).astype(np.float32)
+    v = rng.standard_normal((n_pages, KV, PS, Dh)).astype(np.float32)
     return jnp.asarray(k), jnp.asarray(v)
 
 
@@ -128,8 +128,8 @@ def test_chunk_prefix_pallas_matches_xla(counts, rng):
 def test_chunk_prefix_q8_pallas_matches_xla(rng):
     from repro.models.attention import D
     C, H, KV, Dh = 8, 4, 2, 16
-    kq = jnp.asarray(rng.integers(-127, 128, (6, PS, KV, Dh)), jnp.int8)
-    vq = jnp.asarray(rng.integers(-127, 128, (6, PS, KV, Dh)), jnp.int8)
+    kq = jnp.asarray(rng.integers(-127, 128, (6, KV, PS, Dh)), jnp.int8)
+    vq = jnp.asarray(rng.integers(-127, 128, (6, KV, PS, Dh)), jnp.int8)
     ks = jnp.asarray(rng.uniform(0.01, 0.05, (6, KV)), jnp.float32)
     vs = jnp.asarray(rng.uniform(0.01, 0.05, (6, KV)), jnp.float32)
     q = jnp.asarray(rng.standard_normal((C, H, Dh)), jnp.float32)
@@ -174,8 +174,8 @@ def test_chunk_rows_match_whole_prefill_rows(rng):
         jnp.asarray(q), jnp.asarray(k), jnp.asarray(v))
     whole = np.asarray(A.normalize_partial(o_t, l, jnp.float32))
     n_pages = S // PS
-    kp = jnp.asarray(k.reshape(n_pages, PS, KV, Dh))
-    vp = jnp.asarray(v.reshape(n_pages, PS, KV, Dh))
+    kp = jnp.asarray(k.reshape(n_pages, PS, KV, Dh).transpose(0, 2, 1, 3))
+    vp = jnp.asarray(v.reshape(n_pages, PS, KV, Dh).transpose(0, 2, 1, 3))
     # chunks after the first (chunk 0 has no prior pages; its identity
     # with plain causal self-attention is pinned above)
     for c0 in range(C, S, C):

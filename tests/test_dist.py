@@ -10,6 +10,7 @@ def _run(body: str):
     code = ("import os\n"
             "os.environ['XLA_FLAGS']="
             "'--xla_force_host_platform_device_count=8'\n"
+            "from repro.launch.mesh import make_mesh\n"
             + textwrap.dedent(body))
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, timeout=600,
@@ -25,7 +26,7 @@ def test_distributed_flash_decode_matches_local():
     from repro.dist.decode import sharded_flash_decode
     from repro.models.attention import decode_attend_local
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     key = jax.random.PRNGKey(0)
     B, T, KV, Dh, H = 2, 64, 2, 16, 4
     q = jax.random.normal(key, (B, H, Dh))
@@ -77,12 +78,12 @@ def test_paged_pool_seq_sharded_matches_dense_engine():
                                       err_msg=tag)
 
     # op level: arbitrary page->shard placement, both backends
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ks = jax.random.split(key, 3)
     Bq, KV, D, H, ps, J, n_pages = 2, 2, 16, 4, 4, 6, 16
     q = jax.random.normal(ks[0], (Bq, H, D))
-    kp = jax.random.normal(ks[1], (n_pages, ps, KV, D))
-    vp = jax.random.normal(ks[2], (n_pages, ps, KV, D))
+    kp = jax.random.normal(ks[1], (n_pages, KV, ps, D))
+    vp = jax.random.normal(ks[2], (n_pages, KV, ps, D))
     table = jnp.asarray(np.random.default_rng(0).permutation(n_pages)
                         [:Bq * J].reshape(Bq, J), jnp.int32)
     lens = jnp.array([13, 21], jnp.int32)
@@ -111,15 +112,15 @@ def test_seq_sharded_q8_paged_decode_matches_local():
                                    sharded_paged_flash_decode)
     from repro.kernels.quant import quantize_int8
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     key = jax.random.PRNGKey(0)
     ks = jax.random.split(key, 3)
     B, KV, D, H, ps, J, n_pages = 2, 2, 16, 4, 4, 6, 16
     q = jax.random.normal(ks[0], (B, H, D))
-    kp = jax.random.normal(ks[1], (n_pages, ps, KV, D))
-    vp = jax.random.normal(ks[2], (n_pages, ps, KV, D))
-    kq, ksc = quantize_int8(kp, axis=(1, 3))
-    vq, vsc = quantize_int8(vp, axis=(1, 3))
+    kp = jax.random.normal(ks[1], (n_pages, KV, ps, D))
+    vp = jax.random.normal(ks[2], (n_pages, KV, ps, D))
+    kq, ksc = quantize_int8(kp, axis=(2, 3))
+    vq, vsc = quantize_int8(vp, axis=(2, 3))
     ksc, vsc = ksc.reshape(n_pages, KV), vsc.reshape(n_pages, KV)
     table = jnp.asarray(np.random.default_rng(0).permutation(n_pages)
                         [:B * J].reshape(B, J), jnp.int32)
@@ -197,7 +198,7 @@ def test_pipeline_matches_sequential():
     import jax, jax.numpy as jnp, numpy as np
     from repro.dist.pipeline import pipeline_apply
 
-    mesh = jax.make_mesh((4,), ("pipe",))
+    mesh = make_mesh((4,), ("pipe",))
     S, n_micro, mb, D = 4, 8, 4, 16
     key = jax.random.PRNGKey(1)
     stage_w = jax.random.normal(key, (S, D, D)) / (D ** 0.5)
@@ -219,11 +220,10 @@ def test_pipeline_matches_sequential():
 def test_compressed_psum_close_and_error_feedback():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as PS
     from repro.dist.compression import compressed_psum
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     g = jax.random.normal(jax.random.PRNGKey(0), (8, 256))
     err0 = jnp.zeros((8, 256))
 
@@ -231,9 +231,9 @@ def test_compressed_psum_close_and_error_feedback():
         out, e2 = compressed_psum(g, e, "data", 8)
         return out, e2
 
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(PS("data"), PS("data")),
-                   out_specs=(PS("data"), PS("data")))
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(PS("data"), PS("data")),
+                       out_specs=(PS("data"), PS("data")))
     got, err = fn(g, err0)
     want = jnp.mean(g, axis=0, keepdims=True)      # psum/8 per shard
     # int8 quantization: close but not exact; error feedback captures
@@ -261,7 +261,7 @@ def test_sharded_train_step_runs_and_matches_single():
     from repro.optim import adamw
 
     cfg = reduced(get_config("olmoe-1b-7b"))
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     key = jax.random.PRNGKey(0)
     B, S = 4, 32
     tokens = jax.random.randint(key, (B, S), 0, cfg.vocab)
@@ -290,7 +290,7 @@ def test_zero1_pspecs_shard_replicated_dims():
     from jax.sharding import PartitionSpec as PS
     from repro.optim import adamw
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     pspecs = {"w": PS(None, "model"), "b": PS(None)}
     avals = {"w": jax.ShapeDtypeStruct((64, 32), jnp.float32),
              "b": jax.ShapeDtypeStruct((64,), jnp.float32)}
@@ -310,12 +310,12 @@ def test_elastic_restore_across_mesh_shapes(tmp_path):
     from repro.checkpoint import CheckpointStore
 
     store = CheckpointStore({str(tmp_path)!r})
-    mesh1 = jax.make_mesh((2, 4), ("data", "model"))
+    mesh1 = make_mesh((2, 4), ("data", "model"))
     x = jnp.arange(64.0 * 32).reshape(64, 32)
     xs = jax.device_put(x, NamedSharding(mesh1, PS("data", "model")))
     store.save(1, {{"x": xs}})
 
-    mesh2 = jax.make_mesh((8, 1), ("data", "model"))
+    mesh2 = make_mesh((8, 1), ("data", "model"))
     tgt = {{"x": jax.ShapeDtypeStruct((64, 32), jnp.float32)}}
     sh2 = {{"x": NamedSharding(mesh2, PS("model", "data"))}}
     out = store.restore(1, tgt, sh2)
@@ -333,7 +333,7 @@ def test_hlo_collective_parser_counts_scan_trips():
     from jax.sharding import NamedSharding, PartitionSpec as PS
     from repro.launch import hlo_analysis
 
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = make_mesh((8,), ("model",))
     W = jax.ShapeDtypeStruct((10, 64, 64), jnp.float32)
     x = jax.ShapeDtypeStruct((4, 64), jnp.float32)
 
@@ -367,7 +367,7 @@ def test_distributed_flash_decode_pallas_kernel_path():
     from repro.dist.decode import sharded_flash_decode
     from repro.models.attention import decode_attend_local
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     key = jax.random.PRNGKey(0)
     B, T, KV, Dh, H = 2, 64, 2, 16, 8
     ks = jax.random.split(key, 3)
@@ -405,7 +405,7 @@ def test_serve_sharded_decode_matches_local():
     batch = {"token": tok, "cur_len": jnp.int32(5), "cache": cache}
     want, _ = lm.decode_step(params, batch, cfg)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     scfg = cfg.replace(decode_shard="seq")
     p_sh = jax.device_put(params, SH.to_shardings(
         mesh, SH.param_pspecs(scfg, mesh, "serve")))
@@ -442,7 +442,7 @@ def test_serve_sharded_mla_decode_matches_local():
     batch = {"token": tok, "cur_len": jnp.int32(5), "cache": cache}
     want, _ = lm.decode_step(params, batch, cfg)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     scfg = cfg.replace(decode_shard="seq")
     p_sh = jax.device_put(params, SH.to_shardings(
         mesh, SH.param_pspecs(scfg, mesh, "serve")))
@@ -472,7 +472,7 @@ def test_sharded_mla_split_matches_concat_view():
     from repro.kernels import dispatch as D
     from repro.models import mla as MLA
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     key = jax.random.PRNGKey(0)
     B, H, r, rope, T = 2, 4, 16, 8, 64
     ks = jax.random.split(key, 4)
@@ -574,7 +574,7 @@ def test_shard_hint_explicit_mesh_applies_constraint():
     from jax.sharding import NamedSharding, PartitionSpec as PS
     from repro.common.hints import shard_batch, shard_hint
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     x = jnp.zeros((8, 4, 16))
 
     def f(x):
@@ -597,7 +597,7 @@ def test_pipeline_handles_multi_microbatch_drain():
     import jax, jax.numpy as jnp, numpy as np
     from repro.dist.pipeline import pipeline_apply
 
-    mesh = jax.make_mesh((4,), ("pipe",))
+    mesh = make_mesh((4,), ("pipe",))
     S, n_micro, mb, D = 4, 5, 3, 8
     key = jax.random.PRNGKey(2)
     stage_w = jax.random.normal(key, (S, D, D)) / (D ** 0.5)

@@ -69,6 +69,27 @@ def test_serve_cli_defaults_and_vlm_budget():
     assert serve.engine_config_from_args(args, vlm).max_len == 32
 
 
+def test_serve_stream_exits_nonzero_on_lost_requests():
+    """`--reduce none` parses; a stream with a FAILED or REJECTED request
+    ends the process non-zero, a fully FINISHED one does not."""
+    import types
+    from repro.engine import RequestStatus
+    from repro.launch import serve
+
+    args = serve.build_parser().parse_args(
+        ["--arch", "tinyllama-1.1b", "--reduce", "none"])
+    assert args.reduce == "none"
+
+    def res(status):
+        return types.SimpleNamespace(status=status, error="boom")
+
+    serve._exit_if_lost({0: res(RequestStatus.FINISHED)})
+    for lost in (RequestStatus.FAILED, RequestStatus.REJECTED):
+        with pytest.raises(SystemExit, match="1 request"):
+            serve._exit_if_lost({0: res(RequestStatus.FINISHED),
+                                 1: res(lost)})
+
+
 # ---------------------------------------------------------------- engine
 
 
@@ -122,8 +143,8 @@ def test_engine_inherits_cfg_pinned_knobs():
 
 def test_engine_seq_shard_divisibility_checked():
     """(A stub mesh stands in for a 2-chip model axis: the check fires
-    before the engine touches devices, and make_local_mesh would clamp
-    (1, 2) to the single CPU device anyway.)"""
+    before the engine touches devices, and make_local_mesh refuses
+    (1, 2) on the single CPU device.)"""
     class _Mesh:
         shape = {"data": 1, "model": 2}
 
@@ -131,6 +152,22 @@ def test_engine_seq_shard_divisibility_checked():
         DecodeEngine(_cfg(), EngineConfig(batch=2, max_len=13,
                                           decode_shard="seq"),
                      mesh=_Mesh())
+
+
+def test_make_local_mesh_refuses_missing_devices():
+    """A mesh needing more devices than exist is an error, never a
+    silently smaller mesh; the one constructor states Auto axes."""
+    from jax.sharding import AxisType
+    from repro.launch.mesh import make_local_mesh
+    n = jax.device_count()
+    with pytest.raises(ValueError, match="devices"):
+        make_local_mesh(1, n + 1)
+    mesh = make_local_mesh(1, 1)
+    assert mesh.shape == {"data": 1, "model": 1}
+    assert set(mesh.axis_types) == {AxisType.Auto}
+    with pytest.raises(ValueError, match="devices"):
+        DecodeEngine(_cfg(), EngineConfig(batch=1, max_len=8,
+                                          mesh_shape=(n + 1, 1)))
 
 
 # ---------------------------------------------------------------- cache

@@ -251,8 +251,8 @@ def test_vwr_paged_flash_decode_matches_gather_ref(dtype, b, h, kv, d,
     n_pages = b * j + 3
     k1, k2, k3, k4 = jax.random.split(KEY, 4)
     q = _rand(k1, (b, h, d), dtype)
-    kp = _rand(k2, (n_pages, ps, kv, d), dtype)
-    vp = _rand(k3, (n_pages, ps, kv, d), dtype)
+    kp = _rand(k2, (n_pages, kv, ps, d), dtype)
+    vp = _rand(k3, (n_pages, kv, ps, d), dtype)
     # shuffled disjoint page assignment + ragged per-slot lengths
     perm = jax.random.permutation(k4, n_pages)[:b * j]
     table = perm.reshape(b, j).astype(jnp.int32)
@@ -268,8 +268,8 @@ def test_vwr_paged_flash_decode_matches_gather_ref(dtype, b, h, kv, d,
                                    atol=5 * tol["atol"], err_msg=name)
     # and the gathered-dense oracle agrees slot by slot
     from repro.models.attention import decode_attend_local
-    dense_k = kp[table].reshape(b, j * ps, kv, d)
-    dense_v = vp[table].reshape(b, j * ps, kv, d)
+    dense_k = jnp.swapaxes(kp[table], 2, 3).reshape(b, j * ps, kv, d)
+    dense_v = jnp.swapaxes(vp[table], 2, 3).reshape(b, j * ps, kv, d)
     norm = (got[0] / jnp.maximum(got[2], 1e-30)[..., None])
     for slot in range(b):
         if int(lens[slot]) == 0:

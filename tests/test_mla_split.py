@@ -13,7 +13,8 @@ import numpy as np
 import pytest
 
 from repro.common.config import MLAConfig, ModelConfig
-from repro.engine import DecodeEngine, EngineConfig, Request, Scheduler
+from repro.engine import (DecodeEngine, EngineConfig, Request,
+                          RequestStatus, Scheduler)
 from repro.engine.paged_cache import bucket_table_width
 from repro.kernels import dispatch as D
 from repro.models import mla as MLA
@@ -55,7 +56,7 @@ def _concat_mla_paged_partial(q_abs, q_rope, ckv_pool, krope_pool,
                               max_pages=None, tune=True):
     q_cat, k_cat, v_cat, r = MLA.mla_concat_view(q_abs, q_rope,
                                                  ckv_pool, krope_pool,
-                                                 scale)
+                                                 scale, paged=True)
     o_t, m, l = D.dispatch("decode_partial_paged", "xla", q_cat, k_cat,
                            v_cat, table, counts)
     return o_t[..., :r], m, l
@@ -202,6 +203,10 @@ def test_scheduler_bucketed_tables_match_fixed_width(mla, rng):
     got, stats_b = run(True)
     want, stats_f = run(False)
     assert set(got) == set(want) == {0, 1, 2}
+    # a prefill that raised marks its request FAILED with no decode
+    # step at all, which would leave table_widths empty
+    assert all(v.status is RequestStatus.FINISHED
+               for v in (*got.values(), *want.values()))
     for rid in want:
         np.testing.assert_array_equal(got[rid], want[rid],
                                       err_msg=f"request {rid}")
@@ -231,7 +236,7 @@ def test_paged_dispatch_cache_keyed_by_page_geometry(tmp_path,
     autotune.reset()
     B, H, KV, Dh, ps, J, n_pages = 2, 4, 2, 16, 4, 6, 12
     q = jnp.zeros((B, H, Dh))
-    kp = jnp.zeros((n_pages, ps, KV, Dh))
+    kp = jnp.zeros((n_pages, KV, ps, Dh))
     tbl = jnp.zeros((B, J), jnp.int32)
     cnt = jnp.zeros((B, J), jnp.int32)
     args = (q, kp, kp, tbl, cnt)
@@ -272,9 +277,9 @@ def test_paged_dispatch_cache_keyed_by_pool_dtype(tmp_path,
     tbl = jnp.zeros((B, J), jnp.int32)
     cnt = jnp.zeros((B, J), jnp.int32)
     geom = {"page_size": ps, "max_pages": J}
-    kp16 = jnp.zeros((n_pages, ps, KV, Dh), jnp.bfloat16)
+    kp16 = jnp.zeros((n_pages, KV, ps, Dh), jnp.bfloat16)
     bf16_args = (q, kp16, kp16, tbl, cnt)
-    kp8 = jnp.zeros((n_pages, ps, KV, Dh), jnp.int8)
+    kp8 = jnp.zeros((n_pages, KV, ps, Dh), jnp.int8)
     sc = jnp.zeros((n_pages, KV), jnp.float32)
     q8_args = (q, kp8, kp8, sc, sc, tbl, cnt)
 

@@ -22,6 +22,36 @@ def _cfg(**kw):
     return ModelConfig(**base)
 
 
+# ---------------------------------------------------------------- init
+
+@pytest.mark.parametrize("family", ["gqa", "mla", "xlstm"])
+def test_per_head_projections_init_with_their_true_fan_in(family):
+    """A per-head projection (d, H, Dh) draws with std 1/sqrt(d) and
+    its output (H, Dh, d) with 1/sqrt(H*Dh); the second-to-last axis
+    alone (H, or Dh) would give random models attention scores of
+    std ~sqrt(d) and a one-hot softmax."""
+    if family == "gqa":
+        cfg = _cfg(d_model=512, n_heads=8, n_kv_heads=2, d_head=64)
+        p = A.gqa_spec(cfg)
+        ins, outs = ("wq", "wk", "wv"), ("wo",)
+    elif family == "mla":
+        cfg = _cfg(mla=MLAConfig(q_lora_rank=256, kv_lora_rank=128,
+                                 rope_head_dim=16, nope_head_dim=32,
+                                 v_head_dim=32))
+        p = mla.mla_spec(cfg)
+        ins, outs = ("wq_b", "wk_b", "wv_b"), ("wo",)
+    else:
+        cfg = _cfg(family="ssm", d_model=128, xlstm=XLSTMConfig())
+        p = xlstm.mlstm_spec(cfg)
+        ins, outs = ("wq", "wk", "wv"), ()
+    for name in ins + outs:
+        d = p[name]
+        fan_in = d.shape[0] if name in ins else d.shape[0] * d.shape[1]
+        w = np.asarray(d.init(KEY, d.shape, jnp.float32))
+        np.testing.assert_allclose(w.std(), fan_in ** -0.5, rtol=0.05,
+                                   err_msg=name)
+
+
 # ---------------------------------------------------------------- attn
 
 def test_blockwise_attention_matches_dense():

@@ -89,10 +89,10 @@ def _quant_cache(k, v):
 
 
 def _quant_pools(kp, vp):
-    """(n_pages,ps,KV,Dh) pools -> int8 + per-(page,KV) fp32 scales."""
-    n_pages, _, KV, _ = kp.shape
-    kq, ks = quantize_int8(kp, axis=(1, 3))
-    vq, vs = quantize_int8(vp, axis=(1, 3))
+    """(n_pages,KV,ps,Dh) pools -> int8 + per-(page,KV) fp32 scales."""
+    n_pages, KV, _, _ = kp.shape
+    kq, ks = quantize_int8(kp, axis=(2, 3))
+    vq, vs = quantize_int8(vp, axis=(2, 3))
     return kq, vq, ks.reshape(n_pages, KV), vs.reshape(n_pages, KV)
 
 
@@ -122,16 +122,16 @@ def test_q8_paged_op_matches_dequantized_reference(backend):
     B, KV, Dh, H, ps, J, n_pages = 2, 2, 16, 4, 4, 6, 16
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (B, H, Dh))
-    kp = jax.random.normal(ks[1], (n_pages, ps, KV, Dh))
-    vp = jax.random.normal(ks[2], (n_pages, ps, KV, Dh))
+    kp = jax.random.normal(ks[1], (n_pages, KV, ps, Dh))
+    vp = jax.random.normal(ks[2], (n_pages, KV, ps, Dh))
     kq, vq, ksc, vsc = _quant_pools(kp, vp)
     table = jnp.asarray(np.random.default_rng(0).permutation(n_pages)
                         [:B * J].reshape(B, J), jnp.int32)
     lens = jnp.array([13, 21], jnp.int32)
     counts = jnp.clip(lens[:, None] - jnp.arange(J)[None, :] * ps,
                       0, ps).astype(jnp.int32)
-    kf = kq.astype(jnp.float32) * ksc[:, None, :, None]
-    vf = vq.astype(jnp.float32) * vsc[:, None, :, None]
+    kf = kq.astype(jnp.float32) * ksc[:, :, None, None]
+    vf = vq.astype(jnp.float32) * vsc[:, :, None, None]
     want = D.dispatch("decode_partial_paged", "xla", q, kf, vf, table,
                       counts)
     got = D.dispatch("decode_partial_paged_q8", backend, q, kq, vq,
@@ -220,13 +220,13 @@ def test_prefill_scatter_q8_roundtrip_error_bounds():
     kv = jax.random.normal(KEY, (L, B, S, KV, Dh)) * 2.0
     J = -(-S // ps)
     table = jnp.asarray([[0, 1, 9], [4, 3, 7]], jnp.int32)
-    pool = jnp.zeros((L, n_pages, ps, KV, Dh), jnp.int8)
+    pool = jnp.zeros((L, n_pages, KV, ps, Dh), jnp.int8)
     scales = jnp.zeros((L, n_pages, KV), jnp.float32)
     pool, scales = PC._scatter_pages_q8(pool, scales, kv, table)
 
     got = (pool[:, table[:, :J]].astype(jnp.float32)
-           * scales[:, table[:, :J]][:, :, :, None, :, None])
-    got = got.reshape(L, B, J * ps, KV, Dh)
+           * scales[:, table[:, :J]][..., None, None])
+    got = jnp.swapaxes(got, 3, 4).reshape(L, B, J * ps, KV, Dh)
     err = jnp.abs(got[:, :, :S] - kv)
     step = scales[:, table[:, :J]].max()
     assert float(err.max()) <= float(step) / 2 + 1e-7
@@ -240,7 +240,7 @@ def test_quantized_page_write_fresh_reset_and_growth():
     requantize resident rows onto the new grid; inactive slots (page id
     == n_pages) are dropped."""
     n_pages, ps, KV, Dh = 4, 4, 2, 8
-    pool = jnp.full((n_pages, ps, KV, Dh), 55, jnp.int8)  # stale bytes
+    pool = jnp.full((n_pages, KV, ps, Dh), 55, jnp.int8)  # stale bytes
     scales = jnp.full((n_pages, KV), 9.9, jnp.float32)    # stale scales
     x0 = jax.random.normal(KEY, (1, KV, Dh))
     pages = jnp.array([2], jnp.int32)
@@ -250,11 +250,11 @@ def test_quantized_page_write_fresh_reset_and_growth():
         pool, scales, pages, jnp.array([0], jnp.int32), x0)
     s0 = np.asarray(int8_scale(jnp.max(jnp.abs(x0), axis=-1))[0])
     np.testing.assert_allclose(np.asarray(scales[2]), s0, rtol=1e-6)
-    row0 = np.asarray(pool[2, 0].astype(jnp.float32)
+    row0 = np.asarray(pool[2, :, 0].astype(jnp.float32)
                       * scales[2][:, None])
     np.testing.assert_allclose(row0, np.asarray(x0[0]),
                                atol=float(s0.max()) / 2 + 1e-7)
-    np.testing.assert_array_equal(np.asarray(pool[2, 1:]), 0)
+    np.testing.assert_array_equal(np.asarray(pool[2, :, 1:]), 0)
 
     # growth: a larger token raises the scale; the resident row is
     # requantized onto the new grid and stays within its half-step
@@ -263,7 +263,7 @@ def test_quantized_page_write_fresh_reset_and_growth():
         pool, scales, pages, jnp.array([1], jnp.int32), x1)
     s1 = np.asarray(scales[2])
     assert (s1 >= s0 - 1e-9).all()
-    row0 = np.asarray(pool[2, 0].astype(jnp.float32)
+    row0 = np.asarray(pool[2, :, 0].astype(jnp.float32)
                       * scales[2][:, None])
     np.testing.assert_allclose(row0, np.asarray(x0[0]),
                                atol=float(s1.max()) + 1e-7)

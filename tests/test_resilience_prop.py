@@ -191,7 +191,7 @@ class _FakeEngine:
         self.n_pages = n_pages
         self.params = None
         L, KV, Dh, V = 1, 1, 1, 8
-        self._pool = (L, n_pages, page_size, KV, Dh)
+        self._pool = (L, n_pages, KV, page_size, Dh)   # head-major
         self._V = V
 
     def init_paged_cache(self, enc_len=None):
