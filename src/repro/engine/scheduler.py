@@ -78,6 +78,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import gc
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional
@@ -85,6 +86,7 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.engine.faults import CrashError
 from repro.engine.paged_cache import (PageAllocator, PagePoolExhausted,
@@ -94,6 +96,23 @@ from repro.engine.prefix_cache import PrefixCache
 from repro.runtime.resilience import (Heartbeat, RetryPolicy,
                                       StragglerMonitor, call_with_retries,
                                       percentiles)
+
+
+_gc_span: Optional[TraceAnnotation] = None
+
+
+def _gc_callback(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook: a generation-2 collection runs inside a
+    ``repro.gc`` span; younger generations return at once."""
+    global _gc_span
+    if info["generation"] != 2:
+        return
+    if phase == "start":
+        _gc_span = TraceAnnotation("repro.gc")
+        _gc_span.__enter__()
+    elif _gc_span is not None:
+        span, _gc_span = _gc_span, None
+        span.__exit__(None, None, None)
 
 
 class RequestStatus(str, enum.Enum):
@@ -277,6 +296,40 @@ class Scheduler:
     blocks are exact no-ops for online softmax); int8 pools carry the
     same near-tie caveat as a prefix-cache hit, since chunks after the
     first read the earlier chunks' KV through the quantized pages.
+
+    Spans.  ``admit`` and ``step`` open ``jax.profiler.TraceAnnotation``
+    spans, which a profiler trace records on its host plane, on the
+    device planes' timeline to within a millisecond or two; with no
+    profiler running each costs about a microsecond.  A ``*.sync`` span is the host waiting on the device,
+    so a span's host time is its length less its ``*.sync`` children.
+    The pool write runs as the program ``jit_write_prefill_step``.
+
+    ======================  ===========================================
+    span                    covers
+    ======================  ===========================================
+    ``repro.step``          all of ``step()``
+    ``repro.step.prepare``  deadlines, page growth, copy-on-write,
+                            chunk packing, width bucket, batch arrays
+    ``repro.step.launch``   the decode (or mixed) step and the pick
+                            dispatched
+    ``repro.promote.sync``  chunked prefill: the final chunk's isfinite
+                            guard and first-token read (in ``launch``)
+    ``repro.step.sync``     the one device->host read of the picks
+    ``repro.step.retire``   tokens appended, retirements, straggler /
+                            heartbeat / snapshot hooks
+    ``repro.admit``         all of ``admit()``
+    ``repro.admit.request`` one request taking a slot: prefill and pool
+                            write dispatched, first token read
+    ``repro.admit.sync``    the read of that first token
+    ``repro.gc``            a generation-2 garbage collection
+    ======================  ===========================================
+
+    ``chipbench/spans.py`` reads them beside the device trace: host time
+    per step (``repro.step`` less its syncs, ``sched.step_host_ms``),
+    p95 of ``repro.admit.request`` (``sched.admit_ms_p95``), p95 of
+    ``jit_write_prefill_step`` on the device
+    (``engine.prefill_write_ms_p95``), and the device's idle time split
+    by the innermost span open over it.
     """
 
     def __init__(self, engine, enc_len: Optional[int] = None,
@@ -387,13 +440,19 @@ class Scheduler:
         self._latencies: List[float] = []
         self._itl: List[float] = []     # inter-token latency samples
         self._order = 0
+        if _gc_callback not in gc.callbacks:
+            gc.callbacks.append(_gc_callback)
+
         # jitted prefill->pages scatter with the pool DONATED (where
         # the backend supports donation): the eager .at[].set would
-        # copy every full pool leaf per admission
+        # copy every full pool leaf per admission.  Named, so its runs
+        # show as ``jit_write_prefill_step`` in a profile.
+        def write_prefill_step(cache, caches, table, slots):
+            return write_prefill(self.cfg, cache, caches, table,
+                                 enc_caches_slots=slots)
+
         self._write_prefill = jax.jit(
-            lambda cache, caches, table, slots: write_prefill(
-                self.cfg, cache, caches, table,
-                enc_caches_slots=slots),
+            write_prefill_step,
             donate_argnums=(() if jax.default_backend() == "cpu"
                             else (0,)))
         # jitted copy-on-write page fork (src/dst ride as traced
@@ -675,6 +734,10 @@ class Scheduler:
         pool) are REJECTED individually — the stream keeps serving —
         and a request whose deadline lapsed while queued ends
         TIMED_OUT here instead of wasting a prefill."""
+        with TraceAnnotation("repro.admit"):
+            return self._admit_pending()
+
+    def _admit_pending(self) -> int:
         if (self.n_active == 0 and not self.pending and self.parked):
             # nothing else runnable: the parked slots get their turn
             while self.parked:
@@ -761,12 +824,13 @@ class Scheduler:
                     self.stats["shared_pages"],
                     self.allocator.shared_pages)
             pages = resident + self.allocator.alloc(private)
-            if self.chunked:
-                ok = self._admit_chunked(slot_id, item, pages,
-                                         n_resident=len(resident))
-            else:
-                ok = self._admit_into(slot_id, item, pages,
-                                      n_shared=len(matched))
+            with TraceAnnotation("repro.admit.request"):
+                if self.chunked:
+                    ok = self._admit_chunked(slot_id, item, pages,
+                                             n_resident=len(resident))
+                else:
+                    ok = self._admit_into(slot_id, item, pages,
+                                          n_shared=len(matched))
             if ok:
                 admitted += 1
         return admitted
@@ -833,7 +897,8 @@ class Scheduler:
             # engine convention: the first generated token is the
             # argmax of the prefill logits; sampled steps start at
             # fold_in(key, 0)
-            tok = int(jnp.argmax(logits[0]))
+            with TraceAnnotation("repro.admit.sync"):
+                tok = int(jnp.argmax(logits[0]))
             slot = _Slot(req=req, length=self._prefill_positions(req),
                          pages=list(pages), out=[tok],
                          order=self._order,
@@ -1042,18 +1107,21 @@ class Scheduler:
         the whole prefilled prefix is indexed into the prefix trie."""
         slot = self.slots[slot_id]
         req = slot.req
-        if self.guard_nonfinite and \
-                not bool(jnp.all(jnp.isfinite(chunk_logits))):
-            self._fail_slot(slot_id, "non-finite logits in chunked "
-                            "prefill (final chunk)")
-            return
+        if self.guard_nonfinite:
+            with TraceAnnotation("repro.promote.sync"):
+                finite = bool(jnp.all(jnp.isfinite(chunk_logits)))
+            if not finite:
+                self._fail_slot(slot_id, "non-finite logits in chunked "
+                                "prefill (final chunk)")
+                return
         self.stats["prefills"] += 1
         if slot.out:
             tok = slot.out[-1]
             slot.length = (self._prefill_positions(req)
                            + len(slot.out) - 1)
         else:
-            tok = int(jnp.argmax(chunk_logits[0]))
+            with TraceAnnotation("repro.promote.sync"):
+                tok = int(jnp.argmax(chunk_logits[0]))
             slot.out = [tok]
             slot.length = self._prefill_positions(req)
             slot.token_times.append(time.monotonic())
@@ -1079,18 +1147,36 @@ class Scheduler:
         NaN/inf is quarantined (FAILED) alone while every other slot's
         stream is untouched (a PREFILLING slot is guarded at its final
         chunk, where its logits first matter)."""
+        with TraceAnnotation("repro.step"):
+            with TraceAnnotation("repro.step.prepare"):
+                plan = self._prepare_step()
+            if plan is None:
+                return
+            with TraceAnnotation("repro.step.launch"):
+                picked = self._launch_step(*plan)
+            # ONE device->host transfer for the whole step
+            with TraceAnnotation("repro.step.sync"):
+                picked = np.asarray(picked)
+            with TraceAnnotation("repro.step.retire"):
+                self._retire_step(plan[0], picked)
+
+    def _prepare_step(self):
+        """Host work before the step's launch: deadlines, page growth,
+        copy-on-write, chunk packing and the batch arrays.  Returns
+        ``(was_running, chunk slot, chunk length, batch)``, or None
+        when nothing decodes and nothing chunks."""
         if self.n_active == 0:
-            return
+            return None
         self._expire_deadlines()
         if self.n_active == 0:
-            return
+            return None
         self._grow_pages()
         if self.n_active == 0:      # growth preempted everything
-            return
+            return None
         if self.prefix is not None:
             self._cow_guard()
             if self.n_active == 0:
-                return
+                return None
         # snapshot who decodes THIS step: PREFILLING slots ride the
         # batch masked (cur_len == 0), and a slot promoted after the
         # mixed call must not consume this step's (garbage) logits row
@@ -1099,7 +1185,7 @@ class Scheduler:
                        and s.req.status is not RequestStatus.PREFILLING]
         c_slot, C = self._pack_chunk_for_step(len(was_running))
         if c_slot is None and not was_running:
-            return                  # nothing decodable, nothing chunks
+            return None             # nothing decodable, nothing chunks
         if self.straggler is not None:
             self.straggler.start_step()
         # table-width bucketing: stage only live pages.  After
@@ -1130,20 +1216,26 @@ class Scheduler:
                 slot.pages[:jp], jnp.int32)
             dbatch["chunk_write_pages"] = jnp.asarray(
                 slot.pages[jp:jw], jnp.int32)
+        return was_running, c_slot, C, dbatch
+
+    def _launch_step(self, was_running, c_slot, C, dbatch):
+        """Dispatch the decode (or mixed) step and the pick over its
+        logits; returns the picks, still on the device."""
+        if c_slot is not None:
+            slot = self.slots[c_slot]
             logits, chunk_logits, self.cache = self._run_mixed(dbatch)
             self.stats["mixed_steps"] += 1
             self.stats["chunks"] += 1
             self.stats["chunked_tokens"] += C
-            slot.prefilled = p0 + C
-            if slot.prefilled >= len(toks):
+            slot.prefilled += C
+            if slot.prefilled >= len(self._teacher_tokens(slot)):
                 self._prefilling.popleft()
                 self._promote(c_slot, chunk_logits)
         else:
             logits, self.cache = self._run_decode(dbatch)
         self.stats["steps"] += 1
         # one jitted pick (batched argmax + per-slot fold_in keys +
-        # batched categorical + isfinite guard) and ONE device->host
-        # transfer for the whole step
+        # batched categorical + isfinite guard) for the whole step
         B = len(self.slots)
         seeds = np.zeros((B,), np.int32)
         steps = np.zeros((B,), np.int32)
@@ -1153,9 +1245,12 @@ class Scheduler:
             seeds[slot_id] = slot.req.seed
             steps[slot_id] = slot.steps
             temps[slot_id] = slot.req.temperature
-        picked = np.asarray(self._pick_fn(
-            logits, jnp.asarray(seeds), jnp.asarray(steps),
-            jnp.asarray(temps)))
+        return self._pick_fn(logits, jnp.asarray(seeds),
+                             jnp.asarray(steps), jnp.asarray(temps))
+
+    def _retire_step(self, was_running, picked) -> None:
+        """Append each slot's picked token, retire finished requests
+        and run the per-step hooks."""
         greedy, sampled, finite = picked[0], picked[1], picked[2]
         now = time.monotonic()
         for slot_id in was_running:

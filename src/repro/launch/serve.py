@@ -22,6 +22,7 @@ decide per shape.
 from __future__ import annotations
 
 import argparse
+import contextlib
 
 import jax
 import jax.numpy as jnp
@@ -123,6 +124,11 @@ def build_parser() -> argparse.ArgumentParser:
                          "CrashFault at step K of the first attempt — "
                          "deterministic simulated process death the "
                          "restart loop must recover from")
+    ap.add_argument("--profile-dir", default=None, metavar="DIR",
+                    help="with --stream: write a profiler trace of the "
+                         "request stream under DIR (the scheduler's "
+                         "repro.* spans beside the device's programs; "
+                         "open it in TensorBoard or Perfetto)")
     return ap
 
 
@@ -384,7 +390,9 @@ def main(argv=None):
 
     if args.stream:
         serve = _serve_durable if args.snapshot_dir else _serve_stream
-        finished = serve(engine, args)
+        with (jax.profiler.trace(args.profile_dir) if args.profile_dir
+              else contextlib.nullcontext()):
+            finished = serve(engine, args)
         if not args.inject:
             _exit_if_lost(finished)
         return finished

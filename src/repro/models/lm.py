@@ -260,8 +260,9 @@ def _attn_delta(cfg, ap, h, positions, *, causal=True, residual=None,
                                        head_axis=_head_axis(cfg),
                                        mesh=mesh)
         return (out if residual is None else residual + out), cache
-    q, k, v = A.qkv_proj(ap, h, positions, cfg.rope_theta, cfg,
-                         mesh=mesh)
+    with jax.named_scope("attn.qkv"):
+        q, k, v = A.qkv_proj(ap, h, positions, cfg.rope_theta, cfg,
+                             mesh=mesh)
     if cfg.accounting:
         o = A.full_attn_ref(q, k, v, causal=causal, q_positions=positions,
                             kv_positions=positions)
@@ -271,7 +272,9 @@ def _attn_delta(cfg, ap, h, positions, *, causal=True, residual=None,
                        block_q=cfg.attn_block_q,
                        block_kv=cfg.attn_block_kv,
                        head_axis=_head_axis(cfg), mesh=mesh)
-    return A.o_proj(ap, o, cfg, residual=residual, mesh=mesh), (k, v)
+    with jax.named_scope("attn.out"):
+        out = A.o_proj(ap, o, cfg, residual=residual, mesh=mesh)
+    return out, (k, v)
 
 
 def _head_axis(cfg):
@@ -286,16 +289,18 @@ def _dense_body(cfg, positions, x, lp, _ex, *, causal=True, collect=False,
                 mesh=None):
     x, kv = _attn_delta(cfg, lp["attn"], _norm(cfg, lp["attn_norm"], x),
                         positions, causal=causal, residual=x, mesh=mesh)
-    x = L.mlp(lp["mlp"], _norm(cfg, lp["mlp_norm"], x), cfg.act,
-              backend=cfg, residual=x, mesh=mesh)
+    with jax.named_scope("mlp"):
+        x = L.mlp(lp["mlp"], _norm(cfg, lp["mlp_norm"], x), cfg.act,
+                  backend=cfg, residual=x, mesh=mesh)
     return x, (kv if collect else None)
 
 
 def _moe_body(cfg, positions, x, lp, _ex, *, collect=False, mesh=None):
     x, kv = _attn_delta(cfg, lp["attn"], _norm(cfg, lp["attn_norm"], x),
                         positions, residual=x, mesh=mesh)
-    y, aux = MOE.moe_ffn(lp["moe"], _norm(cfg, lp["mlp_norm"], x), cfg,
-                          mesh=mesh)
+    with jax.named_scope("mlp"):
+        y, aux = MOE.moe_ffn(lp["moe"], _norm(cfg, lp["mlp_norm"], x), cfg,
+                              mesh=mesh)
     return x + y, ((kv if collect else None), aux)
 
 
@@ -762,24 +767,32 @@ def _decode_gqa_paged(cfg, lp, h, kp, vp, table, lens, mesh=None,
     are int8: the token quantizes on write and attention dequantizes
     in-kernel.  Returns (delta, kp, vp, kscale, vscale)."""
     n_pages, ps = kp.shape[0], kp.shape[2]
-    q = jnp.einsum("bd,dhk->bhk", h, lp["wq"])
-    k = jnp.einsum("bd,dhk->bhk", h, lp["wk"])
-    v = jnp.einsum("bd,dhk->bhk", h, lp["wv"])
-    if "bq" in lp:
-        q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-    q = _rope_slots(q, lens, cfg.rope_theta)
-    k = _rope_slots(k, lens, cfg.rope_theta)
-    pages, offs, n_valid = _page_write_ids(table, lens, ps, n_pages)
-    if kscale is not None:
-        from repro.engine import paged_cache as PC
-        kp, kscale = PC.quantized_page_write(kp, kscale, pages, offs, k)
-        vp, vscale = PC.quantized_page_write(vp, vscale, pages, offs, v)
-    else:
-        kp = kp.at[pages, :, offs].set(k.astype(kp.dtype), mode="drop")
-        vp = vp.at[pages, :, offs].set(v.astype(vp.dtype), mode="drop")
-    o = _paged_attend(cfg, q, kp, vp, table, n_valid, mesh,
-                      k_scale=kscale, v_scale=vscale)
-    delta = jnp.einsum("bhk,hkd->bd", o, lp["wo"])
+    with jax.named_scope("attn.qkv"):
+        q = jnp.einsum("bd,dhk->bhk", h, lp["wq"])
+        k = jnp.einsum("bd,dhk->bhk", h, lp["wk"])
+        v = jnp.einsum("bd,dhk->bhk", h, lp["wv"])
+        if "bq" in lp:
+            q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+        q = _rope_slots(q, lens, cfg.rope_theta)
+        k = _rope_slots(k, lens, cfg.rope_theta)
+    with jax.named_scope("attn.kv_write"):
+        pages, offs, n_valid = _page_write_ids(table, lens, ps, n_pages)
+        if kscale is not None:
+            from repro.engine import paged_cache as PC
+            kp, kscale = PC.quantized_page_write(kp, kscale, pages, offs,
+                                                 k)
+            vp, vscale = PC.quantized_page_write(vp, vscale, pages, offs,
+                                                 v)
+        else:
+            kp = kp.at[pages, :, offs].set(k.astype(kp.dtype),
+                                           mode="drop")
+            vp = vp.at[pages, :, offs].set(v.astype(vp.dtype),
+                                           mode="drop")
+    with jax.named_scope("attn.paged"):
+        o = _paged_attend(cfg, q, kp, vp, table, n_valid, mesh,
+                          k_scale=kscale, v_scale=vscale)
+    with jax.named_scope("attn.out"):
+        delta = jnp.einsum("bhk,hkd->bd", o, lp["wo"])
     return delta, kp, vp, kscale, vscale
 
 
@@ -881,8 +894,9 @@ def _dense_paged_body(cfg, lens, table, x, lp, cache_slice, mesh=None):
     d, new = _paged_attn_delta(cfg, lens, table, h, lp, cache_slice,
                                mesh)
     x = x + d
-    x = x + L.mlp(lp["mlp"], _norm(cfg, lp["mlp_norm"], x), cfg.act,
-                  backend=cfg, mesh=mesh)
+    with jax.named_scope("mlp"):
+        x = x + L.mlp(lp["mlp"], _norm(cfg, lp["mlp_norm"], x), cfg.act,
+                      backend=cfg, mesh=mesh)
     return x, new
 
 
@@ -891,8 +905,10 @@ def _moe_paged_body(cfg, lens, table, x, lp, cache_slice, mesh=None):
     d, new = _paged_attn_delta(cfg, lens, table, h, lp, cache_slice,
                                mesh)
     x = x + d
-    y, _aux = MOE.moe_ffn(lp["moe"], _norm(cfg, lp["mlp_norm"], x)[None],
-                          cfg, mesh=mesh)
+    with jax.named_scope("mlp"):
+        y, _aux = MOE.moe_ffn(lp["moe"],
+                              _norm(cfg, lp["mlp_norm"], x)[None], cfg,
+                              mesh=mesh)
     return x + y[0], new
 
 
@@ -906,8 +922,9 @@ def _audio_paged_body(cfg, lens, table, enc_lens, x, lp, cs, mesh=None):
     x = x + _decode_cross_paged(cfg, lp["cross"], h, cs["cross_k"],
                                 cs["cross_v"], enc_lens,
                                 cs["self_k"].shape[2], mesh)
-    x = x + L.mlp(lp["mlp"], _norm(cfg, lp["mlp_norm"], x), cfg.act,
-                  backend=cfg, mesh=mesh)
+    with jax.named_scope("mlp"):
+        x = x + L.mlp(lp["mlp"], _norm(cfg, lp["mlp_norm"], x), cfg.act,
+                      backend=cfg, mesh=mesh)
     return x, {"self_k": kp, "self_v": vp}
 
 
@@ -965,8 +982,10 @@ def paged_decode_step(params, batch, cfg, mesh=None):
         new_cache = dict(cache)
         new_cache.update(upd)
 
-    h = _norm(cfg, params["final_norm"], x)
-    logits = _logits(params, h[:, None, :], cfg)[:, 0].astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        h = _norm(cfg, params["final_norm"], x)
+        logits = _logits(params, h[:, None, :], cfg)[:, 0].astype(
+            jnp.float32)
     return logits, new_cache
 
 
@@ -1144,7 +1163,8 @@ def prefill(params, batch, cfg, mesh=None):
     out = backbone(params, batch["tokens"], cfg,
                    frontend_emb=batch.get("frontend_emb"),
                    collect_cache=True, mesh=mesh)
-    logits = _logits(params, out.h[:, -1:, :], cfg)[:, 0]
+    with jax.named_scope("lm_head"):
+        logits = _logits(params, out.h[:, -1:, :], cfg)[:, 0]
     return logits.astype(jnp.float32), out.caches
 
 
