@@ -8,8 +8,10 @@ per-slot budget with a shared pool of fixed-width pages:
   * each family cache leaf becomes a **page pool** with the (B, T)
     dims replaced by ``(n_pages, page_size)`` — the GQA leaf
     ``(L, B, T, KV, Dh)`` becomes the head-major ``(L, n_pages, KV,
-    page_size, Dh)`` (one head's page is a contiguous ``(page_size,
-    Dh)`` tile, the block the TPU kernels stage), the MLA latent leaf
+    page_size, Dh)`` (every head of one page is a contiguous ``(KV,
+    page_size, Dh)`` block, which the bf16 decode kernel stages whole;
+    the int8 and chunk kernels stage one head's ``(page_size, Dh)``
+    tile), the MLA latent leaf
     ``(L, B, T, r)`` becomes ``(L, n_pages, page_size, r)``;
   * a ``(B_slots, max_pages)`` int32 **block table** maps each slot's
     logical page j to a physical page id (the allocator hands pages

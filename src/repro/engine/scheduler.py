@@ -425,6 +425,9 @@ class Scheduler:
         self.stats = {"prefills": 0, "admitted": 0, "retired": 0,
                       "steps": 0, "peak_pages": 0, "preempted": 0,
                       "table_widths": {},   # width -> steps at it
+                      # table entries the decode kernel stages, summed
+                      # over steps: decoding slots' pages, and B x W
+                      "decode_pages_live": 0, "decode_pages_table": 0,
                       "rejected": 0, "failed": 0, "cancelled": 0,
                       "timed_out": 0, "step_retries": 0,
                       "prefill_retries": 0, "parked": 0,
@@ -1198,6 +1201,9 @@ class Scheduler:
             W = bucket_table_width(live, self.table.shape[1])
         self.stats["table_widths"][W] = \
             self.stats["table_widths"].get(W, 0) + 1
+        self.stats["decode_pages_live"] += sum(
+            len(self.slots[sid].pages) for sid in was_running)
+        self.stats["decode_pages_table"] += len(self.slots) * W
         dbatch = {"token": jnp.asarray(self.tokens),
                   "cur_len": jnp.asarray(self.lens),
                   "block_table": jnp.asarray(self.table[:, :W]),

@@ -22,7 +22,8 @@ import jax.numpy as jnp
 from repro.kernels import autotune
 from repro.kernels.vwr_attention import vwr_attention_p
 from repro.kernels.vwr_conv2d import vwr_conv2d_p
-from repro.kernels.vwr_decode import (vwr_chunk_prefix_attend_p,
+from repro.kernels.vwr_decode import (pages_per_block,
+                                      vwr_chunk_prefix_attend_p,
                                       vwr_chunk_prefix_attend_q8_p,
                                       vwr_flash_decode_p,
                                       vwr_flash_decode_q8_p,
@@ -389,13 +390,14 @@ def _vwr_paged_flash_decode_jit(q, k_pool, v_pool, table, counts, *,
     B, H, D = q.shape
     n_pages, KV, ps, _ = k_pool.shape
     G = H // KV
-    qf = q.reshape(B * KV, G, D)
-    # unallocated / foreign table entries carry count 0, so any legal
-    # page index is safe to stage — clamp rather than branch
+    ppb = pages_per_block(table.shape[1],
+                          2 * KV * ps * D * k_pool.dtype.itemsize)
+    # unallocated / foreign table entries carry count 0: the kernel never
+    # stages them, but any index it reads must be a legal page
     tbl = jnp.clip(table, 0, n_pages - 1).astype(jnp.int32)
     o_t, m, l = vwr_paged_flash_decode_p(
-        qf, k_pool, v_pool, tbl, counts.astype(jnp.int32),
-        interpret=interpret)
+        q.reshape(B, KV, G, D), k_pool, v_pool, tbl,
+        counts.astype(jnp.int32), ppb=ppb, interpret=interpret)
     return (o_t.reshape(B, H, D), m.reshape(B, H), l.reshape(B, H))
 
 
@@ -408,11 +410,13 @@ def vwr_paged_flash_decode(q, k_pool, v_pool, table, counts, *,
     table: (B, max_pages) int32 physical page per (slot, logical page);
     counts:
     (B, max_pages) int32 valid tokens per (slot, logical page) — 0
-    masks a page completely.  Page size is the transaction width here
-    (the engine owns it), so there is no block autotuning; the 'auto'
-    dispatch backend still measures this wrapper against the XLA
-    gather reference per shape.  Returns fp32 (o_tilde (B,H,Dh),
-    m (B,H), l (B,H)), the ``dist.decode`` combine contract."""
+    masks a page completely, wherever it lies in the row.  The kernel
+    stages ``pages_per_block`` whole pages (every KV head) per grid
+    step, a count fixed by the page's bytes and ``max_pages``, so there
+    is no block autotuning; the 'auto' dispatch backend still measures
+    this wrapper against the XLA gather reference per shape.  Returns
+    fp32 (o_tilde (B,H,Dh), m (B,H), l (B,H)), the ``dist.decode``
+    combine contract."""
     interpret = _auto_interpret(interpret)
     return _vwr_paged_flash_decode_jit(q, k_pool, v_pool, table, counts,
                                        interpret=interpret)

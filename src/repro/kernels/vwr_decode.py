@@ -22,8 +22,11 @@ Mosaic tiling contract (what the TPU compiler accepts): the last two
 dims of every block equal the array's own, or divide by (8, 128).  So
 
   * paged GQA pools are **head-major**, ``(n_pages, KV, page_size,
-    Dh)``: one (slot, head, page) grid step stages the contiguous
-    ``(page_size, Dh)`` tile of one head, a block ``(1, 1, ps, Dh)``;
+    Dh)``: each grid step of the bf16 paged kernel (one block of a
+    slot's live pages) stages ``ppb`` whole pages, every head of a page
+    one contiguous block ``(1, KV, ps, Dh)``; the int8 and chunk-prefix
+    kernels' (row, page) step stages one head's ``(page_size, Dh)``
+    tile, a block ``(1, 1, ps, Dh)``;
   * the (m, l) statistics leave the kernel as ``(rows, G, 1)`` columns
     (block ``(1, G, 1)``) — exactly the ``(G, 1)`` VMEM scratch they
     accumulate in — and the wrappers drop the trailing 1;
@@ -46,6 +49,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 F32 = jnp.float32
+EXACT = jax.lax.Precision.HIGHEST
 
 
 def _params(*dimension_semantics):
@@ -64,16 +68,32 @@ def _flash_init(j, acc_ref, ms_ref, ls_ref):
         ls_ref[...] = jnp.zeros_like(ls_ref)
 
 
-def _scores(q, k):
-    """(R, D) x (T, D) -> (R, T) fp32."""
-    return jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+def _scores(q, k, precision=None):
+    """(..., R, D) x (..., T, D) -> (..., R, T) fp32, leading dims
+    batched."""
+    n = q.ndim - 2
+    lead = tuple(range(n))
+    return jax.lax.dot_general(q, k, (((n + 1,), (n + 1,)), (lead, lead)),
+                               precision=precision,
                                preferred_element_type=F32)
 
 
-def _flash_update(s, valid, v, acc_ref, ms_ref, ls_ref, v_scale=None):
+def _pv(p, v, precision=None):
+    """(..., R, T) x (..., T, Dv) -> (..., R, Dv) fp32, leading dims
+    batched."""
+    n = p.ndim - 2
+    lead = tuple(range(n))
+    return jax.lax.dot_general(p, v, (((n + 1,), (n,)), (lead, lead)),
+                               precision=precision,
+                               preferred_element_type=F32)
+
+
+def _flash_update(s, valid, v, acc_ref, ms_ref, ls_ref, v_scale=None,
+                  precision=None):
     """Fold one staged block into the running (acc, m, l): ``s`` (R, T)
     scores, ``valid`` (R, T) mask, ``v`` (T, Dv) values; the scratch
-    refs are (R, Dv), (R, 1), (R, 1)."""
+    refs are (R, Dv), (R, 1), (R, 1).  Leading dims (a block of heads)
+    ride along batched; ``precision`` is that of the ``p.v`` dot."""
     s = jnp.where(valid, s, NEG_INF)
     m_prev = ms_ref[...]
     m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
@@ -81,7 +101,7 @@ def _flash_update(s, valid, v, acc_ref, ms_ref, ls_ref, v_scale=None):
     p = jnp.where(m_new > NEG_INF / 2, p, 0.0)
     corr = jnp.exp(m_prev - m_new)
     ls_ref[...] = ls_ref[...] * corr + p.sum(axis=-1, keepdims=True)
-    pv = jnp.dot(p, v, preferred_element_type=F32)
+    pv = _pv(p, v, precision)
     if v_scale is not None:
         pv = pv * v_scale
     acc_ref[...] = acc_ref[...] * corr + pv
@@ -230,17 +250,101 @@ def vwr_flash_decode_q8_p(q: jax.Array, k: jax.Array, v: jax.Array,
     )(lens, k_scale, v_scale, q, k, v))
 
 
-def _paged_decode_kernel(tbl_ref, cnt_ref, q_ref, k_ref, v_ref,
-                         ot_ref, m_ref, l_ref, acc_ref, ms_ref, ls_ref,
-                         *, scale, n_logical, kv_heads):
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    _flash_init(j, acc_ref, ms_ref, ls_ref)
-    s = _scores(q_ref[0].astype(F32) * scale, k_ref[0, 0].astype(F32))
-    _flash_update(s, _count_valid(s, cnt_ref[b // kv_heads, j]),
-                  v_ref[0, 0].astype(F32), acc_ref, ms_ref, ls_ref)
-    _flash_store(j, n_logical - 1, ot_ref, m_ref, l_ref, acc_ref, ms_ref,
-                 ls_ref)
+# the bf16 paged kernel stages whole pages (every KV head) several at a
+# time; one staged buffer of K + V pages stays under this many bytes
+PAGE_BLOCK_BYTES = 512 * 1024
+
+
+def pages_per_block(J: int, page_bytes: int) -> int:
+    """Pages staged per DMA block: the largest power of two that is at
+    most ``J`` and keeps ``ppb * page_bytes`` (K + V of one page, every
+    head) under ``PAGE_BLOCK_BYTES``."""
+    ppb = 1
+    while 2 * ppb <= J and 2 * ppb * page_bytes <= PAGE_BLOCK_BYTES:
+        ppb *= 2
+    return ppb
+
+
+# flags of a grid step's block: the first and the last of its slot
+FIRST_BLOCK, LAST_BLOCK = 1, 2
+
+
+def _paged_decode_kernel(slot_ref, flag_ref, page_ref, cnt_ref, q_ref,
+                         *refs, scale, ppb, ps):
+    k_refs, v_refs = refs[:ppb], refs[ppb:2 * ppb]
+    ot_ref, m_ref, l_ref, acc_ref, ms_ref, ls_ref = refs[2 * ppb:]
+    w = pl.program_id(0)
+
+    @pl.when(flag_ref[w] & FIRST_BLOCK != 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        ms_ref[...] = jnp.full_like(ms_ref, NEG_INF)
+        ls_ref[...] = jnp.zeros_like(ls_ref)
+
+    # the block's pages side by side: (KV, ppb * ps, Dh)
+    k = jnp.concatenate([r[0] for r in k_refs], axis=1)
+    v = jnp.concatenate([r[0] for r in v_refs], axis=1)
+    q = q_ref[0]                                    # (KV, G, Dh)
+    if q.dtype != k.dtype:
+        q, k = q.astype(F32), k.astype(F32)
+    # bf16 products are exact in the fp32 accumulator; fp32 operands
+    # (and p below) need HIGHEST, as the MXU's default rounds them to
+    # bf16
+    s = _scores(q, k, EXACT if k.dtype == F32 else None) * scale
+    t = jax.lax.broadcasted_iota(jnp.int32, s.shape[1:], 1)
+    valid = jnp.zeros(t.shape, jnp.bool_)
+    for p in range(ppb):
+        lo = p * ps
+        valid |= (t >= lo) & (t < lo + cnt_ref[w * ppb + p])
+    _flash_update(s, valid[None], v.astype(F32), acc_ref, ms_ref, ls_ref,
+                  precision=EXACT)
+
+    @pl.when(flag_ref[w] & LAST_BLOCK != 0)
+    def _store():
+        ot_ref[0] = acc_ref[...]
+        m_ref[0] = ms_ref[...]
+        l_ref[0] = ls_ref[...]
+
+
+def _keep_last_staged(a, b):
+    """Scan step: the later page where its operand stages one, else
+    the one before it."""
+    (page_a, staged_a), (page_b, staged_b) = a, b
+    return jnp.where(staged_b, page_b, page_a), staged_a | staged_b
+
+
+def _work_list(table, counts, ppb):
+    """The grid's work: each slot's live pages (nonzero count) moved to
+    the front of its row in order — ``dist.decode`` zeroes foreign
+    pages anywhere in a row — then one item per block of ``ppb`` pages
+    that holds a live one, slot by slot.  Returns the pages live per
+    slot, the item count, and per item (padded to every block of the
+    row) its slot, its FIRST_BLOCK | LAST_BLOCK flags, and per page its
+    physical page and valid count, flat.  A page past the slot's live
+    ones (count 0) names the page its operand staged last, so the
+    pipeline, seeing the block index unchanged, issues no DMA for it."""
+    B, J = table.shape
+    pad = ((0, 0), (0, (-J) % ppb))
+    table, counts = jnp.pad(table, pad), jnp.pad(counts, pad)
+    dead = (counts == 0).astype(jnp.int32)
+    _, table, counts = jax.lax.sort((dead, table, counts), dimension=1,
+                                    is_stable=True, num_keys=1)
+    n_live = jnp.sum(1 - dead, axis=1, dtype=jnp.int32)
+    NB = table.shape[1] // ppb
+    n_blk = (n_live + ppb - 1) // ppb
+    blk = jnp.arange(NB, dtype=jnp.int32)[None, :]
+    flags = (jnp.where(blk == 0, FIRST_BLOCK, 0)
+             | jnp.where(blk == n_blk[:, None] - 1, LAST_BLOCK, 0))
+    # the blocks that hold live pages first, slot by slot
+    unused = (blk >= n_blk[:, None]).astype(jnp.int32).reshape(-1)
+    _, order = jax.lax.sort((unused, jnp.arange(B * NB, dtype=jnp.int32)),
+                            is_stable=True, num_keys=1)
+    pages = table.reshape(B * NB, ppb)[order]
+    cnt = counts.reshape(B * NB, ppb)[order]
+    pages, _ = jax.lax.associative_scan(_keep_last_staged,
+                                        (pages, cnt > 0), axis=0)
+    return (n_live, jnp.sum(n_blk), order // NB, flags.reshape(-1)[order],
+            pages.reshape(-1), cnt.reshape(-1))
 
 
 def _page_spec(ps, D, KV, page_of):
@@ -254,47 +358,69 @@ def _page_spec(ps, D, KV, page_of):
 
 def vwr_paged_flash_decode_p(q: jax.Array, k_pool: jax.Array,
                              v_pool: jax.Array, table: jax.Array,
-                             counts: jax.Array, *,
+                             counts: jax.Array, *, ppb: int,
                              interpret: bool = False):
-    """Flash-decode over a paged KV pool, one staged page per grid step.
+    """Flash-decode over a paged KV pool, ``ppb`` whole pages per grid
+    step, over live pages only.
 
     The block table IS the transaction schedule: it rides in as a
-    scalar-prefetch operand, so each (slot*head, logical-page) grid
-    step's BlockSpec index map resolves ``table[slot, j]`` *before* the
-    DMA fires and stages exactly that physical page's (page_size x Dh)
-    tile for the head — the gather never materializes in HBM.
-    ``counts[slot, j]`` is the number of valid tokens in that page.
+    scalar-prefetch operand, and each grid step's ``ppb`` page operands
+    resolve their physical pages in their index maps *before* the DMAs
+    fire, each staging one page's whole ``(KV, page_size, Dh)`` block
+    (every head: one contiguous run of the head-major pool) — the
+    gather never materializes in HBM, and the pipeline fetches the next
+    step's pages while this step computes.  The grid is dynamic: one
+    step per block of ``ppb`` live pages (``_work_list``), so no step
+    is spent on a page of count 0, a slot with none, or padding.
 
-    q: (B*KV, G, Dh); k_pool, v_pool: head-major (n_pages, KV,
-    page_size, Dh); table, counts: (B, max_pages) int32, table
-    pre-clamped to [0, n_pages).  Returns (o_tilde (BKV, G, Dh),
-    m (BKV, G), l (BKV, G)) fp32 — the ``vwr_flash_decode_p`` contract.
+    q: (B, KV, G, Dh); k_pool, v_pool: head-major (n_pages, KV,
+    page_size, Dh); table, counts: (B, max_pages) int32, table within
+    [0, n_pages).  Returns (o_tilde (B, KV, G, Dh), m (B, KV, G),
+    l (B, KV, G)) fp32 — the ``vwr_flash_decode_p`` contract, an
+    inactive slot reading (0, NEG_INF, 0).
     """
-    BKV, G, D = q.shape
-    n_pages, KV, ps, Dp = k_pool.shape
-    assert v_pool.shape == k_pool.shape and Dp == D
-    B, J = table.shape
-    assert counts.shape == (B, J) and B * KV == BKV, (table.shape, BKV)
+    B, KV, G, D = q.shape
+    n_pages, KVp, ps, Dp = k_pool.shape
+    assert v_pool.shape == k_pool.shape and (KVp, Dp) == (KV, D)
+    assert table.shape == counts.shape and table.shape[0] == B
+    n_live, n_items, *work = _work_list(table, counts, ppb)
     kernel = functools.partial(_paged_decode_kernel,
-                               scale=1.0 / (D ** 0.5), n_logical=J,
-                               kv_heads=KV)
-    page_of = lambda b, j, tbl: tbl[b // KV, j]             # noqa: E731
+                               scale=1.0 / (D ** 0.5), ppb=ppb, ps=ps)
+
+    def page(p):
+        return pl.BlockSpec(
+            (1, KV, ps, D), lambda w, slot, flag, pg, *_: (
+                pg[w * ppb + p], 0, 0, 0))
+
+    slot = lambda w, slot, *_: (slot[w], 0, 0, 0)           # noqa: E731
+    pages = [page(p) for p in range(ppb)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,              # table, counts
-        grid=(BKV, J),
-        in_specs=[
-            pl.BlockSpec((1, G, D), lambda b, j, *_: (b, 0, 0)),
-            _page_spec(ps, D, KV, page_of),
-            _page_spec(ps, D, KV, page_of),
-        ],
-        out_specs=_out_specs(G, D, lambda b, j, *_: (b, 0, 0)),
-        scratch_shapes=_scratch(G, D),
+        num_scalar_prefetch=4,          # slot, flags, pages, counts
+        # one step per live block (at least one, which all-inactive
+        # slots leave masked)
+        grid=(jnp.maximum(n_items, 1),),
+        in_specs=[pl.BlockSpec((1, KV, G, D), slot)] + pages + pages,
+        out_specs=[pl.BlockSpec((1, KV, G, D), slot),
+                   pl.BlockSpec((1, KV, G, 1), slot),
+                   pl.BlockSpec((1, KV, G, 1), slot)],
+        scratch_shapes=[pltpu.VMEM((KV, G, D), F32),
+                        pltpu.VMEM((KV, G, 1), F32),
+                        pltpu.VMEM((KV, G, 1), F32)],
     )
-    return _squeeze_stats(*pl.pallas_call(
-        kernel, grid_spec=grid_spec, out_shape=_out_shapes(BKV, G, D),
-        compiler_params=_params("parallel", "arbitrary"),
+    out_shape = [jax.ShapeDtypeStruct((B, KV, G, D), F32),
+                 jax.ShapeDtypeStruct((B, KV, G, 1), F32),
+                 jax.ShapeDtypeStruct((B, KV, G, 1), F32)]
+    # "arbitrary": a slot's blocks accumulate over consecutive steps
+    o_t, m, l = pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=out_shape,
+        compiler_params=_params("arbitrary"),
         interpret=interpret,
-    )(table, counts, q, k_pool, v_pool))
+    )(*work, q, *[k_pool] * ppb, *[v_pool] * ppb)
+    # a slot with no live page gets no step: write its contract here
+    live = (n_live > 0)[:, None, None]
+    return (jnp.where(live[..., None], o_t, 0.0),
+            jnp.where(live, m[..., 0], NEG_INF),
+            jnp.where(live, l[..., 0], 0.0))
 
 
 def _paged_decode_kernel_q8(tbl_ref, cnt_ref, ks_ref, vs_ref, q_ref,

@@ -238,15 +238,33 @@ def test_vwr_flash_decode_partials(dtype, b, t, h, kv, d, bkv, cur):
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("b,h,kv,d,ps,j", [
-    (2, 4, 2, 16, 8, 4),             # GQA
-    (3, 4, 1, 32, 4, 6),             # MQA (the absorbed-MLA view)
+@pytest.mark.parametrize("b,h,kv,d,ps,j,rows", [
+    pytest.param(2, 4, 2, 16, 8, 4, "ragged", id="2-4-2-16-8-4"),  # GQA
+    # MQA (the absorbed-MLA view)
+    pytest.param(3, 4, 1, 32, 4, 6, "ragged", id="3-4-1-32-4-6"),
+    # qwen1.5-0.5b's MHA geometry; J = 11 is no multiple of the pages
+    # staged per block (4 fp32, 8 bf16)
+    pytest.param(3, 16, 16, 64, 16, 11, "serving", id="mha-serving"),
+    pytest.param(3, 16, 16, 64, 16, 11, "sharded", id="mha-sharded"),
+    # tinyllama-1.1b's GQA, G = 8
+    pytest.param(2, 32, 4, 64, 16, 5, "serving", id="gqa8-serving"),
+    pytest.param(2, 32, 4, 64, 16, 5, "sharded", id="gqa8-sharded"),
+    pytest.param(2, 4, 2, 16, 8, 1, "serving", id="j1-serving"),
+    pytest.param(3, 4, 2, 16, 8, 9, "sharded", id="gqa-sharded-blocks"),
 ])
 def test_vwr_paged_flash_decode_matches_gather_ref(dtype, b, h, kv, d,
-                                                   ps, j):
+                                                   ps, j, rows):
     """The block-table-indexed paged kernel == the XLA gather reference
     == the dense kernel on the gathered cache, including zero-count
-    (masked) pages and per-slot ragged lengths."""
+    (masked) pages and per-slot ragged lengths.
+
+    ``rows`` is how the table rows look: "ragged" lengths; "serving",
+    the scheduler's table — long ragged rows, slot 1 inactive (every
+    count 0) and unallocated entries pointing at page 0; "sharded",
+    long ragged rows whose pages another shard owns (odd page ids)
+    carry count 0 in the middle of the row, as
+    ``dist.decode.sharded_paged_flash_decode`` makes them."""
+    from repro.kernels.vwr_decode import NEG_INF
     from repro.models.attention import paged_flash_decode_partial
     n_pages = b * j + 3
     k1, k2, k3, k4 = jax.random.split(KEY, 4)
@@ -256,9 +274,19 @@ def test_vwr_paged_flash_decode_matches_gather_ref(dtype, b, h, kv, d,
     # shuffled disjoint page assignment + ragged per-slot lengths
     perm = jax.random.permutation(k4, n_pages)[:b * j]
     table = perm.reshape(b, j).astype(jnp.int32)
-    lens = (jnp.arange(b, dtype=jnp.int32) * (ps + 1) + 3) % (j * ps)
+    slots = jnp.arange(b, dtype=jnp.int32)
+    if rows == "ragged":
+        lens = (slots * (ps + 1) + 3) % (j * ps)
+    else:
+        lens = j * ps * (b - slots) // b - slots
+    if rows == "serving":
+        lens = lens.at[1].set(0)
     counts = jnp.clip(lens[:, None] - jnp.arange(j)[None, :] * ps,
                       0, ps).astype(jnp.int32)
+    if rows == "serving":
+        table = jnp.where(counts > 0, table, 0)
+    if rows == "sharded":
+        counts = jnp.where(table % 2 == 1, 0, counts)
     got = ops.vwr_paged_flash_decode(q, kp, vp, table, counts)
     want = paged_flash_decode_partial(q, kp, vp, table, counts)
     tol = _tol(dtype)
@@ -266,6 +294,14 @@ def test_vwr_paged_flash_decode_matches_gather_ref(dtype, b, h, kv, d,
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    rtol=5 * tol["rtol"],
                                    atol=5 * tol["atol"], err_msg=name)
+    # an inactive slot keeps the all-masked contract exactly
+    for slot in range(b):
+        if int(counts[slot].sum()) == 0:
+            assert float(jnp.abs(got[0][slot]).max()) == 0.0
+            assert float(got[1][slot].max()) == np.float32(NEG_INF)
+            assert float(jnp.abs(got[2][slot]).max()) == 0.0
+    if rows == "sharded":
+        return                   # live positions are no prefix of a row
     # and the gathered-dense oracle agrees slot by slot
     from repro.models.attention import decode_attend_local
     dense_k = jnp.swapaxes(kp[table], 2, 3).reshape(b, j * ps, kv, d)

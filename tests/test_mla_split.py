@@ -220,6 +220,46 @@ def test_scheduler_bucketed_tables_match_fixed_width(mla, rng):
         assert stats_b[k] == stats_f[k], k
 
 
+def test_scheduler_counts_live_and_staged_table_entries(rng):
+    """``decode_pages_live`` adds up, step by step, the pages of the
+    slots that decode — exactly the leading entries of their table
+    rows — and ``decode_pages_table`` the B x W entries staged; live
+    never exceeds staged, and idle slots and bucket rounding leave a
+    gap."""
+    cfg = _cfg(mla=None)
+    ecfg = EngineConfig(batch=3, max_len=32, paged=True, page_size=4)
+    eng = DecodeEngine(cfg, ecfg)
+    sched = Scheduler(eng, bucket_tables=True)
+    for i, (P, G) in enumerate([(7, 10), (3, 4), (12, 6), (5, 9)]):
+        sched.submit(Request(rid=i, tokens=rng.integers(
+            0, cfg.vocab, (P,)).astype(np.int32), gen=G))
+    want = {"decode_pages_live": 0, "decode_pages_table": 0}
+    prepare = sched._prepare_step
+
+    def counted():
+        plan = prepare()
+        if plan is not None:
+            was_running, _, _, batch = plan
+            table = np.asarray(batch["block_table"])
+            live = 0
+            for sid in was_running:
+                pages = sched.slots[sid].pages
+                np.testing.assert_array_equal(table[sid, :len(pages)],
+                                              pages)
+                live += len(pages)
+            assert live <= table.size
+            want["decode_pages_live"] += live
+            want["decode_pages_table"] += table.size
+        return plan
+
+    sched._prepare_step = counted
+    res = sched.run()
+    assert set(res) == {0, 1, 2, 3}
+    for k, v in want.items():
+        assert sched.stats[k] == v, k
+    assert 0 < want["decode_pages_live"] < want["decode_pages_table"]
+
+
 # ------------------------------------------------- dispatch geometry
 
 

@@ -8,8 +8,9 @@ Mosaic refuses blocks whose last two dims are neither (8, 128)-aligned
 nor the array's own.
 
 Widths: tinyllama-1.1b (32 heads, 4 KV heads, Dh 64, d_ff 5632) on
-16-token pages for the GQA kernels, DeepSeek-V3's latent (r 512, rope
-64, 128 heads) for MLA.  The topology is described inside a fixture,
+16-token pages for the GQA kernels, and qwen1.5-0.5b (16 heads, 16 KV
+heads) at the benchmark cells' pools for the bf16 paged decode;
+DeepSeek-V3's latent (r 512, rope 64, 128 heads) for MLA.  The topology is described inside a fixture,
 never at import time, so every pytest-xdist worker collects the same
 tests and only the worker that runs this file loads the TPU library.
 """
@@ -81,6 +82,17 @@ def test_paged_gqa_decode(one_chip, q8):
         _compile(lambda q, k, v, t, c: ops.vwr_paged_flash_decode(
             q, k, v, t, c, interpret=False), one_chip,
             ((B, H, D), BF16), pool, pool, *tables)
+
+
+@pytest.mark.parametrize("b,j,n_pages", [(24, 320, 3800), (64, 24, 1536)],
+                         ids=["longctx", "chat"])
+def test_paged_mha_decode_benchmark_cells(one_chip, b, j, n_pages):
+    """qwen1.5-0.5b (16 heads over 16 KV heads, Dh 64) at the benchmark
+    cells' slots, widest table and pool: 8 whole pages staged a step."""
+    pool = ((n_pages, 16, PS, D), BF16)
+    _compile(lambda q, k, v, t, c: ops.vwr_paged_flash_decode(
+        q, k, v, t, c, interpret=False), one_chip,
+        ((b, 16, D), BF16), pool, pool, ((b, j), I32), ((b, j), I32))
 
 
 @pytest.mark.parametrize("q8", [False, True], ids=["bf16", "int8"])
