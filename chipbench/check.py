@@ -1,5 +1,6 @@
 """How ``correct`` is decided: the served tokens against the float32
-reference.
+reference of the cell's architecture file
+(``chipbench/architectures/<name>.py``).
 
 After the window, a sample of requests drawn from the seed (always with
 the longest request served, and more until ``CHECK_TOKENS`` served
@@ -14,11 +15,10 @@ is ``missing`` and fails the run whatever the gaps say.
 """
 from __future__ import annotations
 
+from types import ModuleType
 from typing import Dict, List
 
 import numpy as np
-
-from chipbench import reference
 
 CHECK_TOKENS = 256
 CHECK_REQUESTS = 12
@@ -49,18 +49,19 @@ def _summary(gaps: np.ndarray, prefix: str = "") -> Dict[str, float]:
             prefix + "flip_share": float((gaps > 0).mean())}
 
 
-def readings(weights, model: dict, reqs: List,
+def readings(arch: ModuleType, weights, model: dict, reqs: List,
              control: bool = False) -> Dict[str, float]:
-    """Gaps of every served token of ``reqs`` against the reference;
-    with ``control`` also those of the fp8 control's first choices."""
+    """Gaps of every served token of ``reqs`` against ``arch``'s
+    reference; with ``control`` also those of the fp8 control's first
+    choices."""
     if not reqs:
         return {"gap_max": float("nan"), "gap_mean": float("nan"),
                 "flip_share": float("nan"), "tokens": 0, "requests": 0}
-    t_pad = reference.pad_length(
+    t_pad = arch.pad_length(
         [len(r.prompt) + len(r.tokens) - 1 for r in reqs])
-    r_pad = reference.pad_rows([len(r.tokens) for r in reqs])
-    out = [reference.served_gaps(weights, model, r.prompt, r.tokens,
-                                 t_pad=t_pad, r_pad=r_pad, control=control)
+    r_pad = arch.pad_rows([len(r.tokens) for r in reqs])
+    out = [arch.served_gaps(weights, model, r.prompt, r.tokens,
+                            t_pad=t_pad, r_pad=r_pad, control=control)
            for r in reqs]
     if control:
         gaps = np.concatenate([g for g, _ in out])

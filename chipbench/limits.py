@@ -5,13 +5,14 @@
 
 Runs the cell as ``run.py`` would, once per seed, and prints each run's
 check readings: the widest served-token gap against the float32
-reference, its mean, the share of tokens that were not the reference's
-best, the tokens compared, and the same three for the control on the
-same sample.  The control is the reference computed with float8 (e4m3)
-operands, the precision below the configuration's bf16: at each
-position, the gap of the token it puts first.  The limit in
-the configuration file lies between the largest sound reading and the
-smallest control reading.  The benchmark's own runs never run this.
+reference of the cell's architecture file, its mean, the share of
+tokens that were not the reference's best, the tokens compared, and the
+same three for the control on the same sample.  The control (the
+file's ``served_gaps(..., control=True)``) is the reference computed
+with float8 (e4m3) operands, the precision below the configuration's
+bf16: at each position, the gap of the token it puts first.  The limit
+in the configuration file lies between the largest sound reading and
+the smallest control reading.  The benchmark's own runs never run this.
 """
 from __future__ import annotations
 

@@ -6,7 +6,8 @@ the jitted decode step and whole-prompt prefill of ``DecodeEngine``
 (``jit_<function>`` on the ``XLA Modules`` line) and a Pallas kernel by
 the jitted wrapper it is called through (its custom call's instruction
 name on the ``XLA Ops`` line).  A reader that
-finds none of them returns None and the metric is left out.
+finds none of them returns None and the metric is left out.  Operations
+and bytes come from the cell's architecture file (``rec.arch``).
 """
 from __future__ import annotations
 
@@ -92,7 +93,7 @@ def decode_mfu(rec) -> Optional[float]:
     if not progs:
         return None
     m = rec.config["model"]
-    flops = sum(costs.decode_flops(m, s.n_active, s.ctx)
+    flops = sum(rec.arch.decode_flops(m, s.n_active, s.ctx)
                 for s in _window_steps(rec))
     t = sum(p.dur for p in progs)
     return 100.0 * flops / (t * rec.peaks["bf16_flops_per_s"]
@@ -106,8 +107,9 @@ def decode_step_roofline(rec) -> Optional[float]:
     if not progs:
         return None
     m, n_chips = rec.config["model"], rec.device["count"]
-    least = sum(costs.roofline_s(costs.decode_flops(m, s.n_active, s.ctx),
-                                 costs.decode_bytes(m, s.n_active, s.ctx),
+    arch = rec.arch
+    least = sum(costs.roofline_s(arch.decode_flops(m, s.n_active, s.ctx),
+                                 arch.decode_bytes(m, s.n_active, s.ctx),
                                  rec.peaks) for s in _window_steps(rec))
     return 100.0 * least / (n_chips * sum(p.dur for p in progs))
 
@@ -139,7 +141,7 @@ def prefill_mfu(rec) -> Optional[float]:
     m = rec.config["model"]
     lens = [P for a in rec.admits if rec.t0 <= a.t0 and a.t1 <= rec.t1
             for P in a.prompt_lens]
-    flops = sum(costs.prefill_flops(m, P) for P in lens)
+    flops = sum(rec.arch.prefill_flops(m, P) for P in lens)
     return 100.0 * flops / (sum(p.dur for p in progs)
                             * rec.peaks["bf16_flops_per_s"]
                             * rec.device["count"])

@@ -1,8 +1,8 @@
 """Paged flash-decode kernel: least time for the live KV it must read (or its operations) over its device time."""
-from chipbench import costs, measure
+from chipbench import measure
 
 
 def read(rec):
     return measure.kernel_roofline(rec, measure.PAGED_DECODE_KERNEL,
-                                   costs.paged_decode_flops,
-                                   costs.paged_decode_bytes)
+                                   rec.arch.paged_decode_flops,
+                                   rec.arch.paged_decode_bytes)

@@ -3,11 +3,12 @@
     python chipbench/run.py --workload <cell> --seed <n> --seconds <s> \\
         --trace <0|1>
 
-Loads the cell's configuration, mix and metric readers by name, builds
-the served path (``DecodeEngine(paged=True)`` + ``Scheduler``) with
-weights made on the device from the seed, warms every shape the mix
-uses, measures for ``--seconds``, then checks the served tokens against
-the float32 reference.  The last line of stdout is one JSON object:
+Loads the cell's configuration, its architecture file, mix and metric
+readers by name, builds the served path (``DecodeEngine(paged=True)`` +
+``Scheduler``) with the architecture's weights made on the device from
+the seed, warms every shape the mix uses, measures for ``--seconds``,
+then checks the served tokens against the architecture's float32
+reference.  The last line of stdout is one JSON object:
 ``correct``, ``attempted``, ``failed``, ``metrics`` (the cell's
 end-to-end metrics, or with ``--trace 1`` its per-layer metrics, read
 from a profiler trace of the window), ``device`` and, traced,
@@ -104,14 +105,14 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: int,
     _say(f"{cell.name}: {devices[0].device_kind} x{cell.chips}, seed "
          f"{seed}, {seconds} s, trace {trace}; compile cache {cache_dir}")
     counter = serve.CompileCounter()
-    server = serve.Server(cell.config, cell.mix, seed, devices[:cell.chips],
-                          period=seconds)
+    server = serve.Server(cell.arch, cell.config, cell.mix, seed,
+                          devices[:cell.chips], period=seconds)
     server.warm()
     saturated = cell.mix["arrival"]["kind"] == "saturated"
     if saturated:
         server.fill()
-    rec = serve.Record(cell=cell.name, config=cell.config, mix=cell.mix,
-                       seed=seed, peaks=peaks)
+    rec = serve.Record(cell=cell.name, config=cell.config, arch=cell.arch,
+                       mix=cell.mix, seed=seed, peaks=peaks)
     rec.setup_s = time.monotonic() - (t_start if t_start is not None
                                       else T_START)
     trace_dir = None
@@ -167,7 +168,7 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: int,
     gc.collect()
     pool = [r for r in rec.requests.values() if r.status not in bad]
     t = time.monotonic()
-    read = check.readings(server.weights, cell.config["model"],
+    read = check.readings(cell.arch, server.weights, cell.config["model"],
                           check.sample(pool, seed), control=control)
     correct, compared = check.verdict(read, cell.config.get("check", {}),
                                       len(failed))

@@ -23,14 +23,16 @@ from __future__ import annotations
 
 import dataclasses
 import time
+import typing
 from collections import deque
+from types import ModuleType
 from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import traffic, weights
+from chipbench import traffic
 
 DRAIN_S = 60.0
 
@@ -68,6 +70,7 @@ class Record:
     """Everything the metric readers and the check read."""
     cell: str
     config: dict
+    arch: ModuleType                # the cell's architecture file
     mix: dict
     seed: int
     t0: float = 0.0                 # window start (monotonic)
@@ -107,8 +110,20 @@ class CompileCounter:
 
 
 def model_config(config: dict, kernel_impl: str):
+    """The configuration's ``model`` section as a ``ModelConfig``.  An
+    object under a key whose field is typed as a dataclass (``moe``,
+    ``mla``) becomes that dataclass."""
     from repro.common.config import ModelConfig
-    return ModelConfig(**config["model"], kernel_impl=kernel_impl)
+    hints = typing.get_type_hints(ModelConfig)
+    kw = {}
+    for k, v in config["model"].items():
+        if isinstance(v, dict):
+            t = hints[k]
+            cls = next(c for c in (t, *typing.get_args(t))
+                       if dataclasses.is_dataclass(c))
+            v = cls(**v)
+        kw[k] = v
+    return ModelConfig(**kw, kernel_impl=kernel_impl)
 
 
 def table_widths(mix: dict, page_size: int, max_pages: int) -> List[int]:
@@ -124,8 +139,8 @@ def table_widths(mix: dict, page_size: int, max_pages: int) -> List[int]:
 class Server:
     """Engine + scheduler for one cell, and the client that drives it."""
 
-    def __init__(self, config: dict, mix: dict, seed: int, devices,
-                 period: float = None):
+    def __init__(self, arch: ModuleType, config: dict, mix: dict,
+                 seed: int, devices, period: float = None):
         from repro.dist import sharding as SH
         from repro.engine import DecodeEngine, EngineConfig, Scheduler
         from repro.launch.mesh import make_local_mesh
@@ -140,7 +155,7 @@ class Server:
         wkey = jax.random.PRNGKey(
             int(np.random.default_rng([seed, 3]).integers(2**31)))
         self.weights = jax.block_until_ready(
-            weights.make(config, wkey, shardings))
+            arch.make(config, wkey, shardings))
         self.engine = DecodeEngine(self.cfg, EngineConfig(
             batch=dep["slots"], max_len=dep["max_len"],
             mesh_shape=(data, model), decode_shard=dep["decode_shard"],

@@ -1,6 +1,9 @@
 """Find every piece of a cell by the name ``BENCHMARK.json`` gives it.
 
     configuration  BENCHMARK.json ``configs[].file``   (a JSON file)
+    architecture   chipbench/architectures/<architecture>.py, named by
+                   the configuration's ``"architecture"`` key: its
+                   weights, float32 reference and counts (``INTERFACE``)
     traffic mix    chipbench/traffic/<traffic>.json
     metric         chipbench/metrics/<metric name>.py  (defines ``read``),
                    else chipbench/metrics/<name less its last part>.py,
@@ -8,8 +11,9 @@
                    ``engine.decode_step_ms.py``
     peaks          chipbench/peaks.json, keyed by ``device_kind``
 
-Nothing here names a configuration, mix or metric: a new one is a new
-file and an entry in ``BENCHMARK.json``.
+Nothing here names a configuration, architecture, mix or metric: a new
+one is a new file and, but for an architecture, an entry in
+``BENCHMARK.json``.
 """
 from __future__ import annotations
 
@@ -17,9 +21,17 @@ import dataclasses
 import importlib.util
 import json
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List
 
 HERE = Path(__file__).resolve().parent
+
+# what every architecture file defines: the harness calls these by name
+INTERFACE = ("shapes", "make",
+             "logits", "served_gaps", "pad_length", "pad_rows",
+             "weight_bytes", "kv_bytes_per_position", "decode_flops",
+             "decode_bytes", "prefill_flops", "paged_decode_flops",
+             "paged_decode_bytes")
 
 
 @dataclasses.dataclass
@@ -35,6 +47,7 @@ class Cell:
     name: str
     chips: int
     config: dict
+    arch: ModuleType                # chipbench/architectures/<name>.py
     mix: dict
     metrics: List[Metric]           # end-to-end then per-layer
 
@@ -44,6 +57,14 @@ def load_json(path: Path) -> dict:
         return json.load(f)
 
 
+def _load_module(path: Path, prefix: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(
+        f"{prefix}_{path.stem.replace('.', '_')}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def load_reader(root: Path, name: str) -> Callable:
     folder = root / "chipbench" / "metrics"
     path = folder / f"{name}.py"
@@ -51,11 +72,18 @@ def load_reader(root: Path, name: str) -> Callable:
         path = folder / f"{name.rsplit('.', 1)[0]}.py"
     if not path.exists():
         raise FileNotFoundError(f"no reader for metric {name!r} in {folder}")
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{path.stem.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load_module(path, "chipbench_metric").read
+
+
+def load_architecture(root: Path, name: str) -> ModuleType:
+    path = root / "chipbench" / "architectures" / f"{name}.py"
+    if not path.exists():
+        raise FileNotFoundError(f"no architecture file {path}")
+    mod = _load_module(path, "chipbench_arch")
+    missing = [n for n in INTERFACE if not callable(getattr(mod, n, None))]
+    if missing:
+        raise AttributeError(f"{path} does not define {missing}")
+    return mod
 
 
 def load_cell(root: Path, workload: str) -> Cell:
@@ -66,7 +94,10 @@ def load_cell(root: Path, workload: str) -> Cell:
                        f"{sorted(cells)}")
     w = cells[workload]
     configs = {c["name"]: c for c in bench["configs"]}
-    config = load_json(root / configs[w["config"]]["file"])
+    config_file = root / configs[w["config"]]["file"]
+    config = load_json(config_file)
+    if "architecture" not in config:
+        raise ValueError(f"{config_file} names no \"architecture\"")
     mix = load_json(root / "chipbench" / "traffic" / f"{w['traffic']}.json")
     metrics = []
     for kind in ("end_to_end", "per_layer"):
@@ -78,6 +109,7 @@ def load_cell(root: Path, workload: str) -> Cell:
                 name=m["name"], unit=m["unit"], kind=kind,
                 read=load_reader(root, m["name"])))
     return Cell(name=workload, chips=int(w["chips"]), config=config,
+                arch=load_architecture(root, config["architecture"]),
                 mix=mix, metrics=metrics)
 
 

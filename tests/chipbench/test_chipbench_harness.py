@@ -64,6 +64,43 @@ def root(tmp_path, monkeypatch):
     return tmp_path
 
 
+# appended to a copy of ``dense.py``: every count the harness calls
+# scaled by SCALE, and each call of the reference recorded
+SCALED_ARCH = '''
+
+SCALE = 1000.0
+CALLS = []
+_served_gaps = served_gaps
+
+
+def served_gaps(weights, model, prompt, served, **kw):
+    CALLS.append(len(served))
+    return _served_gaps(weights, model, prompt, served, **kw)
+
+
+def _scaled(count):
+    return lambda *a: SCALE * count(*a)
+
+
+for _name in ("decode_flops", "decode_bytes", "prefill_flops",
+              "paged_decode_flops", "paged_decode_bytes"):
+    globals()[_name] = _scaled(globals()[_name])
+'''
+
+DENSE_MFU_READER = '''"""The decode MFU of the window with the dense file's counts."""
+import dataclasses
+from pathlib import Path
+
+from chipbench import measure, spec
+
+DENSE = spec.load_architecture(Path(__file__).resolve().parents[2], "dense")
+
+
+def read(rec):
+    return measure.decode_mfu(dataclasses.replace(rec, arch=DENSE))
+'''
+
+
 def _run(root, cell, trace=0, **kw):
     from chipbench import run
     return run.run(root, cell, SEED, 1.0, trace, require_tpu=False,
@@ -104,6 +141,58 @@ def test_new_metric_reader_read_in_traced_run(root, monkeypatch):
     assert res["metrics"]["tiny.decode_steps"]["value"] > 0
     assert res["device"]["busy_s"] > 0 and res["device"]["window_s"] > 0
     assert "device_ops" in res["breakdown"]
+
+
+def test_architecture_added_as_a_file_is_picked_up(root, monkeypatch):
+    """A configuration names an architecture file that exists only in
+    this checkout: a copy of the dense file with its counts scaled.  The
+    run checks its served tokens with that file's reference, and a
+    traced run's decode MFU reads that file's counts."""
+    from chipbench import spec
+    from chipbench import trace as tr
+    cb = root / "chipbench"
+    (cb / "architectures" / "scaled.py").write_text(
+        (cb / "architectures" / "dense.py").read_text() + SCALED_ARCH)
+    cfg = json.loads((DATA / "tiny-gqa.json").read_text())
+    cfg["architecture"] = "scaled"
+    (cb / "configs" / "tiny-scaled.json").write_text(json.dumps(cfg))
+    (cb / "metrics" / "tiny.dense_decode_mfu.py").write_text(
+        DENSE_MFU_READER)
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "tiny-scaled", "source": "test",
+                             "file": "chipbench/configs/tiny-scaled.json",
+                             "reduced": [], "why": "test"})
+    bench["workloads"].append({"name": "tiny.scaled",
+                               "config": "tiny-scaled",
+                               "traffic": "tiny-sat", "chips": 1,
+                               "why": "test"})
+    for name in ("model.decode_mfu.tiny", "tiny.dense_decode_mfu"):
+        bench["per_layer"].append(
+            {"name": name, "unit": "%", "better": "higher",
+             "source": "device_trace", "layer": "model",
+             "moves": "tokens_per_s", "workloads": ["tiny.scaled"]})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    loaded = {}
+    load = spec.load_architecture
+
+    def load_kept(r, name):
+        loaded[name] = load(r, name)
+        return loaded[name]
+    monkeypatch.setattr(spec, "load_architecture", load_kept)
+    fixture = json.loads((DATA / "trace_fixture.json").read_text())
+    monkeypatch.setattr(tr, "load_xplane",
+                        lambda d: tr.Trace.from_json(fixture))
+    res = _run(root, "tiny.scaled", trace=1)
+    assert res["correct"], res["check"]
+    arch = loaded["scaled"]
+    assert Path(arch.__file__) == cb / "architectures" / "scaled.py"
+    read = res["window"]["check_readings"]
+    assert len(arch.CALLS) == read["requests"] > 0
+    assert sum(arch.CALLS) == read["tokens"]
+    m = res["metrics"]
+    assert m["model.decode_mfu.tiny"]["value"] == pytest.approx(
+        arch.SCALE * m["tiny.dense_decode_mfu"]["value"], rel=1e-9)
 
 
 # -- faults in the timed path: each has to make ``correct`` false ------
@@ -214,6 +303,10 @@ def test_every_benchmark_entry_has_its_files():
     cells = {w["name"] for w in bench["workloads"]}
     for c in bench["configs"]:
         assert (REPO / c["file"]).is_file()
+        cfg = json.loads((REPO / c["file"]).read_text())
+        arch = spec.load_architecture(REPO, cfg["architecture"])
+        for name in spec.INTERFACE:
+            assert callable(getattr(arch, name)), (c["name"], name)
     for w in bench["workloads"]:
         assert (REPO / "chipbench" / "traffic"
                 / f"{w['traffic']}.json").is_file()
@@ -236,18 +329,20 @@ def test_fp8_control_fails_the_limit(seed):
     limit (0.15) lies between.  The program passes, its control fails."""
     import itertools
     import jax
-    from chipbench import check, serve
+    from chipbench import check, serve, spec
     from repro.engine import Request
     cfg = json.loads((DATA / "tiny-gqa.json").read_text())
     mix = json.loads((DATA / "tiny-sat.json").read_text())
-    srv = serve.Server(cfg, mix, seed, jax.devices())
+    arch = spec.load_architecture(REPO, cfg["architecture"])
+    srv = serve.Server(arch, cfg, mix, seed, jax.devices())
     items = list(itertools.islice(srv.stream, 12))
     for it in items:
         srv.sched.submit(Request(rid=it.idx, tokens=it.prompt, gen=it.gen))
     done = srv.sched.run()
     reqs = [serve.Req(idx=it.idx, prompt=it.prompt, gen=it.gen, due=0.0,
                       tokens=done[it.idx].tokens) for it in items]
-    read = check.readings(srv.weights, cfg["model"], reqs, control=True)
+    read = check.readings(arch, srv.weights, cfg["model"], reqs,
+                          control=True)
     limit = cfg["check"]["gap_max"]
     assert read["tokens"] == sum(it.gen for it in items)
     assert read["gap_max"] <= limit < read["control_gap_max"]

@@ -1,10 +1,11 @@
-"""The float32 reference against the serving engine, at a small size on
-the CPU: prefill written into pages, then paged decode through the
-cache, teacher-forced on the same tokens, agree with the reference's
-one pass to float32 rounding, on the XLA and the Pallas path.  Two
-shapes of the dense decoder: grouped-query attention with q/k/v biases
-and a tied head (as qwen1.5-0.5b), and without biases and with an untied
-head (as granite-3-8b)."""
+"""The dense architecture's float32 reference
+(``chipbench/architectures/dense.py``) against the serving engine, at a
+small size on the CPU: prefill written into pages, then paged decode
+through the cache, teacher-forced on the same tokens, agree with the
+reference's one pass to float32 rounding, on the XLA and the Pallas
+path.  Two shapes of the dense decoder: grouped-query attention with
+q/k/v biases and a tied head (as qwen1.5-0.5b), and without biases and
+with an untied head (as granite-3-8b)."""
 import json
 import sys
 from pathlib import Path
@@ -17,7 +18,9 @@ import pytest
 REPO = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(REPO))
 
-from chipbench import reference, serve, weights  # noqa: E402
+from chipbench import serve, spec  # noqa: E402
+
+dense = spec.load_architecture(REPO, "dense")
 
 BASE = json.loads((Path(__file__).resolve().parent / "data"
                    / "tiny-gqa.json").read_text())
@@ -62,12 +65,12 @@ def _engine_logits(config, w, prompt, impl):
 @pytest.mark.parametrize("shape", sorted(SHAPES))
 def test_reference_matches_engine(shape, impl):
     config = _config(shape)
-    w = weights.make(config, jax.random.PRNGKey(3))
+    w = dense.make(config, jax.random.PRNGKey(3))
     prompt = np.random.default_rng(0).integers(2, 500, (P,)).astype(
         np.int32)
     got, forced = _engine_logits(config, w, prompt, impl)
     seq = np.concatenate([prompt, forced[:-1]]).astype(np.int32)
-    want = reference.logits(w, config["model"], seq,
+    want = dense.logits(w, config["model"], seq,
                             np.arange(P - 1, P - 1 + GEN))
     err = np.abs(got - want).max() / np.abs(want).max()
     assert err < RTOL, err
@@ -75,18 +78,18 @@ def test_reference_matches_engine(shape, impl):
 
 def test_served_gap_is_zero_for_reference_tokens():
     config = _config("bias-tied")
-    w = weights.make(config, jax.random.PRNGKey(4))
+    w = dense.make(config, jax.random.PRNGKey(4))
     prompt = np.random.default_rng(2).integers(2, 500, (P,)).astype(
         np.int32)
     seq, served = list(prompt), []
     for _ in range(5):               # greedy decoding by the reference
-        lg = reference.logits(w, config["model"], np.asarray(seq, np.int32),
+        lg = dense.logits(w, config["model"], np.asarray(seq, np.int32),
                               np.asarray([len(seq) - 1]))
         served.append(int(lg[0].argmax()))
         seq.append(served[-1])
-    gaps = reference.served_gaps(w, config["model"], prompt,
+    gaps = dense.served_gaps(w, config["model"], prompt,
                                  np.asarray(served))
     assert gaps.shape == (5,) and np.all(gaps == 0)
     wrong = np.asarray(served)
     wrong[2] = (wrong[2] + 1) % 500
-    assert reference.served_gaps(w, config["model"], prompt, wrong)[2] > 0
+    assert dense.served_gaps(w, config["model"], prompt, wrong)[2] > 0
