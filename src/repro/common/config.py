@@ -87,6 +87,16 @@ class ModelConfig:
     lb_coef: float = 0.01          # MoE load-balance coefficient
     router_z_coef: float = 1e-3    # MoE router z-loss coefficient
 
+    # published scaling multipliers (granite): the embedding times
+    # ``embedding_multiplier``, attention scores times
+    # ``attention_multiplier`` (None: 1/sqrt(d_head)), every residual
+    # branch times ``residual_multiplier``, the logits divided by
+    # ``logits_scaling``.  The defaults leave the model unscaled.
+    embedding_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+
     moe: Optional[MoEConfig] = None
     mla: Optional[MLAConfig] = None
     mamba2: Optional[Mamba2Config] = None
@@ -141,6 +151,11 @@ class ModelConfig:
     # counts while-loop bodies once).  Accounting programs are lowered,
     # never executed, so their transient sizes don't matter.
     accounting: bool = False
+
+    def __post_init__(self):
+        if self.attention_multiplier is not None and self.mla is not None:
+            raise ValueError("attention_multiplier scales GQA scores; "
+                             "MLA has its own softmax scale")
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)

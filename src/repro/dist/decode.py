@@ -37,7 +37,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as PS
+from jax.sharding import NamedSharding, PartitionSpec as PS
 
 from repro.common.hints import resolve_mesh
 from repro.kernels import dispatch as D
@@ -50,12 +50,14 @@ def _normalize(o_t, l, dtype):
 
 def _combine_partials(o_t, m, l, axis_name, dtype):
     """pmax/psum statistics combine shared by every sharded decode
-    route (must run inside shard_map over ``axis_name``)."""
-    m_star = jax.lax.pmax(m, axis_name)
-    s = jnp.exp(m - m_star)                               # (B, H)
-    o = jax.lax.psum(o_t * s[..., None], axis_name)
-    l = jax.lax.psum(l * s, axis_name)
-    return _normalize(o, l, dtype)
+    route (must run inside shard_map over ``axis_name``); named scope
+    ``attn.combine``."""
+    with jax.named_scope("attn.combine"):
+        m_star = jax.lax.pmax(m, axis_name)
+        s = jnp.exp(m - m_star)                           # (B, H)
+        o = jax.lax.psum(o_t * s[..., None], axis_name)
+        l = jax.lax.psum(l * s, axis_name)
+        return _normalize(o, l, dtype)
 
 
 def local_decode_attend(q, cache_k, cache_v, cur_len, *,
@@ -356,6 +358,11 @@ def sharded_paged_flash_decode(mesh, q, k_pool, v_pool, table, lens, *,
     scale_specs = ((PS(model_axis, None), PS(model_axis, None))
                    if q8 else ())
     scale_args = (k_scale, v_scale) if q8 else ()
+    # every shard attends with every head: the head-sharded query is
+    # gathered here, under a scope of its own
+    with jax.named_scope("attn.q_gather"):
+        q = jax.lax.with_sharding_constraint(
+            q, NamedSharding(mesh, PS(dp, None, None)))
     fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(PS(dp, None, None),

@@ -233,6 +233,31 @@ def cache_pspecs(cfg, mesh, batch: int, *, seq_shard: bool = False):
     raise ValueError(fam)
 
 
+def _page_axis(mesh, seq_shard: bool, n_pages: Optional[int]):
+    """The mesh axis a paged pool's page dim takes: 'model' when
+    sequence-sharded and the pages split evenly over it, else None."""
+    mp = model_axis(mesh)
+    if (seq_shard and mp is not None
+            and (n_pages is None or n_pages % mesh.shape[mp] == 0)):
+        return mp
+    return None
+
+
+def page_slabs(mesh, n_pages: int) -> int:
+    """How many contiguous slabs a sequence-sharded pool of ``n_pages``
+    is split into on ``mesh`` (1 when its page dim is not sharded).
+    Slab s holds pages ``[s * n_pages // k, (s + 1) * n_pages // k)``;
+    ``page_slab_of`` maps pages to their slab."""
+    ax = _page_axis(mesh, True, n_pages)
+    return mesh.shape[ax] if ax is not None else 1
+
+
+def page_slab_of(pages, n_pages: int, slabs: int):
+    """The slab of each page in ``pages`` (a numpy array) of a pool
+    split into ``slabs`` by ``page_slabs``."""
+    return pages // (n_pages // slabs)
+
+
 def paged_cache_pspecs(cfg, mesh, batch_slots: int, *,
                        seq_shard: bool = False,
                        n_pages: Optional[int] = None,
@@ -258,9 +283,7 @@ def paged_cache_pspecs(cfg, mesh, batch_slots: int, *,
     sizes = _axis_sizes(mesh)
     bax = (dp if dp is not None and batch_slots % data_size(mesh) == 0
            else None)
-    pageax = (mp if (seq_shard and mp is not None
-                     and (n_pages is None or n_pages % sizes[mp] == 0))
-              else None)
+    pageax = _page_axis(mesh, seq_shard, n_pages)
     kvax = (mp if (pageax is None and mp is not None
                    and cfg.n_kv_heads % sizes[mp] == 0) else None)
 

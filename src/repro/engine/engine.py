@@ -245,7 +245,8 @@ class DecodeEngine:
         if self.ecfg.paged:
             cache = paged_cache.init_paged_cache(
                 self.cfg, self.n_pages, self.page_size, B,
-                enc_len=enc_len, kv_dtype=self.ecfg.kv_dtype)
+                enc_len=enc_len, kv_dtype=self.ecfg.kv_dtype,
+                shardings=SH.to_shardings(self.mesh, self.cache_pspecs))
             cache = paged_cache.write_prefill(
                 self.cfg, cache, caches, self.default_block_table())
         else:
@@ -257,19 +258,19 @@ class DecodeEngine:
         return logits, cache
 
     def init_paged_cache(self, enc_len: Optional[int] = None):
-        """Zeroed page pools laid out on the engine mesh — the
-        starting cache for continuous batching (``engine.scheduler``
-        fills it per admitted request).  ``enc_len`` budgets the audio
-        cross cache (default: the engine max_len)."""
+        """Zeroed page pools made in their layout on the engine mesh
+        (each device makes only its shard) — the starting cache for
+        continuous batching (``engine.scheduler`` fills it per admitted
+        request).  ``enc_len`` budgets the audio cross cache (default:
+        the engine max_len)."""
         if not self.ecfg.paged:
             raise ValueError("init_paged_cache() needs paged=True")
-        cache = paged_cache.init_paged_cache(
+        return paged_cache.init_paged_cache(
             self.cfg, self.n_pages, self.page_size, self.ecfg.batch,
             enc_len=(enc_len if enc_len is not None
                      else self.ecfg.max_len),
-            kv_dtype=self.ecfg.kv_dtype)
-        return jax.device_put(
-            cache, SH.to_shardings(self.mesh, self.cache_pspecs))
+            kv_dtype=self.ecfg.kv_dtype,
+            shardings=SH.to_shardings(self.mesh, self.cache_pspecs))
 
     def default_block_table(self) -> jax.Array:
         """Whole-batch identity block table: slot b owns pages

@@ -166,12 +166,19 @@ def paged_cache_spec(cfg, n_pages: int, page_size: int,
 
 def init_paged_cache(cfg, n_pages: int, page_size: int,
                      batch_slots: int, enc_len: int = 0,
-                     kv_dtype: str = None):
+                     kv_dtype: str = None, *, shardings):
+    """Zeroed pools, every leaf made by one jitted program straight into
+    its layout under ``shardings`` (a matching tree), so no device ever
+    holds a whole leaf of a sharded pool."""
     spec = paged_cache_spec(cfg, n_pages, page_size, batch_slots,
                             enc_len, kv_dtype)
-    return jax.tree.map(
-        lambda s: jnp.zeros(s.shape, s.dtype), spec,
-        is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct))
+
+    def zeros():
+        return jax.tree.map(
+            lambda s: jnp.zeros(s.shape, s.dtype), spec,
+            is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct))
+
+    return jax.jit(zeros, out_shardings=shardings)()
 
 
 # ----------------------------------------------------------------------

@@ -88,6 +88,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
+from repro.dist import sharding as SH
 from repro.engine.faults import CrashError
 from repro.engine.paged_cache import (PageAllocator, PagePoolExhausted,
                                       bucket_table_width, fork_page,
@@ -440,6 +441,15 @@ class Scheduler:
                       # chunked-prefill counters (zero when it's off)
                       "mixed_steps": 0, "chunks": 0,
                       "chunked_tokens": 0}
+        # a page pool split into slabs over model shards
+        # (decode_shard='seq'): ``decode_pages_live_by_shard[s]`` sums
+        # the decoding slots' table entries that land in slab s, over
+        # steps
+        self.pool_shards = (SH.page_slabs(engine.mesh, engine.n_pages)
+                            if engine.ecfg.decode_shard == "seq" else 1)
+        if self.pool_shards > 1:
+            self.stats["decode_pages_live_by_shard"] = \
+                [0] * self.pool_shards
         self._latencies: List[float] = []
         self._itl: List[float] = []     # inter-token latency samples
         self._order = 0
@@ -1204,6 +1214,16 @@ class Scheduler:
         self.stats["decode_pages_live"] += sum(
             len(self.slots[sid].pages) for sid in was_running)
         self.stats["decode_pages_table"] += len(self.slots) * W
+        if self.pool_shards > 1 and was_running:
+            live = np.concatenate(
+                [self.table[sid, :len(self.slots[sid].pages)]
+                 for sid in was_running])
+            per = np.bincount(
+                SH.page_slab_of(live, self.eng.n_pages, self.pool_shards),
+                minlength=self.pool_shards)
+            self.stats["decode_pages_live_by_shard"] = [
+                a + b for a, b in zip(self.stats["decode_pages_live_by_shard"],
+                                      per.tolist())]
         dbatch = {"token": jnp.asarray(self.tokens),
                   "cur_len": jnp.asarray(self.lens),
                   "block_table": jnp.asarray(self.table[:, :W]),

@@ -119,7 +119,7 @@ def _swiglu_pallas(x2, wg, wi):
 
 
 @D.register("mlp", "xla")
-def _mlp_xla(p, x, act, residual=None, mesh=None):
+def _mlp_xla(p, x, act, residual=None, mesh=None, scale=1.0):
     if act == "swiglu":
         lead, d = x.shape[:-1], x.shape[-1]
         h = D.dispatch("swiglu", "xla", x.reshape(-1, d),
@@ -128,11 +128,13 @@ def _mlp_xla(p, x, act, residual=None, mesh=None):
         h = jnp.einsum("...d,df->...f", x, p["wi"])
         fn = jax.nn.gelu if act == "gelu" else jax.nn.relu
         h = fn(h.astype(jnp.float32)).astype(x.dtype)
+    if scale != 1.0:
+        h = h * scale
     out = jnp.einsum("...f,fd->...d", h, p["wo"])
     return out if residual is None else residual + out
 
 
-def _mlp_kernels(p, x2, act, r2=None):
+def _mlp_kernels(p, x2, act, r2=None, scale=1.0):
     from repro.kernels import ops
     if act == "swiglu":
         # dual-matmul fused swiglu: one staged x block feeds both
@@ -143,24 +145,26 @@ def _mlp_kernels(p, x2, act, r2=None):
     else:
         h = ops.vwr_matmul(x2, p["wi"],
                            activation="gelu" if act == "gelu" else "relu")
+    if scale != 1.0:
+        h = h * scale
     return ops.vwr_matmul(h, p["wo"], residual=r2)
 
 
 @D.register("mlp", "pallas")
-def _mlp_pallas(p, x, act, residual=None, mesh=None):
+def _mlp_pallas(p, x, act, residual=None, mesh=None, scale=1.0):
     from repro.dist import sharding as SH
     lead, d = x.shape[:-1], x.shape[-1]
     x2 = x.reshape(-1, d)
     if not SH.multi_device(mesh):
         r2 = None if residual is None else residual.reshape(-1, d)
-        return _mlp_kernels(p, x2, act, r2).reshape(*lead, d)
+        return _mlp_kernels(p, x2, act, r2, scale).reshape(*lead, d)
     # tensor parallel over 'model': column-split up-projections, row-
     # split down-projection, one psum
     ff = SH.tp_entry(mesh, p["wo"].shape[0])
     dp = SH.batch_entry(mesh, x2.shape[0])
 
     def local(p, x2):
-        out = _mlp_kernels(p, x2, act)
+        out = _mlp_kernels(p, x2, act, scale=scale)
         return out if ff is None else jax.lax.psum(out, ff)
 
     specs = {k: PS(ff, None) if k == "wo" else PS(None, ff) for k in p}
@@ -170,20 +174,23 @@ def _mlp_pallas(p, x, act, residual=None, mesh=None):
 
 
 def mlp(p, x, act: str, *, backend="xla", residual=None,
-        kernel_impl=None, mesh=None):
+        kernel_impl=None, mesh=None, scale=1.0):
     """FFN block via the dispatch registry.  With ``residual`` the
     residual add is part of the block (``residual + mlp(x)``); on the
     pallas path it is fused into the down-projection's final-K store
     (one HBM round-trip), the non-gated activation into the
     up-projection, and swiglu runs the dual-matmul fused kernel.  On a
     multi-device ``mesh`` the pallas path runs tensor-parallel per
-    device.  ``backend`` is a backend string or a ModelConfig; the
+    device.  ``scale`` multiplies the down projection's input (a
+    published residual multiplier; at 1.0 nothing is added).
+    ``backend`` is a backend string or a ModelConfig; the
     legacy ``kernel_impl=`` kwarg still works but is deprecated."""
     if kernel_impl is not None:
         D.warn_kernel_impl_kwarg("layers.mlp")
         backend = kernel_impl
+    kw = {} if scale == 1.0 else {"scale": scale}
     return D.dispatch("mlp", backend, p, x, act, residual=residual,
-                      mesh=mesh)
+                      mesh=mesh, **kw)
 
 
 # ---------------- frontends (stubs per brief) ----------------

@@ -58,6 +58,49 @@ def _norm(cfg, p, x):
 
 
 # ======================================================================
+# published scaling multipliers (ModelConfig: embedding_multiplier,
+# attention_multiplier, residual_multiplier, logits_scaling)
+# ======================================================================
+# Every forward path applies them through these helpers.  Each tests
+# the static config in Python: at the defaults it returns its input
+# untouched, so an unscaled model traces exactly as without them.
+
+def _embed(params, tokens, cfg):
+    x = L.embed(params["embed"], tokens).astype(jnp.dtype(cfg.dtype))
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
+    return x
+
+
+def _scale_q(cfg, q):
+    """Queries after RoPE.  Scores are ``attention_multiplier * q.k``
+    in place of ``q.k / sqrt(d_head)``: the ratio is folded into q, so
+    every attention kernel keeps its 1/sqrt(d_head)."""
+    if cfg.attention_multiplier is None:
+        return q
+    s = cfg.attention_multiplier * q.shape[-1] ** 0.5
+    return (q.astype(jnp.float32) * s).astype(q.dtype)
+
+
+def _branch(cfg, y):
+    """A residual branch times ``residual_multiplier``: applied to the
+    input of its last (linear) projection where there is one, so a
+    residual add fused into that projection stays fused."""
+    if cfg.residual_multiplier == 1.0:
+        return y
+    return y * cfg.residual_multiplier
+
+
+def _mlp(cfg, p, norm_p, x, *, residual=None, mesh=None):
+    """Pre-norm MLP branch, ``residual + mlp(norm(x))`` with
+    ``residual``; the residual multiplier scales the down projection's
+    input."""
+    return L.mlp(p, _norm(cfg, norm_p, x), cfg.act, backend=cfg,
+                 residual=residual, mesh=mesh,
+                 scale=cfg.residual_multiplier)
+
+
+# ======================================================================
 # per-family layer specs
 # ======================================================================
 
@@ -259,10 +302,12 @@ def _attn_delta(cfg, ap, h, positions, *, causal=True, residual=None,
                                        dense=cfg.accounting,
                                        head_axis=_head_axis(cfg),
                                        mesh=mesh)
+        out = _branch(cfg, out)
         return (out if residual is None else residual + out), cache
     with jax.named_scope("attn.qkv"):
         q, k, v = A.qkv_proj(ap, h, positions, cfg.rope_theta, cfg,
                              mesh=mesh)
+        q = _scale_q(cfg, q)
     if cfg.accounting:
         o = A.full_attn_ref(q, k, v, causal=causal, q_positions=positions,
                             kv_positions=positions)
@@ -273,7 +318,8 @@ def _attn_delta(cfg, ap, h, positions, *, causal=True, residual=None,
                        block_kv=cfg.attn_block_kv,
                        head_axis=_head_axis(cfg), mesh=mesh)
     with jax.named_scope("attn.out"):
-        out = A.o_proj(ap, o, cfg, residual=residual, mesh=mesh)
+        out = A.o_proj(ap, _branch(cfg, o), cfg, residual=residual,
+                       mesh=mesh)
     return out, (k, v)
 
 
@@ -290,8 +336,7 @@ def _dense_body(cfg, positions, x, lp, _ex, *, causal=True, collect=False,
     x, kv = _attn_delta(cfg, lp["attn"], _norm(cfg, lp["attn_norm"], x),
                         positions, causal=causal, residual=x, mesh=mesh)
     with jax.named_scope("mlp"):
-        x = L.mlp(lp["mlp"], _norm(cfg, lp["mlp_norm"], x), cfg.act,
-                  backend=cfg, residual=x, mesh=mesh)
+        x = _mlp(cfg, lp["mlp"], lp["mlp_norm"], x, residual=x, mesh=mesh)
     return x, (kv if collect else None)
 
 
@@ -301,7 +346,7 @@ def _moe_body(cfg, positions, x, lp, _ex, *, collect=False, mesh=None):
     with jax.named_scope("mlp"):
         y, aux = MOE.moe_ffn(lp["moe"], _norm(cfg, lp["mlp_norm"], x), cfg,
                               mesh=mesh)
-    return x + y, ((kv if collect else None), aux)
+    return x + _branch(cfg, y), ((kv if collect else None), aux)
 
 
 def _xattn_body(cfg, positions, enc_out, enc_valid, x, lp, _ex, *,
@@ -310,7 +355,7 @@ def _xattn_body(cfg, positions, enc_out, enc_valid, x, lp, _ex, *,
     x, kv = _attn_delta(cfg, lp["self"], _norm(cfg, lp["self_norm"], x),
                         positions, residual=x, mesh=mesh)
     h = _norm(cfg, lp["cross_norm"], x)
-    q = jnp.einsum("bsd,dhk->bshk", h, lp["cross"]["wq"])
+    q = _scale_q(cfg, jnp.einsum("bsd,dhk->bshk", h, lp["cross"]["wq"]))
     k = jnp.einsum("bsd,dhk->bshk", enc_out, lp["cross"]["wk"])
     v = jnp.einsum("bsd,dhk->bshk", enc_out, lp["cross"]["wv"])
     if cfg.accounting:
@@ -319,17 +364,15 @@ def _xattn_body(cfg, positions, enc_out, enc_valid, x, lp, _ex, *,
         o = A.blockwise_attn(q, k, v, causal=False, kv_valid=enc_valid,
                              block_q=cfg.attn_block_q,
                              block_kv=cfg.attn_block_kv, mesh=mesh)
-    x = A.o_proj(lp["cross"], o, cfg, residual=x, mesh=mesh)
-    x = L.mlp(lp["mlp"], _norm(cfg, lp["mlp_norm"], x), cfg.act,
-              backend=cfg, residual=x, mesh=mesh)
+    x = A.o_proj(lp["cross"], _branch(cfg, o), cfg, residual=x, mesh=mesh)
+    x = _mlp(cfg, lp["mlp"], lp["mlp_norm"], x, residual=x, mesh=mesh)
     return x, ((kv, (k, v)) if collect else None)
 
 
 def _shared_attn_apply(cfg, sp, x, positions, *, collect=False, mesh=None):
     x, kv = _attn_delta(cfg, sp["attn"], _norm(cfg, sp["attn_norm"], x),
                         positions, residual=x, mesh=mesh)
-    x = L.mlp(sp["mlp"], _norm(cfg, sp["mlp_norm"], x), cfg.act,
-              backend=cfg, residual=x, mesh=mesh)
+    x = _mlp(cfg, sp["mlp"], sp["mlp_norm"], x, residual=x, mesh=mesh)
     return x, (kv if collect else None)
 
 
@@ -361,7 +404,7 @@ def backbone(params, tokens, cfg, *, frontend_emb=None,
     aux: Dict[str, jax.Array] = {}
     caches: Any = None
 
-    x = L.embed(params["embed"], tokens).astype(jnp.dtype(cfg.dtype))
+    x = _embed(params, tokens, cfg)
     if fam == "vlm":
         pre = L.frontend_proj(params["frontend"], frontend_emb)
         x = jnp.concatenate([pre.astype(x.dtype), x], axis=1)
@@ -399,7 +442,7 @@ def backbone(params, tokens, cfg, *, frontend_emb=None,
 
         def mamba_body(x, lp, ex):
             d, st = SSM.mamba2_forward(lp, _norm(cfg, ex, x), cfg)
-            return x + d, (st if cc else None)
+            return x + _branch(cfg, d), (st if cc else None)
 
         def group_body(x, gp, gn):
             x, sts = _scan_stack(cfg, mamba_body, x, gp, extra_xs=gn)
@@ -421,12 +464,12 @@ def backbone(params, tokens, cfg, *, frontend_emb=None,
     elif fam == "ssm":
         def ml_body(x, lp, ex):
             d, st = XL.mlstm_forward(lp, _norm(cfg, ex, x), cfg)
-            return x + d, (st if cc else None)
+            return x + _branch(cfg, d), (st if cc else None)
 
         def group_body(x, gp, _ex):
             x, m_sts = _scan_stack(cfg, ml_body, x, gp["m"], extra_xs=gp["n"])
             d, s_st = XL.slstm_forward(gp["s"], x, cfg)
-            return x + d, ((m_sts, s_st) if cc else None)
+            return x + _branch(cfg, d), ((m_sts, s_st) if cc else None)
 
         stacked = {"m": params["mlstm"], "n": params["mlstm_norms"],
                    "s": params["slstm"]}
@@ -461,6 +504,8 @@ def _logits(params, h, cfg):
         logits = jnp.einsum("...d,vd->...v", h, params["embed"]["table"])
     else:
         logits = L.unembed(params["unembed"], h)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     if cfg.vocab_padded != cfg.vocab:
         pad_mask = jnp.arange(cfg.vocab_padded) >= cfg.vocab
         logits = jnp.where(pad_mask, -1e30, logits.astype(jnp.float32)
@@ -680,12 +725,12 @@ def _decode_gqa(cfg, lp, h, ck, cv, cur_len, mesh=None):
     v = jnp.einsum("bd,dhk->bhk", h, lp["wv"])
     if "bq" in lp:
         q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-    q = _rope1(q, cur_len, cfg.rope_theta)
+    q = _scale_q(cfg, _rope1(q, cur_len, cfg.rope_theta))
     k = _rope1(k, cur_len, cfg.rope_theta)
     ck = jax.lax.dynamic_update_slice(ck, k[:, None], (0, cur_len, 0, 0))
     cv = jax.lax.dynamic_update_slice(cv, v[:, None], (0, cur_len, 0, 0))
     o = _decode_attend(cfg, q, ck, cv, cur_len + 1, mesh)
-    delta = jnp.einsum("bhk,hkd->bd", o, lp["wo"])
+    delta = jnp.einsum("bhk,hkd->bd", _branch(cfg, o), lp["wo"])
     return delta, ck, cv
 
 
@@ -713,16 +758,17 @@ def _decode_mla(cfg, lp, h, cckv, ckr, cur_len, mesh=None):
                              scale=scale, backend=cfg.kernel_impl,
                              mesh=mesh,
                              seq_shard=(cfg.decode_shard == "seq"))
-    delta = MLA.mla_decode_finish(lp, o.astype(jnp.float32), cfg)
+    delta = _branch(cfg, MLA.mla_decode_finish(lp, o.astype(jnp.float32),
+                                               cfg))
     return delta.astype(h.dtype), cckv, ckr
 
 
 def _decode_cross(cfg, lp, h, xk, xv, mesh=None):
     """Cross-attention against the (static) encoder KV cache."""
-    q = jnp.einsum("bd,dhk->bhk", h, lp["wq"])
+    q = _scale_q(cfg, jnp.einsum("bd,dhk->bhk", h, lp["wq"]))
     T = xk.shape[1]
     o = _decode_attend(cfg, q, xk, xv, jnp.int32(T), mesh)
-    return jnp.einsum("bhk,hkd->bd", o, lp["wo"])
+    return jnp.einsum("bhk,hkd->bd", _branch(cfg, o), lp["wo"])
 
 
 # ---------------- paged decode (block-table + per-slot lengths) -------
@@ -773,7 +819,7 @@ def _decode_gqa_paged(cfg, lp, h, kp, vp, table, lens, mesh=None,
         v = jnp.einsum("bd,dhk->bhk", h, lp["wv"])
         if "bq" in lp:
             q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-        q = _rope_slots(q, lens, cfg.rope_theta)
+        q = _scale_q(cfg, _rope_slots(q, lens, cfg.rope_theta))
         k = _rope_slots(k, lens, cfg.rope_theta)
     with jax.named_scope("attn.kv_write"):
         pages, offs, n_valid = _page_write_ids(table, lens, ps, n_pages)
@@ -792,7 +838,7 @@ def _decode_gqa_paged(cfg, lp, h, kp, vp, table, lens, mesh=None,
         o = _paged_attend(cfg, q, kp, vp, table, n_valid, mesh,
                           k_scale=kscale, v_scale=vscale)
     with jax.named_scope("attn.out"):
-        delta = jnp.einsum("bhk,hkd->bd", o, lp["wo"])
+        delta = jnp.einsum("bhk,hkd->bd", _branch(cfg, o), lp["wo"])
     return delta, kp, vp, kscale, vscale
 
 
@@ -835,7 +881,8 @@ def _decode_mla_paged(cfg, lp, h, ckv_pool, krope_pool, table, lens,
                                    backend=cfg.kernel_impl,
                                    mesh=mesh,
                                    seq_shard=(cfg.decode_shard == "seq"))
-    delta = MLA.mla_decode_finish(lp, o.astype(jnp.float32), cfg)
+    delta = _branch(cfg, MLA.mla_decode_finish(lp, o.astype(jnp.float32),
+                                               cfg))
     return delta.astype(h.dtype), ckv_pool, krope_pool, ckv_scale, \
         krope_scale
 
@@ -859,11 +906,11 @@ def _decode_cross_paged(cfg, lp, h, xk, xv, enc_lens, page_size,
     kp, vp = pool_view(xk), pool_view(xv)
     tbl = (jnp.arange(B, dtype=jnp.int32)[:, None] * Jx
            + jnp.arange(Jx, dtype=jnp.int32)[None, :])
-    q = jnp.einsum("bd,dhk->bhk", h, lp["wq"])
+    q = _scale_q(cfg, jnp.einsum("bd,dhk->bhk", h, lp["wq"]))
     o = DD.paged_decode_attend(q, kp, vp, tbl, enc_lens,
                                backend=cfg.kernel_impl, mesh=mesh,
                                seq_shard=False)
-    return jnp.einsum("bhk,hkd->bd", o, lp["wo"])
+    return jnp.einsum("bhk,hkd->bd", _branch(cfg, o), lp["wo"])
 
 
 def _paged_attn_delta(cfg, lens, table, h, lp, cache_slice, mesh):
@@ -895,8 +942,7 @@ def _dense_paged_body(cfg, lens, table, x, lp, cache_slice, mesh=None):
                                mesh)
     x = x + d
     with jax.named_scope("mlp"):
-        x = x + L.mlp(lp["mlp"], _norm(cfg, lp["mlp_norm"], x), cfg.act,
-                      backend=cfg, mesh=mesh)
+        x = x + _mlp(cfg, lp["mlp"], lp["mlp_norm"], x, mesh=mesh)
     return x, new
 
 
@@ -909,7 +955,7 @@ def _moe_paged_body(cfg, lens, table, x, lp, cache_slice, mesh=None):
         y, _aux = MOE.moe_ffn(lp["moe"],
                               _norm(cfg, lp["mlp_norm"], x)[None], cfg,
                               mesh=mesh)
-    return x + y[0], new
+    return x + _branch(cfg, y[0]), new
 
 
 def _audio_paged_body(cfg, lens, table, enc_lens, x, lp, cs, mesh=None):
@@ -923,8 +969,7 @@ def _audio_paged_body(cfg, lens, table, enc_lens, x, lp, cs, mesh=None):
                                 cs["cross_v"], enc_lens,
                                 cs["self_k"].shape[2], mesh)
     with jax.named_scope("mlp"):
-        x = x + L.mlp(lp["mlp"], _norm(cfg, lp["mlp_norm"], x), cfg.act,
-                      backend=cfg, mesh=mesh)
+        x = x + _mlp(cfg, lp["mlp"], lp["mlp_norm"], x, mesh=mesh)
     return x, {"self_k": kp, "self_v": vp}
 
 
@@ -947,7 +992,7 @@ def paged_decode_step(params, batch, cfg, mesh=None):
     lens = jnp.asarray(batch["cur_len"], jnp.int32)
     table = jnp.asarray(batch["block_table"], jnp.int32)
     cache = batch["cache"]
-    x = L.embed(params["embed"], tok).astype(jnp.dtype(cfg.dtype))
+    x = _embed(params, tok, cfg)
 
     if fam in ("dense", "vlm"):
         body = functools.partial(_dense_paged_body, cfg, lens, table,
@@ -1001,8 +1046,7 @@ def _dense_decode_body(cfg, cur_len, x, lp, cache_slice, mesh=None):
                                 cache_slice["v"], cur_len, mesh)
         new = {"k": ck, "v": cv}
     x = x + d
-    x = x + L.mlp(lp["mlp"], _norm(cfg, lp["mlp_norm"], x), cfg.act,
-                  backend=cfg, mesh=mesh)
+    x = x + _mlp(cfg, lp["mlp"], lp["mlp_norm"], x, mesh=mesh)
     return x, new
 
 
@@ -1021,7 +1065,7 @@ def _moe_decode_body(cfg, cur_len, x, lp, cache_slice, mesh=None):
     # decode grouping: one group of all B tokens (see moe.py docstring)
     y, _aux = MOE.moe_ffn(lp["moe"], _norm(cfg, lp["mlp_norm"], x)[None],
                           cfg, mesh=mesh)
-    return x + y[0], new
+    return x + _branch(cfg, y[0]), new
 
 
 def decode_step(params, batch, cfg, mesh=None):
@@ -1044,7 +1088,7 @@ def decode_step(params, batch, cfg, mesh=None):
     tok = batch["token"]
     cur = batch["cur_len"]
     cache = batch["cache"]
-    x = L.embed(params["embed"], tok).astype(jnp.dtype(cfg.dtype))  # (B,D)
+    x = _embed(params, tok, cfg)                                   # (B,D)
 
     if fam in ("dense", "vlm"):
         body = functools.partial(_dense_decode_body, cfg, cur, mesh=mesh)
@@ -1072,14 +1116,13 @@ def decode_step(params, batch, cfg, mesh=None):
         def mamba_dec(x, lp, ex):
             nrm, st = ex
             d, st1 = SSM.mamba2_step(lp, _norm(cfg, nrm, x), st, cfg)
-            return x + d, st1
+            return x + _branch(cfg, d), st1
 
         def shared_dec(x, ck, cv):
             h = _norm(cfg, sp["attn_norm"], x)
             d, ck, cv = _decode_gqa(cfg, sp["attn"], h, ck, cv, cur, mesh)
             x = x + d
-            x = x + L.mlp(sp["mlp"], _norm(cfg, sp["mlp_norm"], x), cfg.act,
-                          backend=cfg, mesh=mesh)
+            x = x + _mlp(cfg, sp["mlp"], sp["mlp_norm"], x, mesh=mesh)
             return x, ck, cv
 
         def group_dec(x, gp, ex):
@@ -1108,14 +1151,14 @@ def decode_step(params, batch, cfg, mesh=None):
         def ml_dec(x, lp, ex):
             nrm, st = ex
             d, st1 = XL.mlstm_step(lp, _norm(cfg, nrm, x), st, cfg)
-            return x + d, st1
+            return x + _branch(cfg, d), st1
 
         def group_dec(x, gp, ex):
             gst = ex
             x, mst = _scan_stack(cfg, ml_dec, x, gp["m"],
                                  extra_xs=(gp["n"], gst["mlstm"]))
             d, sst = XL.slstm_step(gp["s"], x, gst["slstm"], cfg)
-            return x + d, {"mlstm": mst, "slstm": sst}
+            return x + _branch(cfg, d), {"mlstm": mst, "slstm": sst}
 
         stacked = {"m": params["mlstm"], "n": params["mlstm_norms"],
                    "s": params["slstm"]}
@@ -1132,8 +1175,7 @@ def decode_step(params, batch, cfg, mesh=None):
             h = _norm(cfg, lp["cross_norm"], x)
             x = x + _decode_cross(cfg, lp["cross"], h, cs["cross_k"],
                                   cs["cross_v"], mesh)
-            x = x + L.mlp(lp["mlp"], _norm(cfg, lp["mlp_norm"], x), cfg.act,
-                          backend=cfg, mesh=mesh)
+            x = x + _mlp(cfg, lp["mlp"], lp["mlp_norm"], x, mesh=mesh)
             return x, {"self_k": ck, "self_v": cv}
 
         xs_cache = {"self_k": cache["self_k"], "self_v": cache["self_v"],
@@ -1205,10 +1247,12 @@ def _suffix_attn_delta(cfg, ap, h, q_pos, kv_pos, prefix, *,
         out, cache = MLA.mla_attention_suffix(
             ap, h, q_pos, kv_pos, cfg, pckv, pkrope,
             head_axis=_head_axis(cfg), mesh=mesh)
+        out = _branch(cfg, out)
         return (out if residual is None else residual + out), cache
     pk, pv = prefix
     q, k, v = A.qkv_proj(ap, h, q_pos, cfg.rope_theta, cfg,
                          mesh=mesh)
+    q = _scale_q(cfg, q)
     k_all = jnp.concatenate([pk.astype(k.dtype), k], axis=1)
     v_all = jnp.concatenate([pv.astype(v.dtype), v], axis=1)
     if cfg.accounting:
@@ -1220,7 +1264,8 @@ def _suffix_attn_delta(cfg, ap, h, q_pos, kv_pos, prefix, *,
                              block_q=cfg.attn_block_q,
                              block_kv=cfg.attn_block_kv,
                              head_axis=_head_axis(cfg), mesh=mesh)
-    return A.o_proj(ap, o, cfg, residual=residual, mesh=mesh), (k, v)
+    return A.o_proj(ap, _branch(cfg, o), cfg, residual=residual,
+                    mesh=mesh), (k, v)
 
 
 def _dense_suffix_body(cfg, q_pos, kv_pos, x, lp, prefix, *, mesh=None):
@@ -1228,8 +1273,7 @@ def _dense_suffix_body(cfg, q_pos, kv_pos, x, lp, prefix, *, mesh=None):
                                _norm(cfg, lp["attn_norm"], x),
                                q_pos, kv_pos, prefix, residual=x,
                                mesh=mesh)
-    x = L.mlp(lp["mlp"], _norm(cfg, lp["mlp_norm"], x), cfg.act,
-              backend=cfg, residual=x, mesh=mesh)
+    x = _mlp(cfg, lp["mlp"], lp["mlp_norm"], x, residual=x, mesh=mesh)
     return x, kv
 
 
@@ -1240,7 +1284,7 @@ def _moe_suffix_body(cfg, q_pos, kv_pos, x, lp, prefix, *, mesh=None):
                                mesh=mesh)
     y, aux = MOE.moe_ffn(lp["moe"], _norm(cfg, lp["mlp_norm"], x), cfg,
                          mesh=mesh)
-    return x + y, (kv, aux)
+    return x + _branch(cfg, y), (kv, aux)
 
 
 def prefill_suffix(params, batch, cfg, mesh=None):
@@ -1280,7 +1324,7 @@ def prefill_suffix(params, batch, cfg, mesh=None):
     q_pos = jnp.arange(S) + M
     kv_pos = jnp.arange(M + S)
 
-    x = L.embed(params["embed"], tokens).astype(jnp.dtype(cfg.dtype))
+    x = _embed(params, tokens, cfg)
 
     if fam == "dense":
         prefix = _gather_prefix_kv(cache, keys, pages)

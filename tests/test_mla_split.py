@@ -273,6 +273,8 @@ def test_paged_dispatch_cache_keyed_by_page_geometry(tmp_path,
 
     monkeypatch.setenv("REPRO_AUTOTUNE_CACHE",
                        str(tmp_path / "autotune.json"))
+    # another test in this process may have turned the tuner off
+    monkeypatch.delenv("REPRO_AUTOTUNE", raising=False)
     autotune.reset()
     B, H, KV, Dh, ps, J, n_pages = 2, 4, 2, 16, 4, 6, 12
     q = jnp.zeros((B, H, Dh))
@@ -311,6 +313,8 @@ def test_paged_dispatch_cache_keyed_by_pool_dtype(tmp_path,
 
     monkeypatch.setenv("REPRO_AUTOTUNE_CACHE",
                        str(tmp_path / "autotune.json"))
+    # another test in this process may have turned the tuner off
+    monkeypatch.delenv("REPRO_AUTOTUNE", raising=False)
     autotune.reset()
     B, H, KV, Dh, ps, J, n_pages = 2, 4, 2, 16, 4, 6, 12
     q = jnp.zeros((B, H, Dh))                       # fp32 leads both
